@@ -11,6 +11,44 @@ import sys
 import time
 
 
+def start_agent(*, workers: int = 2, port: int = 4646, algorithm: str = "",
+                eval_batching: bool = False, batch_width: int = 0,
+                acl: bool = False, region: str = "global", join=(),
+                tls=None, heartbeat_ttl: float = 0.0):
+    """Start the served scheduling path -- Server (broker, workers, plan
+    applier) behind the HTTP API -- wired the one way the agent serves
+    it; returns ``(server, http)``, both running. ``main`` below and
+    chip_smoke.py both come through here, so what the smoke proves on
+    the chip is what ``python -m nomad_tpu.api.devagent`` runs.
+    ``algorithm`` ("" = the default host binpack) is set before the
+    server starts, because the worker pool is shaped by it (tpu-lpq
+    runs ONE coalescing batch worker). ``heartbeat_ttl`` (0 = the
+    server's default) is for fleets registered without a heartbeating
+    client behind each node."""
+    from ..server import Server
+    from ..server.core import DEFAULT_HEARTBEAT_TTL
+    from ..structs import SchedulerConfiguration
+    from .http import HttpServer
+
+    server = Server(num_workers=workers, acl_enabled=acl, region=region,
+                    eval_batching=eval_batching,
+                    batch_width=batch_width or None,
+                    heartbeat_ttl=heartbeat_ttl or DEFAULT_HEARTBEAT_TTL)
+    for spec in join:
+        peer, _, addr = spec.partition("=")
+        if peer and addr:
+            server.join_federation(peer, addr)
+    if algorithm:
+        server.state.set_scheduler_config(SchedulerConfiguration(
+            scheduler_algorithm=algorithm))
+    server.start()
+    # HTTP first: with port 0 the bound port is only known afterwards,
+    # and real clients advertise it to workloads (attr.nomad.api_addr)
+    http = HttpServer(server, port=port, tls=tls)
+    http.start()
+    return server, http
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description="nomad-tpu dev agent")
     parser.add_argument("--nodes", type=int, default=3,
@@ -75,29 +113,15 @@ def main(argv=None) -> int:
 
     from .. import mock
     from ..client import SimClient
-    from ..server import Server
-    from ..structs import SchedulerConfiguration, SCHED_ALG_TPU_BINPACK
-    from .http import HttpServer
+    from ..structs import SCHED_ALG_TPU_BINPACK
 
-    server = Server(num_workers=args.workers, acl_enabled=args.acl,
-                    region=args.region,
-                    eval_batching=args.eval_batching,
-                    batch_width=args.batch_width or None)
-    for spec in args.join:
-        region, _, addr = spec.partition("=")
-        if region and addr:
-            server.join_federation(region, addr)
-    if args.tpu:
-        server.state.set_scheduler_config(SchedulerConfiguration(
-            scheduler_algorithm=SCHED_ALG_TPU_BINPACK))
-    server.start()
-
+    server, http = start_agent(
+        workers=args.workers, port=args.port,
+        algorithm=SCHED_ALG_TPU_BINPACK if args.tpu else "",
+        eval_batching=args.eval_batching, batch_width=args.batch_width,
+        acl=args.acl, region=args.region, join=args.join, tls=tls_cfg)
     scheme = ("https" if tls_cfg is not None and tls_cfg.enable_http
               else "http")
-    # HTTP first: with --port 0 the bound port is only known afterwards,
-    # and real clients advertise it to workloads (attr.nomad.api_addr)
-    http = HttpServer(server, port=args.port, tls=tls_cfg)
-    http.start()
     clients = []
     if args.real_clients:
         import os

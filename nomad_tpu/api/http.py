@@ -935,7 +935,7 @@ class ApiHandler(BaseHTTPRequestHandler):
                         # transfer & device-residency observatory
                         # (solver/xferobs.py): per-dispatch payload
                         # ledger by tree group, const-cache residency
-                        # map, live tunnel-model fit;
+                        # map, live link-model fit;
                         # {"enabled": False} under the kill switch
                         "xferobs": _xferobs.state(),
                         # flap damping: per-node flap scores + active
@@ -1649,10 +1649,10 @@ class ApiHandler(BaseHTTPRequestHandler):
             elif parts == ["v1", "operator", "solver", "reprobe"]:
                 # operator-triggered accelerator guard recovery check
                 # (solver/guard.py reprobe: late-thread flag read + a
-                # killable subprocess probe -- a wedged init can't hang
-                # this handler). Gated operator:write by the blanket
-                # /v1/operator POST check above, like other operator
-                # mutations.
+                # deadline-bounded probe dispatch -- a wedged device
+                # can't hang this handler). Gated operator:write by the
+                # blanket /v1/operator POST check above, like other
+                # operator mutations.
                 from ..solver import guard as solver_guard
                 try:
                     timeout = float(

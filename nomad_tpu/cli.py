@@ -706,7 +706,7 @@ def cmd_operator_debug(args) -> int:
             captures["agent-self.json"]["stats"]["shardcheck"])
     except Exception as e:  # noqa: BLE001 -- partial bundles beat none
         captures["shardcheck.json"] = {"capture_error": repr(e)}
-    # transfer ledger + residency map + tunnel fit as their own member:
+    # transfer ledger + residency map + link fit as their own member:
     # the byte decomposition belongs next to metrics.json when an
     # operator is untangling a slow or bloated dispatch path (ISSUE 13)
     try:
@@ -750,6 +750,9 @@ def cmd_operator_solver(args) -> int:
                   "recovered_late", "host_fallback_dispatches",
                   "backend_unavailable_total", "recovered_total"):
             print(f"{k:28s} = {st.get(k)}")
+        dev = st.get("device") or {}
+        for k in ("platform", "kind", "count"):
+            print(f"device.{k:21s} = {dev.get(k)}")
         br = st.get("breaker") or {}
         for k in ("state", "consecutive_failures", "trips",
                   "recoveries", "backoff_s"):
@@ -784,19 +787,19 @@ def cmd_operator_solver(args) -> int:
         print(f"pack.cache_hit           = {pk.get('cache_hit')}")
         print(f"pack.cache_miss          = {pk.get('cache_miss')}")
     elif args.sub2 == "reprobe":
-        # a first-touch reprobe legitimately blocks for the in-process
-        # probe deadline (<=30s) plus the subprocess transport probe
+        # a first-touch reprobe legitimately blocks for the init probe
+        # deadline (<=30s) plus the probe dispatch's own
         api.timeout = 150.0
         rep = api.post("/v1/operator/solver/reprobe")
         print(f"recovered          = {rep.get('recovered')}")
-        if rep.get("subprocess") is not None:
-            sub = rep["subprocess"]
-            print(f"transport probe    = "
-                  f"{'TIMED OUT' if sub['timed_out'] else 'ok'} "
-                  f"(devices={sub['devices']})")
-        if rep.get("tunnel_ok_process_wedged"):
-            print("verdict            = transport healthy but this "
-                  "process is wedged: restart the agent to recover")
+        if rep.get("dispatch") is not None:
+            d = rep["dispatch"]
+            verdict = ("TIMED OUT" if d["timed_out"]
+                       else "ok" if d["ok"] else "FAILED")
+            print(f"probe dispatch     = {verdict} ({d['ms']}ms)")
+        if rep.get("init_hung"):
+            print("verdict            = backend init is still hung in "
+                  "this process: restart the agent to recover")
         print(f"guard ok           = {rep['state']['ok']}")
     return 0
 
@@ -1258,7 +1261,7 @@ def cmd_operator_transfers(args) -> int:
     stats.xferobs): the per-dispatch payload ledger decomposed by tree
     group (shipped vs cache-resident bytes), the sanctioned-fetch
     result-byte table, the const-cache residency map (per-entry
-    bytes/version/age/hits + high watermark), and the live tunnel-model
+    bytes/version/age/hits + high watermark), and the live link-model
     fit (rtt/bandwidth/crossover). Exit 1 when the ledger's byte parity
     against nomad.solver.dispatch_bytes_total is nonzero."""
     api = _client(args)
@@ -1289,16 +1292,17 @@ def cmd_operator_transfers(args) -> int:
             [[g, mb(d["bytes"]), str(d["fetches"])]
              for g, d in sorted(fetches.items())],
             ["Fetch", "Bytes(MB)", "Count"]))
-    fit = st.get("tunnel")
+    fit = st.get("link")
     print()
     if fit:
         bw = fit.get("bw_mbps")
         xo = fit.get("crossover_bytes")
-        # a local (in-process CPU fallback) backend has no tunnel to
-        # fit: bandwidth is structurally absent, not merely unsampled
+        # a backend whose wall time is compute-bound (the CPU backend)
+        # has no link to fit: bandwidth is structurally absent, not
+        # merely unsampled
         bw_txt = (f"{bw}MB/s" if bw is not None
                   else "n/a (local backend)")
-        print(f"tunnel fit: rtt={fit.get('rtt_ms')}ms "
+        print(f"link fit: rtt={fit.get('rtt_ms')}ms "
               f"bw={bw_txt} "
               f"samples={fit.get('samples')} "
               f"residual={fit.get('residual_rms_ms')}ms"
@@ -1306,7 +1310,7 @@ def cmd_operator_transfers(args) -> int:
               + (f" (skipped {fit.get('skipped_slow')} compile-slow)"
                  if fit.get("skipped_slow") else ""))
     else:
-        print("tunnel fit: insufficient samples")
+        print("link fit: insufficient samples")
     res = st.get("residency") or {}
     if res:
         print(f"residency: {res.get('entries')} pinned entries, "
@@ -1877,7 +1881,7 @@ def build_parser() -> argparse.ArgumentParser:
     ojc.set_defaults(fn=cmd_operator_jitcheck)
     otx = op.add_parser("transfers",
                         help="transfer ledger + device-residency map "
-                        "+ live tunnel-model fit (xferobs)")
+                        "+ live link-model fit (xferobs)")
     otx.set_defaults(fn=cmd_operator_transfers)
     otr = op.add_parser("trace",
                         help="eval span-waterfall forensics")

@@ -1,14 +1,22 @@
 """ctypes bindings for the native tensorization kernels (native/
-pack_kernels.cc), with pure-numpy fallbacks when the library is absent.
+pack_kernels.cc), with pure-numpy fallbacks when no C++ compiler exists.
 
 The native boundary mirrors where the reference keeps native code
 (SURVEY.md section 2.4): performance-critical runtime components, here the
 struct->tensor marshalling path of the TPU solver.
+
+The library is built from the checked-out source on first use and lives
+at a path named by a digest of that source and the compiler flags, so
+what loads is always what git holds: a library left behind by other
+source, another checkout or a hand build is simply never at that path.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import subprocess
+import threading
 from typing import Optional, Tuple
 
 import numpy as np
@@ -16,12 +24,25 @@ import numpy as np
 PORT_WORDS = 2048
 MAX_PORTS_PER_ALLOC = 8
 
-# Bumped whenever the C ABI changes shape; load() refuses a stale .so so a
-# half-upgraded tree falls back to numpy instead of corrupting memory.
+# Bumped whenever the C ABI changes shape; load() refuses a library that
+# answers otherwise instead of corrupting memory.
 ABI_VERSION = 3
+
+_NATIVE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
+SOURCE = os.path.join(_NATIVE_DIR, "pack_kernels.cc")
+# no -march=native: the tree is copied between machines as it stands on
+# disk, and a library tuned to the CPU it was built on may not run on
+# the next one
+CXXFLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
+_load_lock = threading.Lock()
+
+
+class NativeBuildError(RuntimeError):
+    """native/pack_kernels.cc could not be compiled on this machine."""
 
 
 def native_cp_enabled() -> bool:
@@ -32,94 +53,101 @@ def native_cp_enabled() -> bool:
     return os.environ.get("NOMAD_TPU_NATIVE_CP", "") != "0"
 
 
-def _find_library() -> Optional[str]:
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for cand in (
-            os.path.join(here, "native", "build", "libnomad_tpu_native.so"),
-            os.environ.get("NOMAD_TPU_NATIVE_LIB", "")):
-        if cand and os.path.exists(cand):
-            return cand
-    return None
+def library_path() -> str:
+    """Where the library for THIS source and these flags lives."""
+    h = hashlib.sha256(" ".join(CXXFLAGS).encode())
+    with open(SOURCE, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_NATIVE_DIR, "build",
+                        f"libnomad_tpu_native-{h.hexdigest()[:16]}.so")
+
+
+def build(timeout_s: int = 120) -> str:
+    """Compile the library from source, replacing whatever sits at
+    ``library_path()``; returns that path. The rename is atomic, so
+    concurrent builders and loaders only ever see a whole file."""
+    out = library_path()
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    tmp = f"{out}.{os.getpid()}.{threading.get_ident()}.tmp"
+    try:
+        subprocess.run(["g++", *CXXFLAGS, "-o", tmp, SOURCE],
+                       check=True, capture_output=True, timeout=timeout_s)
+        os.replace(tmp, out)
+    except (subprocess.SubprocessError, OSError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        raise NativeBuildError(
+            f"g++ {' '.join(CXXFLAGS)} {SOURCE}: {e}\n"
+            f"{detail.decode(errors='replace')[-2000:]}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return out
 
 
 def load() -> Optional[ctypes.CDLL]:
+    """The library for the checked-out source, built now if it is not
+    there yet. None (the numpy/Python paths take over, and say so in the
+    log) only when it cannot be built or loaded here."""
     global _lib, _load_attempted
     if _load_attempted:
         return _lib
-    _load_attempted = True
-    path = _find_library()
-    if path is None:
-        return None
-    try:
-        lib = ctypes.CDLL(path)
-        if lib.nt_abi_version() != ABI_VERSION:
-            return None
-        d = ctypes.POINTER(ctypes.c_double)
-        i32 = ctypes.POINTER(ctypes.c_int32)
-        i64 = ctypes.POINTER(ctypes.c_int64)
-        i8 = ctypes.POINTER(ctypes.c_int8)
-        u8 = ctypes.POINTER(ctypes.c_uint8)
-        u32 = ctypes.POINTER(ctypes.c_uint32)
-        u64 = ctypes.POINTER(ctypes.c_uint64)
-        lib.nt_pack_usage.argtypes = [
-            i32, d, d, d, u8, i32, ctypes.c_int64, ctypes.c_int32,
-            i32, i32, d, d, d, i32, u32, ctypes.c_int64]
-        lib.nt_count_placed.argtypes = [
-            i32, u64, u64, u8, ctypes.c_int64, ctypes.c_uint64,
-            ctypes.c_uint64, i32, i32, ctypes.c_int64]
-        lib.nt_static_ports_free.argtypes = [
-            u32, ctypes.c_int64, i32, ctypes.c_int32, u8]
-        lib.nt_verify_fit.argtypes = [d, d, d, d, d, d, d, d, d,
-                                      ctypes.c_int64, i32]
-        lib.nt_verify_plan.argtypes = [
-            d, d, d, u8,                          # table columns
-            i64, i32, i8, ctypes.c_int64,         # row deltas
-            i32, d, d, d, i8, ctypes.c_int64,     # direct ask entries
-            d, d, d,                              # caps
-            d, d, d, d, d, d,                     # used/ask accumulators
-            ctypes.c_int64, i32]
-        lib.nt_solve_eval.argtypes = [
-            ctypes.c_int32, d, d, d, d, d, d, i32, u8,
-            ctypes.c_uint64, ctypes.c_double, ctypes.c_double,
-            ctypes.c_double, ctypes.c_int32, ctypes.c_int32,
-            ctypes.c_int32, ctypes.c_double, ctypes.c_int32,
-            ctypes.c_int32, i32, i32]
-        lib.nt_shuffled_order.argtypes = [ctypes.c_uint64, ctypes.c_int32,
-                                          i32]
-        _lib = lib
-    except OSError:
-        _lib = None
+    with _load_lock:
+        if not _load_attempted:
+            try:
+                _lib = _load_locked()
+            except (NativeBuildError, OSError) as e:
+                from .server.logbroker import log
+                log("warn", "native",
+                    f"native kernels unavailable, Python paths in use: {e}")
+            _load_attempted = True
     return _lib
+
+
+def _load_locked() -> ctypes.CDLL:
+    path = library_path()
+    if not os.path.exists(path):
+        build()
+    lib = ctypes.CDLL(path)
+    if lib.nt_abi_version() != ABI_VERSION:
+        raise OSError(f"{path} reports ABI {lib.nt_abi_version()}, "
+                      f"bindings expect {ABI_VERSION}")
+    d = ctypes.POINTER(ctypes.c_double)
+    i32 = ctypes.POINTER(ctypes.c_int32)
+    i64 = ctypes.POINTER(ctypes.c_int64)
+    i8 = ctypes.POINTER(ctypes.c_int8)
+    u8 = ctypes.POINTER(ctypes.c_uint8)
+    u32 = ctypes.POINTER(ctypes.c_uint32)
+    u64 = ctypes.POINTER(ctypes.c_uint64)
+    lib.nt_pack_usage.argtypes = [
+        i32, d, d, d, u8, i32, ctypes.c_int64, ctypes.c_int32,
+        i32, i32, d, d, d, i32, u32, ctypes.c_int64]
+    lib.nt_count_placed.argtypes = [
+        i32, u64, u64, u8, ctypes.c_int64, ctypes.c_uint64,
+        ctypes.c_uint64, i32, i32, ctypes.c_int64]
+    lib.nt_static_ports_free.argtypes = [
+        u32, ctypes.c_int64, i32, ctypes.c_int32, u8]
+    lib.nt_verify_fit.argtypes = [d, d, d, d, d, d, d, d, d,
+                                  ctypes.c_int64, i32]
+    lib.nt_verify_plan.argtypes = [
+        d, d, d, u8,                          # table columns
+        i64, i32, i8, ctypes.c_int64,         # row deltas
+        i32, d, d, d, i8, ctypes.c_int64,     # direct ask entries
+        d, d, d,                              # caps
+        d, d, d, d, d, d,                     # used/ask accumulators
+        ctypes.c_int64, i32]
+    lib.nt_solve_eval.argtypes = [
+        ctypes.c_int32, d, d, d, d, d, d, i32, u8,
+        ctypes.c_uint64, ctypes.c_double, ctypes.c_double,
+        ctypes.c_double, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_double, ctypes.c_int32,
+        ctypes.c_int32, i32, i32]
+    lib.nt_shuffled_order.argtypes = [ctypes.c_uint64, ctypes.c_int32,
+                                      i32]
+    return lib
 
 
 def available() -> bool:
     return load() is not None
-
-
-def ensure_built(timeout_s: int = 120) -> bool:
-    """Build the native library if absent (g++ one-liner, matching the
-    CMake flags). Used by bench.py so the compiled-host baseline exists on
-    whatever machine runs the bench."""
-    global _load_attempted
-    if available():
-        return True
-    import subprocess
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(here, "native", "pack_kernels.cc")
-    out_dir = os.path.join(here, "native", "build")
-    out = os.path.join(out_dir, "libnomad_tpu_native.so")
-    if not os.path.exists(src):
-        return False
-    os.makedirs(out_dir, exist_ok=True)
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             "-o", out, src],
-            check=True, capture_output=True, timeout=timeout_s)
-    except (subprocess.SubprocessError, OSError):
-        return False
-    _load_attempted = False
-    return available()
 
 
 def shuffled_order(seed: int, n: int) -> Optional[np.ndarray]:

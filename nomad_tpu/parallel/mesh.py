@@ -516,6 +516,20 @@ def shard_eval_axis(trees, tag: str = "compact"):
     note_dispatch_bytes(total)
     xferobs.note_payload(tag, total)
     sharding = NamedSharding(mesh, P("evals"))
-    return tuple(
+    out = tuple(
         jax.tree_util.tree_map(lambda a: jax.device_put(a, sharding), t)
         for t in trees)
+    if xferobs.enabled():
+        # per-shard ledger rows like the mesh puts write, with ACTUAL
+        # read off the arrays' own shards: an even split is what the
+        # spec declares, where the bytes landed is what the runtime did
+        landed: dict = {}
+        for leaf in jax.tree_util.tree_leaves(out):
+            for shard in leaf.addressable_shards:
+                landed[shard.device.id] = (landed.get(shard.device.id, 0)
+                                           + shard.data.nbytes)
+        for dev in mesh.devices.flat:
+            xferobs.note_shard_bytes(tag, f"d{dev.id}",
+                                     total // mesh.devices.size,
+                                     landed.get(dev.id, 0))
+    return out

@@ -11,6 +11,7 @@ always the leader; the raft boundary is the StateStore write API.
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -222,6 +223,12 @@ class WorkerSupervisor:
                     or self._stop_ev.is_set()):
                 return
             now = time.monotonic()
+            # a worker waiting on an XLA compile is slow, not wedged:
+            # the stall clock restarts at the latest compile-stage edge
+            # (sys.modules: a host-only server never imports the solver
+            # for this)
+            guard = sys.modules.get("nomad_tpu.solver.guard")
+            last_compile = guard.last_compile_activity() if guard else 0.0
             for i, w in enumerate(self.server.workers):
                 if i in self._pending:
                     if now >= self._pending[i]:
@@ -235,7 +242,8 @@ class WorkerSupervisor:
                          f"restarting slot {i} with backoff")
                     self._schedule_restart_locked(i, now)
                     continue
-                age = now - getattr(w, "last_progress", now)
+                age = now - max(getattr(w, "last_progress", now),
+                                last_compile)
                 if self.stall_s > 0 and age > self.stall_s:
                     self.wedges_detected += 1
                     metrics.incr("nomad.worker.supervisor_wedge")

@@ -749,7 +749,7 @@ _STAGE_OF: Dict[str, Tuple[str, str]] = {
     "solver.dispatch_solo": ("dispatch", "busy"),
     "solver.constcache": ("dispatch", "busy"),
     "solver.fixpoint": ("dispatch", "busy"),
-    # transfer-vs-compute split (solver/xferobs.py): the tunnel model's
+    # transfer-vs-compute split (solver/xferobs.py): the link model's
     # predicted wire share of each dispatch vs the remainder -- the
     # dispatch stage decomposed into link time and chip time
     "solver.xfer_transfer": ("dispatch.transfer", "busy"),

@@ -1,49 +1,33 @@
 """Persistent XLA compilation cache for the solver's jitted programs.
 
 The solver compiles one program per (signature, E-bucket, P-bucket, N)
-shape variant; each dense-kernel compile costs seconds (CPU backend) to
-tens of seconds (first TPU compile). In-process jax caching already
-dedupes within one server lifetime; this enables jax's on-disk cache so
-restarts, test runs and bench processes skip recompiling variants any
-prior process already built. Opt-out with NOMAD_TPU_COMPILE_CACHE=0;
-override the location with NOMAD_TPU_COMPILE_CACHE=<dir>.
+shape variant and each compile costs seconds. In-process jax caching
+dedupes within one server lifetime; the on-disk cache lets restarts,
+test runs and bench processes skip variants any prior process built.
 
-The reference has no analog (its hot loop is host Go); this is purely a
-TPU-runtime concern, the moral equivalent of its compiled binary being
-reusable across restarts.
+The directory is placed from OUTSIDE: when ``JAX_COMPILATION_CACHE_DIR``
+is set JAX reads it itself and this module names no directory at all.
+Otherwise the cache lives at ``<checkout>/.jax_cache`` -- a fixed path,
+because the path is part of the cache key: a directory that moves with
+a uid, a pid or the clock never hits.
+
+``enable_compile_cache`` runs once, from the ``nomad_tpu.solver``
+package import, which every program factory (solver/*, parallel/mesh)
+sits behind -- so no program can compile before the cache is on.
 """
 from __future__ import annotations
 
 import os
-import tempfile
-import threading
 
-_LOCK = threading.Lock()
-_DONE = False
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def enable_compile_cache() -> None:
-    """Idempotent; safe to call before every solver dispatch."""
-    global _DONE
-    with _LOCK:
-        if _DONE:
-            return
-        _DONE = True
-        raw = os.environ.get("NOMAD_TPU_COMPILE_CACHE", "")
-        if raw == "0":
-            return
-        # uid-suffixed: a fixed path in the shared tmp dir would let
-        # another user pre-create it (silent recompiles) or pre-plant
-        # cache entries that get deserialized into this process
-        path = raw or os.path.join(
-            tempfile.gettempdir(),
-            f"nomad_tpu_xla_cache_{os.getuid()}")
-        try:
-            os.makedirs(path, exist_ok=True)
-            import jax
-            jax.config.update("jax_compilation_cache_dir", path)
-            # the dense kernels compile in 1-10s; cache everything
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.5)
-        except Exception:  # noqa: BLE001 -- cache is best-effort
-            pass
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    # cache every program, however quick its compile was this time: a
+    # time threshold would make what is cached depend on the clock
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -1,20 +1,16 @@
 """Sampled-stack profile of the headline-shape e2e round (the r5 pass-3
 methodology): run bench.time_batched_path under a 200Hz all-thread
 sampler, aggregate leaf frames and (module, function) self-time, print
-the top entries. CPU-host control-plane profile; the solver dispatch
-itself is timed separately by bench."""
+the top entries. Host control-plane profile on whatever platform JAX
+comes up on (JAX_PLATFORMS=cpu keeps it off the chip); the solver
+dispatch itself is timed separately by bench."""
 import collections
 import os
 import sys
 import threading
 import time
 
-os.environ.pop("JAX_PLATFORMS", None)
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import jax
-
-if os.environ.get("E2E_PROFILE_TPU", "") != "1":
-    jax.config.update("jax_platforms", "cpu")
 
 import bench
 

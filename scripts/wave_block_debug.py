@@ -1,13 +1,11 @@
 """Tiny-shape debug driver for _solve_wave_block_impl vs the classic
-compact kernel: synthetic compact tables, CPU, fast compiles."""
+compact kernel: synthetic compact tables, fast compiles. Meant for the
+CPU backend: run with JAX_PLATFORMS=cpu."""
 import os
 import sys
 
-os.environ.pop("JAX_PLATFORMS", None)
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import jax.numpy as jnp

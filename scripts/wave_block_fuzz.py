@@ -1,14 +1,12 @@
 """Randomized equivalence fuzz: _solve_wave_block_impl vs the classic
-compact kernel over synthetic compact tables (CPU). One process, few
-shapes (compile reuse), many seeds."""
+compact kernel over synthetic compact tables. One process, few shapes
+(compile reuse), many seeds. Meant for the CPU backend: run with
+JAX_PLATFORMS=cpu."""
 import os
 import sys
 
-os.environ.pop("JAX_PLATFORMS", None)
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import jax.numpy as jnp

@@ -3,13 +3,12 @@ the classic per-placement wave kernel semantics for ONE headline lane in
 numpy and counts 'events' (winner saturation -> refill, skip-set growth,
 penalty steps). Average placements-per-event bounds the speedup of a
 block kernel that commits all placements between events in one chain
-step."""
+step. Host-side numpy: run with JAX_PLATFORMS=cpu to keep off the chip."""
 import os
 import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
 

@@ -83,8 +83,8 @@ def test_greedy_mesh_shape_parity(e_par, n_par):
 
     ref = jax.jit(
         functools.partial(solve_eval_batch, spread_alg=False,
-                          dtype_name="float32"),
-        device=jax.devices()[0])(const, init, batch)
+                          dtype_name="float32"))(
+        *jax.device_put((const, init, batch), jax.devices()[0]))
     ref_chosen, ref_scores = np.asarray(ref[0]), np.asarray(ref[1])
 
     mesh = meshmod.make_mesh(8, eval_parallel=e_par)

@@ -1,6 +1,5 @@
 """Native kernel equivalence: C++ kernels vs numpy fallbacks."""
 import os
-import subprocess
 
 import numpy as np
 import pytest
@@ -34,26 +33,9 @@ KERNEL_PARITY_TESTS = {
 }
 
 
-@pytest.fixture(scope="module", autouse=True)
-def build_native_lib():
-    """Build the native library on demand so a fresh clone tests the real
-    kernels; skip the module if no C++ toolchain is available."""
-    if native.available():
-        return
-    try:
-        subprocess.run(["cmake", "-S", os.path.join(REPO, "native"),
-                        "-B", os.path.join(REPO, "native", "build")],
-                       check=True, capture_output=True, timeout=120)
-        subprocess.run(["cmake", "--build",
-                        os.path.join(REPO, "native", "build")],
-                       check=True, capture_output=True, timeout=120)
-    except (subprocess.CalledProcessError, FileNotFoundError,
-            subprocess.TimeoutExpired) as e:
-        pytest.skip(f"cannot build native library: {e}")
-    native._load_attempted = False
-    native._lib = None
-    if not native.available():
-        pytest.skip("native library built but failed to load")
+pytestmark = pytest.mark.skipif(
+    not native.available(),
+    reason="no C++ compiler: native library cannot be built here")
 
 
 def _rows(n_rows, n_pad, rng):
@@ -73,8 +55,36 @@ def _rows(n_rows, n_pad, rng):
 
 
 def test_native_lib_loads():
-    # the built library must be present in this repo
-    assert native.available(), "native/build/libnomad_tpu_native.so missing"
+    assert native.available()
+
+
+def test_only_the_library_built_from_this_source_loads(tmp_path,
+                                                      monkeypatch):
+    """The loaded library's path is a digest of the checked-out source
+    and the flags: other source means another path, so a library left
+    over from elsewhere is never picked up, and a fresh checkout builds
+    its own on first use."""
+    here = native.library_path()
+    assert os.path.exists(here)
+    src = tmp_path / "pack_kernels.cc"
+    with open(native.SOURCE) as f:
+        src.write_text(f.read() + "\n// edited\n")
+    monkeypatch.setattr(native, "SOURCE", str(src))
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    there = native.library_path()
+    assert os.path.basename(there) != os.path.basename(here)
+    # a stale library planted under the old fixed name changes nothing
+    os.makedirs(tmp_path / "build")
+    (tmp_path / "build" / "libnomad_tpu_native.so").write_bytes(b"stale")
+    monkeypatch.setattr(native, "_load_attempted", False)
+    monkeypatch.setattr(native, "_lib", None)
+    assert native.load() is not None            # built on first use
+    assert os.path.exists(there)
+    # and a failing compiler is an error the caller sees, not a silent
+    # switch to the Python paths
+    monkeypatch.setattr(native, "CXXFLAGS", ("--no-such-flag",))
+    with pytest.raises(native.NativeBuildError):
+        native.build()
 
 
 def test_pack_usage_native_matches_numpy():
@@ -314,7 +324,7 @@ def test_verify_plan_concurrent_scaling():
     import threading
     import time
     assert native.available()
-    if (os.cpu_count() or 1) < 2:
+    if len(os.sched_getaffinity(0)) < 2:
         pytest.skip("needs >=2 cores to demonstrate kernel overlap")
     head, used = _big_verify_plan_inputs()
 
@@ -322,17 +332,34 @@ def test_verify_plan_concurrent_scaling():
         native.verify_plan(*head, *[u.copy() for u in used])
 
     one_call()                                       # warm caches
-    t0 = time.perf_counter()
-    one_call()
-    single = time.perf_counter() - t0
 
-    threads = [threading.Thread(target=one_call) for _ in range(2)]
-    t0 = time.perf_counter()
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    both = time.perf_counter() - t0
+    def timed_single():
+        t0 = time.perf_counter()
+        one_call()
+        return time.perf_counter() - t0
+
+    cpus = sorted(os.sched_getaffinity(0))
+
+    def pinned_call(cpu):
+        # a thread this short-lived can spend its whole life on its
+        # parent's CPU before the kernel balances it away; the claim
+        # under test is that the CALLS overlap, so give each a CPU
+        os.sched_setaffinity(threading.get_native_id(), {cpu})
+        one_call()
+
+    def timed_pair():
+        threads = [threading.Thread(target=pinned_call, args=(cpu,))
+                   for cpu in cpus[:2]]
+        t0 = time.perf_counter()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        return time.perf_counter() - t0
+
+    # best of several: one reading on a shared host is a draw
+    single = min(timed_single() for _ in range(5))
+    both = min(timed_pair() for _ in range(5))
     assert both < 1.9 * single, (
         f"2 concurrent calls took {both:.4f}s vs single {single:.4f}s "
         f"({both / single:.2f}x) -- kernel calls are serializing")

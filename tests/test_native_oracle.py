@@ -21,7 +21,7 @@ from nomad_tpu.structs import (
 
 EVAL_ID = "native-parity-eval-00000001"
 
-pytestmark = pytest.mark.skipif(not native.ensure_built(),
+pytestmark = pytest.mark.skipif(not native.available(),
                                 reason="native library unavailable")
 
 

@@ -2,7 +2,7 @@
 ISSUE 13): byte-parity of the tagged ledger decomposition against the
 ``nomad.solver.dispatch_bytes_total`` counter across the dense, wave,
 wave-preempt and mesh transports; the kill switch as a bitwise no-op;
-the tunnel-model fit; the residency map; the fuse_dispatch waterfall
+the link-model fit; the residency map; the fuse_dispatch waterfall
 annotation; the saturation-stage split; the Perfetto counter tracks;
 the bench-artifact fields and their regress-gate direction rows; and
 the <2%-of-a-dispatch ledger-overhead bound."""
@@ -264,7 +264,7 @@ def test_ledger_overhead_under_two_percent():
         xferobs.end_dispatch(3.0, time.time())
 
     best = None
-    for _ in range(3):
+    for _ in range(10):     # min over enough windows to find a quiet one
         reps = 100
         t0 = time.perf_counter()
         for _ in range(reps):
@@ -277,11 +277,11 @@ def test_ledger_overhead_under_two_percent():
 
 
 # ---------------------------------------------------------------------------
-# tunnel model
+# link model
 
 
-def test_tunnel_model_recovers_rtt_and_bandwidth():
-    m = xferobs._TunnelModel()
+def test_link_model_recovers_rtt_and_bandwidth():
+    m = xferobs._LinkModel()
     # wall_ms = 5ms RTT + bytes at 1 MB/s (0.001 ms/byte)
     for nbytes in (1000, 2000, 5000, 10000, 20000, 50000, 100000,
                    200000):
@@ -297,7 +297,7 @@ def test_tunnel_model_recovers_rtt_and_bandwidth():
     assert m.fit()["samples"] == 8
     assert m.fit()["skipped_slow"] == 1
     # degenerate: constant byte size -> pure-RTT readout, no slope
-    flat = xferobs._TunnelModel()
+    flat = xferobs._LinkModel()
     flat.add(1000, 7.0)
     flat.add(1000, 9.0)
     f = flat.fit()
@@ -305,7 +305,7 @@ def test_tunnel_model_recovers_rtt_and_bandwidth():
     assert abs(f["rtt_ms"] - 8.0) < 1e-6
 
 
-def test_tunnel_fit_feeds_metrics_and_split_spans():
+def test_link_fit_feeds_metrics_and_split_spans():
     """After >=8 recorded dispatches the fit emits nomad.xfer.rtt_ms /
     bw_mbps gauges and records the transfer-vs-compute split spans the
     saturation attribution maps to dispatch.transfer/.compute."""
