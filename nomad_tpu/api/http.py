@@ -1310,7 +1310,14 @@ class ApiHandler(BaseHTTPRequestHandler):
                 try:
                     import jax
                     if action == "start":
-                        jax.profiler.start_trace(trace_dir)
+                        # host side: the program's own spans (each a
+                        # TraceAnnotation, server/tracing.py), not the
+                        # Python tracer's every frame -- a profile of a
+                        # loaded server has to stay small and cheap
+                        opts = jax.profiler.ProfileOptions()
+                        opts.python_tracer_level = 0
+                        jax.profiler.start_trace(
+                            trace_dir, profiler_options=opts)
                         self._send(200, {"tracing": True,
                                          "dir": trace_dir})
                     elif action == "stop":

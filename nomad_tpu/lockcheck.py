@@ -68,6 +68,7 @@ _REAL_RLOCK = threading.RLock
 _REAL_CONDITION = threading.Condition
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_STORELOCK_FILE = os.path.join("nomad_tpu", "state", "storelock.py")
 
 _ACTIVE = False                  # module-global fast gate (one dict read)
 _REAL_QUEUE_GET = None           # queue.Queue.get, saved at first enable
@@ -336,6 +337,17 @@ def _patched_queue_get(self, block=True, timeout=None):
 # instrumented primitives
 
 
+def _acquirer(frame):
+    """The frame that asked for the lock: past the store lock's
+    accounting wrapper (state/storelock.py) where that stands between,
+    so witness sites and the escaped-frame check keep naming the store
+    method."""
+    while frame is not None and frame.f_code.co_filename.endswith(
+            _STORELOCK_FILE):
+        frame = frame.f_back
+    return frame
+
+
 class _LockWrapper:
     """Instrumented Lock/RLock. Delegates to a real primitive; records
     acquire/release into the checker when it is active. Implements the
@@ -357,7 +369,7 @@ class _LockWrapper:
             _schedcheck.lock_gate(self._lc_inner)
         ok = self._lc_inner.acquire(blocking, timeout)
         if ok:
-            _record_acquire(self, True, sys._getframe(1))
+            _record_acquire(self, True, _acquirer(sys._getframe(1)))
         return ok
 
     def release(self):
@@ -372,7 +384,7 @@ class _LockWrapper:
         # nomadlint: waive=bare-acquire -- this IS the lock: the paired
         # release is __exit__ by context-manager protocol
         self._lc_inner.acquire()
-        _record_acquire(self, False, sys._getframe(1))
+        _record_acquire(self, False, _acquirer(sys._getframe(1)))
         return self
 
     def __exit__(self, *exc):
@@ -405,7 +417,7 @@ class _LockWrapper:
             # protocol: wait() re-acquires here, releases via
             # _release_save; the condvar owns the pairing
             self._lc_inner.acquire()
-        _record_acquire(self, False, sys._getframe(1))
+        _record_acquire(self, False, _acquirer(sys._getframe(1)))
 
     def _is_owned(self):
         if self._lc_kind == "rlock":
@@ -432,6 +444,11 @@ class _InstrumentedCondition(_REAL_CONDITION):
     at the next scheduling decision -- which is what makes condvar
     handoff order a deterministic function of the schedule seed."""
 
+    def _lc_lock(self):
+        """The instrumented lock this condition waits on: its own, or
+        the one under a wrapper that says so (state/storelock.py)."""
+        return getattr(self._lock, "_lc_wrapped", self._lock)
+
     def wait(self, timeout=None):
         if _schedcheck._ACTIVE and _schedcheck.managed_active():
             state = self._release_save()
@@ -439,14 +456,14 @@ class _InstrumentedCondition(_REAL_CONDITION):
                 notified = _schedcheck.cond_wait_gate(
                     id(self), timed=timeout is not None)
             finally:
-                inner = getattr(self._lock, "_lc_inner", None)
+                inner = getattr(self._lc_lock(), "_lc_inner", None)
                 if inner is not None:
                     _schedcheck.lock_gate(inner, "cond.reacquire")
                 self._acquire_restore(state)
             return notified
         if not _ACTIVE:
             return super().wait(timeout)
-        others = _held_other(exclude=self._lock)
+        others = _held_other(exclude=self._lc_lock())
         if not others:
             return super().wait(timeout)
         t0 = time.monotonic()

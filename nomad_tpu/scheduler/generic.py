@@ -97,8 +97,16 @@ class GenericScheduler:
                  else MAX_SERVICE_SCHEDULE_ATTEMPTS)
         attempts = 0
         err: Optional[Exception] = None
+        # attempts a placing eval: a partial refusal by the applier
+        # costs a whole further round (pack, barrier, submit)
+        from ..server.telemetry import metrics as _tm
+        placing = evaluation.triggered_by == TRIGGER_JOB_REGISTER
+        if placing:
+            _tm.incr("nomad.scheduler.register_evals")
         while attempts < limit:
             try:
+                if placing:
+                    _tm.incr("nomad.scheduler.register_attempts")
                 done = self._process_once()
             except SetStatusError as e:
                 self.planner.update_eval(self._eval_with_status(
@@ -140,9 +148,20 @@ class GenericScheduler:
         self.blocked = None
         self.failed_tg_allocs = {}
 
+        from ..server.tracing import tracer
+        with tracer.span("sched.setup"):
+            self._setup_once()
+        if not self._compute_job_allocs():
+            return False
+
+        # Queued allocations accounting for annotations
+        return self._finish_plan()
+
+    def _setup_once(self) -> None:
+        """The attempt's plan, context and stack over the job's ready
+        nodes (the stack shuffles them)."""
         ns, job_id = self.eval.namespace, self.eval.job_id
         self.job = self.state.job_by_id(ns, job_id)
-        num_tainted = 0
 
         self.plan = Plan(
             eval_id=self.eval.id,
@@ -173,31 +192,29 @@ class GenericScheduler:
             self.stack.set_nodes(nodes)
             self.ctx.metrics.nodes_in_pool = len(nodes)
 
-        if not self._compute_job_allocs():
-            return False
-
-        # Queued allocations accounting for annotations
-        return self._finish_plan()
-
     def _compute_job_allocs(self) -> bool:
         """(reference: generic_sched.go:364 computeJobAllocs)"""
+        from ..server.tracing import tracer
         ns, job_id = self.eval.namespace, self.eval.job_id
-        allocs = self.state.allocs_by_job(ns, job_id)
-        tainted = tainted_nodes(self.state, allocs)
+        with tracer.span("sched.reconcile"):
+            allocs = self.state.allocs_by_job(ns, job_id)
+            tainted = tainted_nodes(self.state, allocs)
 
-        # node-update evals mark running allocs on down nodes lost
-        # (reference: generic_sched.go:382 updateNonTerminalAllocsToLost)
-        reconciler = AllocReconciler(
-            batch=self.batch,
-            job_id=job_id,
-            job=self.job if (self.job and not self.job.stopped()) else None,
-            deployment=self.state.latest_deployment_by_job(ns, job_id),
-            existing_allocs=allocs,
-            tainted_nodes=tainted,
-            eval_id=self.eval.id,
-            eval_priority=self.eval.priority,
-        )
-        results = reconciler.compute()
+            # node-update evals mark running allocs on down nodes lost
+            # (reference: generic_sched.go:382
+            # updateNonTerminalAllocsToLost)
+            reconciler = AllocReconciler(
+                batch=self.batch,
+                job_id=job_id,
+                job=self.job if (self.job and not self.job.stopped())
+                else None,
+                deployment=self.state.latest_deployment_by_job(ns, job_id),
+                existing_allocs=allocs,
+                tainted_nodes=tainted,
+                eval_id=self.eval.id,
+                eval_priority=self.eval.priority,
+            )
+            results = reconciler.compute()
         self.followup_evals = results.desired_followup_evals
         # the deployment placements attach to: existing-and-active or newly
         # created by the reconciler (reference: generic_sched.go s.deployment)
@@ -446,17 +463,19 @@ class GenericScheduler:
                 fallback.extend(tg_places)
                 continue
             n_solved = 0
-            for sp in solved:
-                if sp.node is None:
-                    if tg.name in self.failed_tg_allocs:
-                        self.failed_tg_allocs[tg.name].coalesced_failures += 1
-                    else:
-                        m = self.ctx.metrics.copy()
-                        m.nodes_evaluated = sp.n_yielded
-                        self.failed_tg_allocs[tg.name] = m
-                    continue
-                self._append_solved_alloc(sp, deployment_id)
-                n_solved += 1
+            with tracer.span("sched.append_allocs", tg=tg_name):
+                for sp in solved:
+                    if sp.node is None:
+                        if tg.name in self.failed_tg_allocs:
+                            self.failed_tg_allocs[
+                                tg.name].coalesced_failures += 1
+                        else:
+                            m = self.ctx.metrics.copy()
+                            m.nodes_evaluated = sp.n_yielded
+                            self.failed_tg_allocs[tg.name] = m
+                        continue
+                    self._append_solved_alloc(sp, deployment_id)
+                    n_solved += 1
             if n_solved:
                 # one counter bump per TG batch, not per placement: the
                 # per-alloc incr serialized 32 workers on the telemetry

@@ -37,6 +37,7 @@ from .worker import BatchWorker, Worker
 DEFAULT_HEARTBEAT_TTL = 10.0
 GC_EVAL_THRESHOLD = 3600.0
 GC_INTERVAL = 60.0
+SCHED_LAG_SLEEP_S = 0.05
 # terminal allocs retained before the watermark GC pass kicks in
 # (NOMAD_TPU_GC_ALLOC_WATERMARK overrides; 0 disables the pass)
 GC_ALLOC_WATERMARK = 1_000_000
@@ -227,8 +228,11 @@ class WorkerSupervisor:
             # the stall clock restarts at the latest compile-stage edge
             # (sys.modules: a host-only server never imports the solver
             # for this)
+            # getattr-guarded: sys.modules can hand back the module
+            # while another thread is still importing it
             guard = sys.modules.get("nomad_tpu.solver.guard")
-            last_compile = guard.last_compile_activity() if guard else 0.0
+            read = getattr(guard, "last_compile_activity", None)
+            last_compile = read() if read is not None else 0.0
             for i, w in enumerate(self.server.workers):
                 if i in self._pending:
                     if now >= self._pending[i]:
@@ -433,12 +437,20 @@ class Server:
         self.establish_leadership()
 
     def _start_background(self) -> None:
-        for fn, name in ((self._run_heartbeat_watcher, "heartbeat"),
-                         (self._run_gc, "core-gc"),
-                         (self._run_periodic, "periodic"),
-                         (self._run_deployment_watcher, "deploy-watch"),
-                         (self._run_volume_watcher, "volume-watch"),
-                         (self._run_drainer, "drainer")):
+        from .tracing import trace_enabled
+        loops = [(self._run_heartbeat_watcher, "heartbeat"),
+                 (self._run_gc, "core-gc"),
+                 (self._run_periodic, "periodic"),
+                 (self._run_deployment_watcher, "deploy-watch"),
+                 (self._run_volume_watcher, "volume-watch"),
+                 (self._run_drainer, "drainer")]
+        from .. import schedcheck
+        if trace_enabled() and not schedcheck._ACTIVE:
+            # (under the schedule explorer waits are virtual: a lag of
+            # the wall clock means nothing there, and a 50 ms poller
+            # would only add decisions to every schedule)
+            loops.append((self._run_sched_lag, "sched-lag"))
+        for fn, name in loops:
             t = threading.Thread(target=self._supervised, args=(fn, name),
                                  daemon=True, name=name)
             t.start()
@@ -459,6 +471,20 @@ class Server:
                      f"{name} watcher error (restarting): "
                      f"{traceback.format_exc()}")
                 self._shutdown.wait(0.5)
+
+    def _run_sched_lag(self) -> None:
+        """How long a thread that becomes runnable waits for the
+        interpreter: sleep SCHED_LAG_SLEEP_S, record how late the wake
+        came (timer nomad.runtime.sched_lag, 20 samples a second). Part
+        of the contention account; not started under NOMAD_TPU_TRACE=0."""
+        from .telemetry import metrics
+        while True:
+            t0 = time.perf_counter()
+            if self._shutdown.wait(SCHED_LAG_SLEEP_S):
+                return
+            late = time.perf_counter() - t0 - SCHED_LAG_SLEEP_S
+            metrics.sample_ms("nomad.runtime.sched_lag",
+                              max(0.0, late) * 1e3)
 
     def establish_leadership(self) -> None:
         """(reference: leader.go:357 establishLeadership -- enable broker
@@ -1765,11 +1791,32 @@ class Server:
 
     def run_gc_once(self, threshold: float = GC_EVAL_THRESHOLD,
                     terminal_watermark: Optional[int] = None) -> dict:
+        """One pass of the core GC job, inside span ``core.gc`` and
+        timer ``nomad.core.gc``. It works for no one eval, so the span
+        has no trace to land in: it shows on the profiler's timeline
+        and in the span sink, and every eval in flight while it ran
+        gets a ``core.gc`` event carrying its duration, so that a
+        waterfall shows the stall that slowed it."""
+        from .telemetry import metrics
+        from .tracing import tracer
+        t0 = time.perf_counter()
+        with metrics.measure("nomad.core.gc"), tracer.span("core.gc"):
+            out = self._gc_pass(threshold, terminal_watermark)
+        tracer.broadcast_event(
+            "core.gc", dur_ms=round((time.perf_counter() - t0) * 1e3, 3))
+        return out
+
+    def _gc_pass(self, threshold: float,
+                 terminal_watermark: Optional[int]) -> dict:
+        from .telemetry import metrics
         cutoff = time.time() - threshold
         gone_evals = []
-        for ev in self.state.evals():
+        evals = self.state.evals()
+        terminal_evals = 0
+        for ev in evals:
             if not ev.terminal_status():
                 continue
+            terminal_evals += 1
             allocs = self.state.allocs_by_eval(ev.id)
             if all(a.terminal_status() for a in allocs) and \
                     ev.modify_time < cutoff:
@@ -1777,8 +1824,14 @@ class Server:
         if gone_evals:
             self.state.delete_evals(gone_evals)
         gone_set = set(gone_evals)
+        all_allocs = self.state.allocs()
+        metrics.incr("nomad.core.gc_evals_scanned", len(evals))
+        # allocs_by_eval walks the whole table for every terminal eval,
+        # then the sweep below walks it once more
+        metrics.incr("nomad.core.gc_allocs_scanned",
+                     (terminal_evals + 1) * len(all_allocs))
         gone_allocs = [
-            a.id for a in self.state.allocs()
+            a.id for a in all_allocs
             if a.terminal_status() and a.modify_time < cutoff
             and (a.eval_id in gone_set or not a.eval_id
                  or self.state.eval_by_id(a.eval_id) is None)]
@@ -1803,7 +1856,6 @@ class Server:
         compacted = self.state.compact_alloc_table() \
             if hasattr(self.state, "compact_alloc_table") else None
         if compacted is not None:
-            from .telemetry import metrics
             metrics.incr("nomad.gc.table_compactions")
         return {"evals": len(gone_evals), "allocs": len(gone_allocs),
                 "jobs": gone_jobs, "watermark_allocs": wm,

@@ -749,11 +749,12 @@ _STAGE_OF: Dict[str, Tuple[str, str]] = {
     "solver.dispatch_solo": ("dispatch", "busy"),
     "solver.constcache": ("dispatch", "busy"),
     "solver.fixpoint": ("dispatch", "busy"),
-    # transfer-vs-compute split (solver/xferobs.py): the link model's
-    # predicted wire share of each dispatch vs the remainder -- the
-    # dispatch stage decomposed into link time and chip time
-    "solver.xfer_transfer": ("dispatch.transfer", "busy"),
-    "solver.xfer_compute": ("dispatch.compute", "busy"),
+    # the dispatch stage decomposed by its measured stage spans
+    # (solver/stages.py): put + fetch cross the link (the fetch also
+    # waits out the device's execution), launch is the jitted call
+    "solver.dispatch_put": ("dispatch.transfer", "busy"),
+    "solver.dispatch_fetch": ("dispatch.transfer", "busy"),
+    "solver.dispatch_launch": ("dispatch.compute", "busy"),
     "plan.submit": ("commit.wait", "wait"),
     "plan.evaluate": ("commit", "busy"),
     "plan.commit": ("commit", "busy"),

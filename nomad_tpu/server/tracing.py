@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -67,6 +68,27 @@ _SPAN_SINK = None
 def set_span_sink(sink) -> None:
     global _SPAN_SINK
     _SPAN_SINK = sink
+
+
+# One clock with the device trace: a `with` span also opens a
+# jax.profiler.TraceAnnotation of its own name, so a profile (the
+# operator's POST /v1/agent/jax-profile, or a benchmark's) carries the
+# program's spans on the device's timeline. Resolved lazily and only
+# from sys.modules: a host-only server never imports JAX for this, and
+# with no profile running the annotation is the profiler's own inactive
+# fast path.
+_ANNOTATION = None
+
+
+def _annotation_cls():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        # getattr-guarded: sys.modules can hand back a module another
+        # thread is still importing
+        prof = getattr(jax, "profiler", None)
+        _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+    return _ANNOTATION
 
 
 def _slow_ms() -> float:
@@ -181,7 +203,7 @@ class _SpanCM:
     """Context manager recording one span on exit; ``tag()`` adds tags
     mid-flight (e.g. the plan result, known only after the block)."""
 
-    __slots__ = ("_tracer", "_ctx", "_name", "_tags", "_t0")
+    __slots__ = ("_tracer", "_ctx", "_name", "_tags", "_t0", "_note")
 
     def __init__(self, tracer: "Tracer", ctx: Optional[TraceCtx],
                  name: str, tags: dict):
@@ -194,10 +216,16 @@ class _SpanCM:
         self._tags.update(kv)
 
     def __enter__(self) -> "_SpanCM":
+        cls = _annotation_cls()
+        self._note = cls(self._name) if cls is not None else None
+        if self._note is not None:
+            self._note.__enter__()
         self._t0 = time.time()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self._tags.setdefault("error", exc_type.__name__)
         self._tracer.record(
@@ -207,7 +235,7 @@ class _SpanCM:
 
 
 class _NullSpan:
-    """Shared no-op span: tracing disabled or no active context."""
+    """Shared no-op span: tracing disabled."""
 
     __slots__ = ()
 
@@ -310,14 +338,14 @@ class Tracer:
 
     # -- recording -----------------------------------------------------
     def span(self, name: str, ctx: Optional[TraceCtx] = None, **tags):
+        """A span with no context (work done for no one eval: the core
+        GC job) is stored in no trace but still annotates the profiler
+        and feeds the span sink."""
         if not trace_enabled():
             return _NULL_SPAN
-        ctx = self._resolve(ctx)
-        if ctx is None:
-            return _NULL_SPAN
-        return _SpanCM(self, ctx, name, tags)
+        return _SpanCM(self, self._resolve(ctx), name, tags)
 
-    def record(self, name: str, t0: float, dur_ms: float,
+    def record(self, name: str, t0: float, dur_ms: float, /,
                ctx: Optional[TraceCtx] = None, **tags) -> None:
         """Low-level span append (explicit start/duration -- the broker
         records the enqueue->dequeue wait retroactively at pop time)."""

@@ -6,6 +6,7 @@ SubmitPlan :650 / UpdateEval :721 / CreateEval :760 / ReblockEval :802).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import List, Optional, Tuple
@@ -13,6 +14,7 @@ from typing import List, Optional, Tuple
 from ..scheduler.factory import new_scheduler
 from ..structs import (
     Evaluation, Plan, PlanResult, EVAL_STATUS_BLOCKED, EVAL_STATUS_COMPLETE,
+    TRIGGER_JOB_REGISTER,
 )
 from .telemetry import metrics
 from .tracing import tracer
@@ -62,6 +64,9 @@ class WorkerPlanner:
         self.eval_token = eval_token
         self.eval_id = eval_id
         self.worker_name = worker_name
+        # index of the snapshot the eval is first solved against, set
+        # by invoke_scheduler; rides the eval's status update
+        self.snapshot_index = 0
 
     def submit_plan(self, plan: Plan) -> Tuple[Optional[PlanResult], object]:
         # stale-lease fence (reference: the plan applier's EvalToken
@@ -87,12 +92,16 @@ class WorkerPlanner:
         if result.rejected_nodes or (result.is_no_op() and not plan.is_no_op()):
             # partial/failed commit: scheduler refreshes its snapshot
             new_state = self.server.state.snapshot()
-        self.server.on_plan_result(plan, result)
+        with tracer.span("plan.on_result"):
+            self.server.on_plan_result(plan, result)
         return result, new_state
 
     def update_eval(self, ev: Evaluation) -> None:
-        self.server.state.upsert_evals([ev])
-        self.server.on_eval_update(ev)
+        # `ev` is the scheduler's own copy (_eval_with_status)
+        ev.snapshot_index = self.snapshot_index
+        with tracer.span("worker.update_eval", status=ev.status):
+            self.server.state.upsert_evals([ev])
+            self.server.on_eval_update(ev)
 
     def create_eval(self, ev: Evaluation) -> None:
         self.server.state.upsert_evals([ev])
@@ -192,6 +201,7 @@ def invoke_scheduler(server, ev: Evaluation, token: str,
         snapshot = server.state.snapshot()
         planner = WorkerPlanner(server, token, eval_id=ev.id,
                                 worker_name=worker_name)
+        planner.snapshot_index = snapshot.index
         sched_type = (ev.type if ev.type in
                       ("service", "batch", "system", "sysbatch")
                       else "service")
@@ -205,8 +215,14 @@ def invoke_scheduler(server, ev: Evaluation, token: str,
                 kwargs["batch"] = sched_type == "batch"
         sched = new_scheduler(name, snapshot, planner, **kwargs)
         from ..statecheck import eval_scope
+        # the placing evals alone: a job's stop sends a quick
+        # job-deregister eval through the timer by type as well, which
+        # halves its mean
+        placing = (metrics.measure("nomad.worker.invoke_register")
+                   if ev.triggered_by == TRIGGER_JOB_REGISTER
+                   else contextlib.nullcontext())
         with metrics.measure(
-                f"nomad.worker.invoke_scheduler_{sched_type}"), \
+                f"nomad.worker.invoke_scheduler_{sched_type}"), placing, \
                 tracer.span("worker.invoke", ctx=ctx, sched=sched_type), \
                 eval_scope(snapshot):
             # snapshot-isolation sanitizer scope (statecheck.py, inert

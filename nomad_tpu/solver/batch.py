@@ -36,7 +36,7 @@ import numpy as np
 
 from ..server.telemetry import metrics
 from ..server.tracing import tracer
-from . import xferobs
+from . import stages, xferobs
 from .service import PackedLane
 
 # Pad the fused eval axis to these sizes so XLA compiles one program per
@@ -464,10 +464,13 @@ def fuse_lanes(lanes: List[PackedLane], e_pad_hint: int = 0
     safe to run while an earlier generation's dispatch is in flight
     (the pipeline's prepare stage)."""
     groups: Dict[tuple, List[int]] = {}
-    for i, lane in enumerate(lanes):
-        groups.setdefault(lane.fuse_key(), []).append(i)
-    return [_fuse_group(lanes, idxs, key, e_pad_hint)
-            for key, idxs in groups.items()]
+    # at depth > 1 this runs on the pipeline's intake thread, outside
+    # the dispatch timer
+    with metrics.measure("nomad.solver.fuse"), tracer.span("solver.fuse"):
+        for i, lane in enumerate(lanes):
+            groups.setdefault(lane.fuse_key(), []).append(i)
+        return [_fuse_group(lanes, idxs, key, e_pad_hint)
+                for key, idxs in groups.items()]
 
 
 def solve_groups(lanes: List[PackedLane], groups: List[_FusedGroup],
@@ -479,7 +482,6 @@ def solve_groups(lanes: List[PackedLane], groups: List[_FusedGroup],
     results: List = [None] * len(lanes)
     try:
         for g in groups:
-            t0_wall = time.time()
             t0 = time.perf_counter()
             # transfer-ledger record for this generation: the payload
             # notes the transports emit below land in it, and its
@@ -492,21 +494,26 @@ def solve_groups(lanes: List[PackedLane], groups: List[_FusedGroup],
                     E=g.e_pad, e_real=g.e_real, P=g.p_pad,
                     wave=bool(g.wave), A=g.A,
                     in_flight=pipeline_state()["in_flight"])
-            try:
-                out = _dispatch(g.const, g.init, g.batch, g.spread_alg,
-                                g.dtype_name, use_mesh, ptab=g.ptab,
-                                pinit=g.pinit, wave=g.wave,
-                                cache_version=g.cache_version,
-                                delta_src=g.delta_src)
-            finally:
-                dt_ms = (time.perf_counter() - t0) * 1e3
-                xferobs.end_dispatch(dt_ms, t0_wall)
+            # the span is a host annotation on the profiler's timeline
+            # (server/tracing.py); the stage clock inside it says which
+            # of prep / put / launch / fetch held the dispatch
+            with tracer.span("solver.dispatch", E=g.e_pad,
+                             e_real=g.e_real, P=g.p_pad,
+                             wave=bool(g.wave), A=g.A,
+                             arena_reused=bool(g.arena_reused)) as sp, \
+                    stages.clock():
+                try:
+                    out = _dispatch(g.const, g.init, g.batch,
+                                    g.spread_alg, g.dtype_name, use_mesh,
+                                    ptab=g.ptab, pinit=g.pinit,
+                                    wave=g.wave,
+                                    cache_version=g.cache_version,
+                                    delta_src=g.delta_src)
+                finally:
+                    dt_ms = (time.perf_counter() - t0) * 1e3
+                    xferobs.end_dispatch(dt_ms)
+                sp.tag(slow_compile=dt_ms > 1000.0)
             metrics.sample_ms("nomad.solver.dispatch", dt_ms)
-            tracer.record("solver.dispatch", t0_wall, dt_ms,
-                          E=g.e_pad, e_real=g.e_real, P=g.p_pad,
-                          wave=bool(g.wave), A=g.A,
-                          arena_reused=bool(g.arena_reused),
-                          slow_compile=dt_ms > 1000.0)
             if dt_ms > 1000.0:
                 # a >1s dispatch on these shapes is an XLA compile, not
                 # compute; record which variant so warm-path stalls are
@@ -521,14 +528,17 @@ def solve_groups(lanes: List[PackedLane], groups: List[_FusedGroup],
                 chosen, scores, n_yielded, evict_rows = out
             else:
                 chosen, scores, n_yielded = out
-            for j, li in enumerate(g.idxs):
-                p_real = lanes[li].batch.ask_cpu.shape[0]
-                res = [np.asarray(chosen[j][:p_real]).astype(np.int64),
-                       np.asarray(scores[j][:p_real]),
-                       np.asarray(n_yielded[j][:p_real]).astype(np.int64)]
-                if g.A > 0:
-                    res.append(np.asarray(evict_rows[j][:p_real]))
-                results[li] = tuple(res)
+            with metrics.measure("nomad.solver.dispatch_unpack"), \
+                    tracer.span("solver.dispatch_unpack"):
+                for j, li in enumerate(g.idxs):
+                    p_real = lanes[li].batch.ask_cpu.shape[0]
+                    res = [np.asarray(chosen[j][:p_real]).astype(np.int64),
+                           np.asarray(scores[j][:p_real]),
+                           np.asarray(n_yielded[j][:p_real]).astype(
+                               np.int64)]
+                    if g.A > 0:
+                        res.append(np.asarray(evict_rows[j][:p_real]))
+                    results[li] = tuple(res)
     finally:
         for g in groups:
             if g.entry is not None:
@@ -606,11 +616,14 @@ def _dispatch(const, init, batch, spread_alg: bool, dtype_name: str,
         from ..parallel.mesh import mesh_solve_fn
         metrics.incr("nomad.solver.mesh_dispatches")
         with mesh:
+            stages.mark("put")
             s_const, s_init, s_batch = shard_solver_inputs(
                 mesh, const, init, batch, version=cache_version,
                 delta_src=delta_src)
+            stages.mark("launch")
             fn = mesh_solve_fn(mesh, spread_alg, dtype_name)
             chosen, scores, n_yielded = fn(s_const, s_init, s_batch)
+        stages.mark("fetch")
         from .. import jitcheck
         with jitcheck.sanctioned_fetch("mesh"):
             # the mesh path's one bulk fetch: gather + host copy

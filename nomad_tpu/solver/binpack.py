@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import jitcheck
+from . import stages
 
 
 def _single_flight(fn):
@@ -1000,12 +1001,15 @@ def solve_lane_fused(const, init, batch, ptab=None, pinit=None, *,
     # each stacked buffer's tree group for the transfer ledger; the
     # stacked buffers are _fuse_trees' fresh np.stack outputs, so the
     # version chain may retain them as frozen shadows without copying.
+    stages.mark("put")
     buffers, _ = device_put_cached(
         stacked, version=cache_version,
         cacheable=[k[0] == 0 for k in group_keys],
         tags=[_FUSE_TREE_NAMES[k[0]] for k in group_keys],
         delta_src=delta_src)
+    stages.mark("launch")
     out = fn(*buffers)
+    stages.mark("fetch")
     # the 3-way output axis is leading in both forms: (3, P) or (3, E, P)
     if ptab is not None:
         with jitcheck.sanctioned_fetch("fused_preempt"):
@@ -2580,12 +2584,15 @@ def solve_lane_wave_preempt(const, init, batch, ptab, pinit, *,
     fn = _wave_preempt_program(compact.shape, cand["cpu"].shape,
                                counts0.shape, spread_alg, dtype_name,
                                batched, B)
+    stages.mark("put")
     cm, cd, sf, si, pn, c0 = _put_eval_sharded(
         batched, compact.shape[0],
         (compact, cand, scal_f, scal_i, pen, counts0),
         cache_version=cache_version, tag="compact_preempt",
         delta_src=delta_src)
+    stages.mark("launch")
     out = fn(cm, cd, sf, si, pn, c0)
+    stages.mark("fetch")
     with jitcheck.sanctioned_fetch("wave_preempt"):
         combined, ev = jax.device_get(out)
     from . import xferobs
@@ -2753,10 +2760,13 @@ def solve_lane_wave(const, init, batch, *, spread_alg: bool,
     fn = _wave_compact_program(compact.shape, sp.counts.shape,
                                spread_alg, dtype_name, batched, B,
                                use_block)
+    stages.mark("put")
     cm, sf, si, pn, spd = _put_eval_sharded(
         batched, compact.shape[0], (compact, scal_f, scal_i, pen, sp),
         cache_version=cache_version, delta_src=delta_src)
+    stages.mark("launch")
     out = fn(cm, sf, si, pn, spd)
+    stages.mark("fetch")
     with jitcheck.sanctioned_fetch("wave"):
         combined = jax.device_get(out)
     from . import xferobs

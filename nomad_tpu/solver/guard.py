@@ -359,7 +359,7 @@ def run_dispatch(fn, label: str = "solver.dispatch",
     """
     from ..faultinject import faults
     from ..server.telemetry import metrics
-    from ..server.tracing import tracer
+    from ..server.tracing import trace_enabled, tracer
     from .. import jitcheck, lockcheck, schedcheck
 
     if lockcheck._ACTIVE:
@@ -379,7 +379,14 @@ def run_dispatch(fn, label: str = "solver.dispatch",
     trace_ctx = tracer.current()
     eval_tag = ",".join(tracer.current_ids()) or "-"
 
+    # what a thread per dispatch costs under a busy interpreter:
+    # (runner's first instruction - entry) + (caller resumes - runner's
+    # last instruction), timer nomad.solver.guard_handoff
+    clocked = trace_enabled()
+    t_entry = time.perf_counter()
+
     def runner() -> None:
+        box["t_first"] = time.perf_counter()
         # jitcheck hot region: host syncs between here and the fn()
         # return are hot-path syncs (jitcheck.py check b). Gated on one
         # module-attr read when off, like the lockcheck hook above.
@@ -395,12 +402,21 @@ def run_dispatch(fn, label: str = "solver.dispatch",
         finally:
             if hot:
                 jitcheck.note_dispatch_end()
+            box["t_last"] = time.perf_counter()
 
     if timeout <= 0:
         runner()
     else:
         expired, _ = _run_under_deadline(runner, f"dispatch-{label}",
                                          timeout)
+        if clocked:
+            # a runner that never started or never ended (the timeout
+            # path) has handed nothing back: its side counts up to now
+            now = time.perf_counter()
+            metrics.sample_ms(
+                "nomad.solver.guard_handoff",
+                ((box.get("t_first", now) - t_entry)
+                 + (now - box.get("t_last", now))) * 1e3)
         if expired:
             metrics.incr("nomad.solver.dispatch_timeout")
             record_dispatch_failure("timeout")

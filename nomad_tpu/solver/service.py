@@ -1062,6 +1062,14 @@ class TpuPlacementService:
 
         with_ports = bool(tg.networks)
         with (lock if lock is not None else contextlib.nullcontext()):
+            if store is not None:
+                # the table folded here is the live one: how far its
+                # last alloc write lies past the snapshot whose index
+                # seeds this eval's shuffle (PERF.md open question 1)
+                from ..server.telemetry import metrics as _tm
+                _tm.sample("nomad.solver.pack_usage_ahead", float(max(
+                    0, store._table_index.get("allocs", 0)
+                    - self.ctx.state.index)))
             # fold cache: all lanes of one barrier generation pack from
             # the same table version against the same (version-keyed)
             # matrix -- fold once, hand out copies (the overlay mutates

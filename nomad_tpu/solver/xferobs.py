@@ -7,7 +7,7 @@ payload are both judged in bytes; this module makes those bytes a
 continuous, per-dispatch accounting layer instead of a post-mortem.  Sibling of tracing/quality in design: always cheap,
 process-global, read-side derivation, and a true kill switch.
 
-Four coupled pieces:
+Three coupled pieces:
 
 1. **Per-dispatch payload ledger** (`_Ledger`): every transfer the
    dispatch stack performs is attributed to a tree group -- ``const``
@@ -40,12 +40,11 @@ Four coupled pieces:
    transfer time equals the round trip -- the ROADMAP-4 target is a
    steady-state payload far below it).
 
-4. **Transfer-vs-compute split**: when the fit is warm, each dispatch
-   records ``solver.xfer_transfer`` / ``solver.xfer_compute`` spans
-   (model-predicted transfer share vs the remainder) into the eval
-   trace and the PR-7 saturation attribution (new ``dispatch.transfer``
-   / ``dispatch.compute`` stages), so "the dispatch stage is busy"
-   decomposes into wire time vs chip time.
+The split of a dispatch's wall time into link time and chip time is
+measured, not modelled: the ``solver.dispatch_put`` /
+``solver.dispatch_fetch`` / ``solver.dispatch_launch`` stage spans
+(solver/stages.py) feed the saturation attribution's
+``dispatch.transfer`` / ``dispatch.compute`` stages.
 
 Kill switch: ``NOMAD_TPU_XFEROBS=0`` -- every entry point returns
 before touching any state (bitwise no-op, parity-tested).  Bounds:
@@ -485,12 +484,11 @@ def begin_dispatch(**meta) -> None:
     _LEDGER.begin_dispatch(**meta)
 
 
-def end_dispatch(dur_ms: float, t0_wall: Optional[float] = None) -> None:
-    """Close the open record: feed the link fit, emit the
-    ``nomad.xfer.*`` gauges, and (when the fit is warm) record the
-    transfer-vs-compute split spans into the active trace ctx.  Gated
-    on the record itself (begin_dispatch consulted the kill switch;
-    no record ever opens while it is off)."""
+def end_dispatch(dur_ms: float) -> None:
+    """Close the open record: feed the link fit and emit the
+    ``nomad.xfer.*`` gauges.  Gated on the record itself
+    (begin_dispatch consulted the kill switch; no record ever opens
+    while it is off)."""
     rec = _LEDGER.end_dispatch(dur_ms)
     if rec is None:
         return
@@ -508,18 +506,6 @@ def end_dispatch(dur_ms: float, t0_wall: Optional[float] = None) -> None:
     if slope > 0:
         metrics.sample("nomad.xfer.bw_mbps",
                        round((1e3 / slope) / 1e6, 3))
-    # transfer-vs-compute split: the model's predicted wire share of
-    # this dispatch vs the remainder, recorded as spans so the PR-7
-    # saturation attribution grows dispatch.transfer/dispatch.compute
-    # stages and the eval waterfall shows the split per generation
-    payload = rec["shipped_bytes"] + rec["fetched_bytes"]
-    est_transfer = min(max(rtt + slope * payload, 0.0), dur_ms)
-    t0 = t0_wall if t0_wall is not None else rec["t0"]
-    from ..server.tracing import tracer
-    tracer.record("solver.xfer_transfer", t0, est_transfer,
-                  payload_bytes=payload)
-    tracer.record("solver.xfer_compute", t0 + est_transfer / 1e3,
-                  max(dur_ms - est_transfer, 0.0))
 
 
 def mark() -> int:

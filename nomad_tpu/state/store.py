@@ -15,6 +15,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from .. import schedcheck
 from .alloc_table import AllocTable
+from .storelock import make_store_lock
 from ..structs import (
     ACL_TOKEN_TYPE_MANAGEMENT, ACLPolicy, ACLToken, Allocation, Deployment,
     Evaluation, Job, Namespace, Node, NodePool, Plan, PlanResult, RootKey,
@@ -324,7 +325,8 @@ class StateStore:
     under one lock, bumping the index exactly once per logical write."""
 
     def __init__(self) -> None:
-        self._lock = threading.RLock()
+        # the RLock behind an account of who waits for it (storelock.py)
+        self._lock = make_store_lock()
         self._index = 1
         self._table_index: Dict[str, int] = {t: 1 for t in TABLES}
         self._nodes: Dict[str, Node] = {}
@@ -419,7 +421,9 @@ class StateStore:
         deadline = None
         import time as _time
         deadline = _time.monotonic() + timeout
-        with self._watch_cond:
+        # the condition's own lock, taken here so that the lock's
+        # account names this method as the holder
+        with self._lock:
             while True:
                 cur = (self.table_index(*tables) if tables else self._index)
                 if cur > min_index:

@@ -261,7 +261,7 @@ def test_ledger_overhead_under_two_percent():
             xferobs.note_payload("const", 65536)
         xferobs.note_shipped(8 * 65536)
         xferobs.note_fetch(4096, "wave")
-        xferobs.end_dispatch(3.0, time.time())
+        xferobs.end_dispatch(3.0)
 
     best = None
     for _ in range(10):     # min over enough windows to find a quiet one
@@ -307,23 +307,33 @@ def test_link_model_recovers_rtt_and_bandwidth():
 
 def test_link_fit_feeds_metrics_and_split_spans():
     """After >=8 recorded dispatches the fit emits nomad.xfer.rtt_ms /
-    bw_mbps gauges and records the transfer-vs-compute split spans the
-    saturation attribution maps to dispatch.transfer/.compute."""
-    for i in range(10):
-        xferobs.begin_dispatch(E=2, in_flight=0)
-        xferobs.note_payload("const", 10000 * (i + 1))
-        xferobs.note_shipped(10000 * (i + 1))
-        xferobs.end_dispatch(2.0 + 0.0001 * 10000 * (i + 1), time.time())
+    bw_mbps gauges. The transfer-vs-compute split of the saturation
+    attribution is fed by the measured dispatch stage spans
+    (solver/stages.py), no longer by spans the link model invents."""
+    from nomad_tpu.server import tracing
+    seen = []
+    prev = tracing._SPAN_SINK
+    tracing.set_span_sink(lambda name, dur_ms: seen.append(name))
+    try:
+        for i in range(10):
+            xferobs.begin_dispatch(E=2, in_flight=0)
+            xferobs.note_payload("const", 10000 * (i + 1))
+            xferobs.note_shipped(10000 * (i + 1))
+            xferobs.end_dispatch(2.0 + 0.0001 * 10000 * (i + 1))
+    finally:
+        tracing.set_span_sink(prev)
     snap = metrics.snapshot()
     assert snap["gauges"]["nomad.xfer.rtt_ms"]["count"] > 0
     assert snap["gauges"]["nomad.xfer.bw_mbps"]["count"] > 0
     assert snap["counters"]["nomad.xfer.dispatches"] == 10
-    # the stage map turns the split spans into their own stages
+    assert not seen, "the ledger records no span of its own"
+    # the stage map turns the measured stage spans into their stages
     from nomad_tpu.server.quality import _STAGE_OF
-    assert _STAGE_OF["solver.xfer_transfer"] == ("dispatch.transfer",
-                                                 "busy")
-    assert _STAGE_OF["solver.xfer_compute"] == ("dispatch.compute",
-                                                "busy")
+    assert _STAGE_OF["solver.dispatch_put"] == ("dispatch.transfer", "busy")
+    assert _STAGE_OF["solver.dispatch_fetch"] == ("dispatch.transfer",
+                                                  "busy")
+    assert _STAGE_OF["solver.dispatch_launch"] == ("dispatch.compute",
+                                                   "busy")
 
 
 # ---------------------------------------------------------------------------
@@ -388,7 +398,7 @@ def test_counter_events_render_perfetto_tracks(tmp_path):
         xferobs.begin_dispatch(E=1, in_flight=i)
         xferobs.note_payload("const", 1000)
         xferobs.note_shipped(1000)
-        xferobs.end_dispatch(1.0, time.time())
+        xferobs.end_dispatch(1.0)
     events = xferobs.counter_events()
     names = {e["name"] for e in events}
     assert names == {"xfer shipped bytes", "xfer resident bytes",
