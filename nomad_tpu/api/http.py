@@ -321,8 +321,10 @@ class ApiHandler(BaseHTTPRequestHandler):
         self._error(403, "Permission denied")
         return False
 
-    def _blocking(self, query, tables=()) -> int:
-        """Apply ?index/?wait blocking semantics; returns current index."""
+    def _blocking(self, query, keys=()) -> int:
+        """Apply ?index/?wait blocking semantics; returns current index.
+        ``keys``: the items the route reads (state/watch.py); a route
+        that gives none waits for any write."""
         q = parse_qs(query)
         if "index" in q:
             min_index = int(q["index"][0])
@@ -333,7 +335,7 @@ class ApiHandler(BaseHTTPRequestHandler):
             # can't pin a handler thread arbitrarily long
             wait = min(wait, 300.0)
             return self.nomad.state.block_until(min_index, timeout=wait,
-                                                tables=tables)
+                                                keys=keys)
         return self.nomad.state.latest_index()
 
     # ------------------------------------------------------------------
@@ -388,14 +390,21 @@ class ApiHandler(BaseHTTPRequestHandler):
             return self._serve_ui(parts)
         state = self.nomad.state
         try:
-            # the node alloc watch blocks on the allocs table only, so
-            # unrelated writes don't wake every polling node
-            tables = (("allocs",) if parts[:2] == ["v1", "node"]
-                      and len(parts) == 4 and parts[3] == "allocations"
-                      else ())
             q = parse_qs(url.query)
             ns = q.get("namespace", ["default"])[0]
             acl = self._acl()
+            # a route that reads one job's or one node's items is woken
+            # by writes to that job or node only; list routes by any
+            keys = ()
+            if parts[:2] == ["v1", "job"] and (
+                    len(parts) == 3 or len(parts) == 4 and parts[3] in (
+                        "summary", "allocations", "evaluations",
+                        "deployment")):
+                keys = (("job", ns, parts[2]),)
+            elif parts[:2] == ["v1", "node"] and (
+                    len(parts) == 3 and parts[2] not in ("pools", "pool")
+                    or len(parts) == 4 and parts[3] == "allocations"):
+                keys = (("node", parts[2]),)
             from ..acl import CAP_LIST_JOBS, CAP_READ_JOB
             # authorize BEFORE the blocking wait so a denied request can't
             # pin a server thread for the full ?wait duration; namespaced
@@ -408,7 +417,7 @@ class ApiHandler(BaseHTTPRequestHandler):
                 if parts != ["v1", "acl", "token", "self"] and \
                         not self._check(acl.is_management()):
                     return
-                index = self._blocking(url.query, tables)
+                index = self._blocking(url.query, keys)
                 return self._acl_get(parts, acl, index)
             if parts[1:2] == ["operator"]:
                 if not self._check(acl.allow_operator_read()):
@@ -464,7 +473,7 @@ class ApiHandler(BaseHTTPRequestHandler):
             elif parts == ["v1", "metrics"]:
                 if not self._check(acl.allow_agent_read()):
                     return
-            index = self._blocking(url.query, tables)
+            index = self._blocking(url.query, keys)
             if parts[:2] == ["v1", "jobs"] and len(parts) == 2:
                 # ?prefix= filtering like every reference list endpoint
                 prefix = q.get("prefix", [""])[0]

@@ -80,8 +80,8 @@ class LocalServerConn(ServerConn):
 
     def pull_allocs(self, node_id: str, min_index: int,
                     timeout: float) -> tuple:
-        index = self.server.state.block_until(min_index, timeout=timeout,
-                                              tables=("allocs",))
+        index = self.server.state.block_until(
+            min_index, timeout=timeout, keys=(("node", node_id),))
         return self.server.state.allocs_by_node(node_id), index
 
     def update_allocs(self, updates: List[Allocation]) -> None:

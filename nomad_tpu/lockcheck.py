@@ -444,11 +444,6 @@ class _InstrumentedCondition(_REAL_CONDITION):
     at the next scheduling decision -- which is what makes condvar
     handoff order a deterministic function of the schedule seed."""
 
-    def _lc_lock(self):
-        """The instrumented lock this condition waits on: its own, or
-        the one under a wrapper that says so (state/storelock.py)."""
-        return getattr(self._lock, "_lc_wrapped", self._lock)
-
     def wait(self, timeout=None):
         if _schedcheck._ACTIVE and _schedcheck.managed_active():
             state = self._release_save()
@@ -456,14 +451,14 @@ class _InstrumentedCondition(_REAL_CONDITION):
                 notified = _schedcheck.cond_wait_gate(
                     id(self), timed=timeout is not None)
             finally:
-                inner = getattr(self._lc_lock(), "_lc_inner", None)
+                inner = getattr(self._lock, "_lc_inner", None)
                 if inner is not None:
                     _schedcheck.lock_gate(inner, "cond.reacquire")
                 self._acquire_restore(state)
             return notified
         if not _ACTIVE:
             return super().wait(timeout)
-        others = _held_other(exclude=self._lc_lock())
+        others = _held_other(exclude=self._lock)
         if not others:
             return super().wait(timeout)
         t0 = time.monotonic()

@@ -1812,12 +1812,12 @@ class Server:
         cutoff = time.time() - threshold
         gone_evals = []
         evals = self.state.evals()
-        terminal_evals = 0
+        walked = 0
         for ev in evals:
             if not ev.terminal_status():
                 continue
-            terminal_evals += 1
             allocs = self.state.allocs_by_eval(ev.id)
+            walked += len(allocs)
             if all(a.terminal_status() for a in allocs) and \
                     ev.modify_time < cutoff:
                 gone_evals.append(ev.id)
@@ -1826,10 +1826,10 @@ class Server:
         gone_set = set(gone_evals)
         all_allocs = self.state.allocs()
         metrics.incr("nomad.core.gc_evals_scanned", len(evals))
-        # allocs_by_eval walks the whole table for every terminal eval,
-        # then the sweep below walks it once more
+        # each terminal eval's own allocs (an index by eval), then the
+        # sweep below walks the whole table once
         metrics.incr("nomad.core.gc_allocs_scanned",
-                     (terminal_evals + 1) * len(all_allocs))
+                     walked + len(all_allocs))
         gone_allocs = [
             a.id for a in all_allocs
             if a.terminal_status() and a.modify_time < cutoff

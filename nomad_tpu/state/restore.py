@@ -89,18 +89,23 @@ def restore_state(store: "StateStore", blob: dict) -> None:
         store._node_pools = {p.name: p for p in pools}
         if sched_cfg is not None:
             store._scheduler_config = sched_cfg
-        # rebuild secondary indexes (and drop the snapshot cache + its
-        # incremental-copy base: both refer to the replaced dicts)
-        store._allocs_by_node = {}
-        store._allocs_by_job = {}
+        # rebuild secondary indexes (and drop the snapshot cache: it
+        # refers to the replaced dicts)
         store._snap_cache = None
-        store._snap_prev = None
-        store._dirty_alloc_nodes.clear()
-        store._dirty_alloc_jobs.clear()
+        by_node, by_job, by_eval, evals_by_job = {}, {}, {}, {}
         for a in allocs:
-            store._allocs_by_node.setdefault(a.node_id, {})[a.id] = None
-            store._allocs_by_job.setdefault(
-                (a.namespace, a.job_id), {})[a.id] = None
+            by_node.setdefault(a.node_id, []).append(a.id)
+            by_job.setdefault((a.namespace, a.job_id), []).append(a.id)
+            if a.eval_id:
+                by_eval.setdefault(a.eval_id, []).append(a.id)
+        for e in evals:
+            evals_by_job.setdefault((e.namespace, e.job_id),
+                                    []).append(e.id)
+        store._allocs_by_node = {k: tuple(v) for k, v in by_node.items()}
+        store._allocs_by_job = {k: tuple(v) for k, v in by_job.items()}
+        store._allocs_by_eval = {k: tuple(v) for k, v in by_eval.items()}
+        store._evals_by_job = {k: tuple(v)
+                               for k, v in evals_by_job.items()}
         # re-link alloc.job to the stored job (codec duplicates the object)
         for a in allocs:
             stored = store._jobs.get((a.namespace, a.job_id))
@@ -136,4 +141,5 @@ def restore_state(store: "StateStore", blob: dict) -> None:
         table.upsert_many(
             [a for a in allocs if not a.client_terminal_status()])
         store.alloc_table = table
-        store._watch_cond.notify_all()
+        # every item may have changed: forget the watch keys, wake all
+        store._watch.publish(store._index, store._table_index, None)

@@ -10,12 +10,12 @@ guarantee), and watchers block until a table index advances
 """
 from __future__ import annotations
 
-import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
 from .. import schedcheck
 from .alloc_table import AllocTable
 from .storelock import make_store_lock
+from .watch import WatchRegistry
 from ..structs import (
     ACL_TOKEN_TYPE_MANAGEMENT, ACLPolicy, ACLToken, Allocation, Deployment,
     Evaluation, Job, Namespace, Node, NodePool, Plan, PlanResult, RootKey,
@@ -46,6 +46,30 @@ def _delta_journal_cap() -> int:
                                          "128")))
     except ValueError:
         return 128
+
+
+def _eval_keys(evals) -> list:
+    """Watch keys of the jobs these evals belong to."""
+    return [("job", ev.namespace, ev.job_id) for ev in evals]
+
+
+def _deployment_keys(deployments) -> list:
+    return [("job", d.namespace, d.job_id) for d in deployments
+            if d is not None]
+
+
+def _unindex(index: dict, gone) -> None:
+    """Take (key, id) pairs out of a copy-on-write secondary index: one
+    new tuple per touched key, the key itself when nothing is left."""
+    by_key: Dict[object, set] = {}
+    for k, i in gone:
+        by_key.setdefault(k, set()).add(i)
+    for k, ids in by_key.items():
+        left = tuple(i for i in index.get(k, ()) if i not in ids)
+        if left:
+            index[k] = left
+        else:
+            index.pop(k, None)
 
 
 class _DeltaAllocs:
@@ -146,38 +170,11 @@ class StateSnapshot:
             # plan applier re-verifies every plan against latest state
             self.alloc_table = store.alloc_table
             self._store = store
-            # secondary indexes: incremental copy-on-write. Snapshots are
-            # immutable, so a new snapshot reuses the previous snapshot's
-            # inner id-set copies for every key the store has not touched
-            # since -- a full {k: dict(v)} walk is ~120K dict inserts at
-            # 10K nodes and was a top-5 leaf in the headline e2e profile.
-            prev = store._snap_prev
-            if prev is None:
-                by_node = {k: dict(v)
-                           for k, v in store._allocs_by_node.items()}
-                by_job = {k: dict(v)
-                          for k, v in store._allocs_by_job.items()}
-            else:
-                pn, pj = prev
-                by_node = dict(pn)
-                for k in store._dirty_alloc_nodes:
-                    src = store._allocs_by_node.get(k)
-                    if src:
-                        by_node[k] = dict(src)
-                    else:
-                        by_node.pop(k, None)
-                by_job = dict(pj)
-                for k in store._dirty_alloc_jobs:
-                    src = store._allocs_by_job.get(k)
-                    if src:
-                        by_job[k] = dict(src)
-                    else:
-                        by_job.pop(k, None)
-            store._dirty_alloc_nodes.clear()
-            store._dirty_alloc_jobs.clear()
-            store._snap_prev = (by_node, by_job)
-            self._allocs_by_node = by_node
-            self._allocs_by_job = by_job
+            # secondary indexes: the store publishes an immutable id
+            # tuple per key (copy-on-write), so a snapshot shares them
+            # and copies only the outer dicts
+            self._allocs_by_node = dict(store._allocs_by_node)
+            self._allocs_by_job = dict(store._allocs_by_job)
             self._csi_volumes = dict(store._csi_volumes)
             self._csi_plugins = dict(store._csi_plugins)
 
@@ -322,7 +319,23 @@ class StateSnapshot:
 class StateStore:
     """The live, writable store. All writes go through raft in the reference
     (fsm.go:211 nomadFSM.Apply); here the FSM calls these methods directly
-    under one lock, bumping the index exactly once per logical write."""
+    under one lock, bumping the index exactly once per logical write.
+
+    The reader contract -- a reader never waits for a writer's hand-over:
+    writers hold ``_lock`` and REPLACE stored objects, never edit one in
+    place. So point getters (LOCK_FREE_POINT_READS: ``node_by_id``,
+    ``job_by_id``, ``eval_by_id``, ``alloc_by_id``, ``latest_index``,
+    ...) are one read of one table and take no lock; a read that starts
+    after a write returned sees it. ``allocs_by_job``, ``allocs_by_node``,
+    ``allocs_by_eval`` and ``evals_by_job`` (LOCK_FREE_WALKS) walk a
+    copy-on-write id tuple
+    the writer published whole, one point read an id: whole objects, not
+    one point in time. Everything that iterates a live table keeps the
+    lock; several tables that must agree are read from ``snapshot()``.
+    A blocking query (``block_until``) waits in the watch registry, on a
+    lock of its own (order: store -> watch), and is woken by writes to
+    the job or node it names, to its tables, or when the index passes
+    its N -- not by every write."""
 
     def __init__(self) -> None:
         # the RLock behind an account of who waits for it (storelock.py)
@@ -363,25 +376,27 @@ class StateStore:
         # native service catalog (reference: state_store.go
         # service_registration region), keyed by registration id
         self._services: Dict[str, "ServiceRegistration"] = {}
-        # secondary indexes: insertion-ordered id sets (dict keys). Plain
-        # lists made _insert_allocs_locked O(K^2) in a job's alloc count
-        # (a membership scan per insert) -- 70ms of a 2000-alloc plan
-        # commit was this scan.
-        self._allocs_by_node: Dict[str, Dict[str, None]] = {}
-        self._allocs_by_job: Dict[Tuple[str, str], Dict[str, None]] = {}
+        # secondary indexes, copy-on-write: key -> tuple of ids in
+        # insertion order. A writer publishes a NEW tuple per touched
+        # key and never edits one in place, so a reader walks the tuple
+        # it fetched without the lock (allocs_by_job, allocs_by_node,
+        # allocs_by_eval, evals_by_job) and a snapshot shares it.
+        self._allocs_by_node: Dict[str, Tuple[str, ...]] = {}
+        self._allocs_by_job: Dict[Tuple[str, str], Tuple[str, ...]] = {}
+        # by the eval that placed (or last updated in place) the alloc;
+        # allocs of no eval ("") are not indexed
+        self._allocs_by_eval: Dict[str, Tuple[str, ...]] = {}
+        self._evals_by_job: Dict[Tuple[str, str], Tuple[str, ...]] = {}
         # snapshot cache: one StateSnapshot build per index (any write
-        # invalidates); _snap_prev/_dirty_* feed the incremental
-        # secondary-index copies in StateSnapshot.__init__
+        # invalidates)
         self._snap_cache: Optional[StateSnapshot] = None
-        self._snap_prev = None
-        self._dirty_alloc_nodes: set = set()
-        self._dirty_alloc_jobs: set = set()
         # (alloc table index, mapping) of the last snapshot's alloc view:
         # the base the next snapshot delta-advances from (ISSUE 17,
         # native control plane; see _snapshot_allocs_locked)
         self._snap_alloc_prev: Optional[Tuple[int, object]] = None
-        # watch support
-        self._watch_cond = threading.Condition(self._lock)
+        # blocking queries wait here, off the store lock (watch.py);
+        # lock order store -> watch
+        self._watch = WatchRegistry(self._index, TABLES)
         # bounded journal of alloc-level write deltas: (index, pairs)
         # where pairs is [(old_alloc|None, new_alloc|None), ...] or None
         # for writes with no structured delta. Lets incremental memo
@@ -407,33 +422,25 @@ class StateStore:
 
     # -- watch / blocking query ---------------------------------------------
     def latest_index(self) -> int:
-        with self._lock:
-            return self._index
+        return self._index
 
     def table_index(self, *tables: str) -> int:
-        with self._lock:
-            return max(self._table_index.get(t, 0) for t in tables)
+        return max(self._table_index.get(t, 0) for t in tables)
 
     def block_until(self, min_index: int, timeout: float = 5.0,
-                    tables: Tuple[str, ...] = ()) -> int:
-        """Wait until the (table) index passes min_index
-        (reference: blockingRPC nomad/rpc.go:852). Returns current index."""
-        deadline = None
-        import time as _time
-        deadline = _time.monotonic() + timeout
-        # the condition's own lock, taken here so that the lock's
-        # account names this method as the holder
-        with self._lock:
-            while True:
-                cur = (self.table_index(*tables) if tables else self._index)
-                if cur > min_index:
-                    return self._index
-                remaining = deadline - _time.monotonic()
-                if remaining <= 0:
-                    return self._index
-                self._watch_cond.wait(remaining)
+                    tables: Tuple[str, ...] = (),
+                    keys: Tuple[Tuple[str, ...], ...] = ()) -> int:
+        """Wait until a write past min_index touched what the query
+        read (reference: blockingRPC nomad/rpc.go:852 over a memdb
+        watch set): one of the items ``keys`` -- ``("job", ns, id)``,
+        ``("node", id)`` --, else one of ``tables``, else any write at
+        all (the worker's wait for index N). Returns the store's
+        current index. The waiter stands on the watch registry's lock,
+        never on the store's (watch.py)."""
+        self._watch.wait(min_index, timeout, tables, keys)
+        return self._index
 
-    def _bump(self, *tables: str, delta=None) -> int:
+    def _bump(self, *tables: str, delta=None, keys=()) -> int:
         """Advance the raft-style index for a logical write. ``delta``
         carries the write's alloc-level change set -- a list of
         (old_alloc_or_None, new_alloc_or_None) pairs -- when the caller
@@ -441,7 +448,10 @@ class StateStore:
         layers get it through ONE delta-aware notification instead of a
         bare "something changed", and the bounded journal below lets
         incremental memo holders catch a stale base up to the current
-        index by applying the missed deltas instead of refolding."""
+        index by applying the missed deltas instead of refolding.
+        ``keys`` are the watch keys the write touched besides those of
+        the delta's allocs (their job and node); an alloc write without
+        a delta (a restore) cannot say, and wakes every watcher."""
         if schedcheck._ACTIVE:
             # schedule-explorer interposition: every index bump is a
             # decision point (one module-attr read when off)
@@ -458,8 +468,32 @@ class StateStore:
         if hook is not None:
             hook(tables, self._index, delta)
         self._notify_write_hooks(tables, self._index, delta)
-        self._watch_cond.notify_all()
+        self._publish_locked(tables, delta, keys)
         return self._index
+
+    def _publish_locked(self, tables, delta, keys) -> None:
+        """Tell the watch registry which items this write touched, and
+        which keys went with their job or node (nothing is left to
+        watch under them). Store lock held: store -> watch."""
+        if "allocs" in tables and delta is None:
+            self._watch.publish(self._index, tables, None)
+            return
+        touched = set(keys)
+        for old, new in delta or ():
+            a = new if new is not None else old
+            touched.add(("job", a.namespace, a.job_id))
+            touched.add(("node", a.node_id))
+        dropped = []
+        for k in touched:
+            if k[0] == "job":
+                jk = k[1:]
+                if jk not in self._jobs and not self._allocs_by_job.get(jk) \
+                        and not self._evals_by_job.get(jk):
+                    dropped.append(k)
+            elif k[1] not in self._nodes and \
+                    not self._allocs_by_node.get(k[1]):
+                dropped.append(k)
+        self._watch.publish(self._index, tables, touched, dropped)
 
     @staticmethod
     def _notify_write_hooks(tables, index: int, delta) -> None:
@@ -591,7 +625,7 @@ class StateStore:
                 node.compute_class()
             self._nodes[node.id] = node
             self.alloc_table.register_node(node)
-            idx = self._bump("nodes")
+            idx = self._bump("nodes", keys=(("node", node.id),))
             # the recompute walks every node; skip it when this write
             # cannot change plugin state (no CSI fingerprints on the new
             # node and none aggregated fleet-wide) -- otherwise a 10K-node
@@ -603,7 +637,7 @@ class StateStore:
     def delete_node(self, node_id: str) -> int:
         with self._lock:
             node = self._nodes.pop(node_id, None)
-            idx = self._bump("nodes")
+            idx = self._bump("nodes", keys=(("node", node_id),))
             if (node is not None and node.csi_node_plugins) \
                     or self._csi_plugins:
                 self._recompute_csi_plugins_locked()
@@ -621,7 +655,7 @@ class StateStore:
             node.status_updated_at = updated_at
             node.modify_index = self._index + 1
             self._nodes[node_id] = node
-            idx = self._bump("nodes")
+            idx = self._bump("nodes", keys=(("node", node_id),))
             if node.csi_node_plugins or self._csi_plugins:
                 self._recompute_csi_plugins_locked()
             return idx
@@ -636,7 +670,7 @@ class StateStore:
             node.scheduling_eligibility = eligibility
             node.modify_index = self._index + 1
             self._nodes[node_id] = node
-            idx = self._bump("nodes")
+            idx = self._bump("nodes", keys=(("node", node_id),))
             if node.csi_node_plugins or self._csi_plugins:
                 self._recompute_csi_plugins_locked()
             return idx
@@ -657,7 +691,7 @@ class StateStore:
                 node.scheduling_eligibility = NODE_SCHED_ELIGIBLE
             node.modify_index = self._index + 1
             self._nodes[node_id] = node
-            idx = self._bump("nodes")
+            idx = self._bump("nodes", keys=(("node", node_id),))
             if node.csi_node_plugins or self._csi_plugins:
                 self._recompute_csi_plugins_locked()
             return idx
@@ -680,7 +714,8 @@ class StateStore:
             self._jobs[key] = job
             self._job_versions[(job.namespace, job.id, job.version)] = job
             self._update_job_scaling_policies_locked(job)
-            return self._bump("jobs", "job_versions")
+            return self._bump("jobs", "job_versions",
+                              keys=(("job",) + key,))
 
     def _update_job_scaling_policies_locked(self, job: Job) -> None:
         """Re-derive the job's scaling policies from its groups' scaling
@@ -736,7 +771,7 @@ class StateStore:
             job.modify_index = self._index + 1
             self._jobs[key] = job
             self._job_versions[(namespace, job_id, job.version)] = job
-            return self._bump("jobs")
+            return self._bump("jobs", keys=(("job",) + key,))
 
     def delete_job(self, namespace: str, job_id: str) -> int:
         with self._lock:
@@ -748,12 +783,12 @@ class StateStore:
                 if (pol.namespace, pol.job_id) == (namespace, job_id):
                     del self._scaling_policies[pid]
             self._scaling_events.pop((namespace, job_id), None)
-            return self._bump("jobs", "job_versions", "scaling_policies")
+            return self._bump("jobs", "job_versions", "scaling_policies",
+                              keys=(("job", namespace, job_id),))
 
     def job_version(self, namespace: str, job_id: str,
                     version: int) -> Optional[Job]:
-        with self._lock:
-            return self._job_versions.get((namespace, job_id, version))
+        return self._job_versions.get((namespace, job_id, version))
 
     def job_versions_by_id(self, namespace: str, job_id: str) -> List[Job]:
         """All tracked versions, newest first (reference:
@@ -778,7 +813,8 @@ class StateStore:
             current = self._jobs.get((namespace, job_id))
             if current is not None and current.version == version:
                 self._jobs[(namespace, job_id)] = updated
-            return self._bump("jobs", "job_versions")
+            return self._bump("jobs", "job_versions",
+                              keys=(("job", namespace, job_id),))
 
     # -- scaling -------------------------------------------------------------
     def scaling_policies(self, namespace: Optional[str] = None
@@ -789,8 +825,7 @@ class StateStore:
 
     def scaling_policy_by_id(self, policy_id: str
                              ) -> Optional[ScalingPolicy]:
-        with self._lock:
-            return self._scaling_policies.get(policy_id)
+        return self._scaling_policies.get(policy_id)
 
     def scaling_policies_by_job(self, namespace: str, job_id: str
                                 ) -> List[ScalingPolicy]:
@@ -830,15 +865,24 @@ class StateStore:
                     ev.create_time = now
                 ev.modify_index = self._index + 1
                 ev.modify_time = now
-                self._evals[ev.id] = ev
+                self._put_eval_locked(ev)
                 self._update_job_summary_status(ev)
-            return self._bump("evals")
+            return self._bump("evals", keys=_eval_keys(evals))
+
+    def _put_eval_locked(self, ev: Evaluation) -> None:
+        if ev.id not in self._evals:
+            jk = (ev.namespace, ev.job_id)
+            self._evals_by_job[jk] = \
+                self._evals_by_job.get(jk, ()) + (ev.id,)
+        self._evals[ev.id] = ev
 
     def delete_evals(self, eval_ids: List[str]) -> int:
         with self._lock:
-            for eid in eval_ids:
-                self._evals.pop(eid, None)
-            return self._bump("evals")
+            gone = [ev for ev in (self._evals.pop(i, None)
+                                  for i in eval_ids) if ev is not None]
+            _unindex(self._evals_by_job,
+                     (((ev.namespace, ev.job_id), ev.id) for ev in gone))
+            return self._bump("evals", keys=_eval_keys(gone))
 
     def _update_job_summary_status(self, ev: Evaluation) -> None:
         # Blocked eval => job still pending work; minimal summary upkeep.
@@ -856,6 +900,10 @@ class StateStore:
         import time as _time
         now = _time.time()
         pairs = []
+        by_node: Dict[str, list] = {}
+        by_job: Dict[Tuple[str, str], list] = {}
+        by_eval: Dict[str, list] = {}
+        moved = []          # an in-place update re-homes the alloc's eval
         for alloc in allocs:
             existing = self._allocs.get(alloc.id)
             if existing is not None:
@@ -870,11 +918,25 @@ class StateStore:
                 alloc.job = existing.job
             self._allocs[alloc.id] = alloc
             pairs.append((existing, alloc))
-            self._allocs_by_node.setdefault(alloc.node_id, {})[alloc.id] = None
-            self._dirty_alloc_nodes.add(alloc.node_id)
             jk = (alloc.namespace, alloc.job_id)
-            self._allocs_by_job.setdefault(jk, {})[alloc.id] = None
-            self._dirty_alloc_jobs.add(jk)
+            if existing is None or existing.node_id != alloc.node_id:
+                by_node.setdefault(alloc.node_id, []).append(alloc.id)
+            if existing is None or \
+                    (existing.namespace, existing.job_id) != jk:
+                by_job.setdefault(jk, []).append(alloc.id)
+            if existing is None or existing.eval_id != alloc.eval_id:
+                if alloc.eval_id:
+                    by_eval.setdefault(alloc.eval_id, []).append(alloc.id)
+                if existing is not None and existing.eval_id:
+                    moved.append((existing.eval_id, alloc.id))
+        if moved:
+            _unindex(self._allocs_by_eval, moved)
+        # one new tuple per touched key, published whole
+        for index, added in ((self._allocs_by_node, by_node),
+                             (self._allocs_by_job, by_job),
+                             (self._allocs_by_eval, by_eval)):
+            for k, ids in added.items():
+                index[k] = index.get(k, ()) + tuple(ids)
         self.alloc_table.upsert_many(allocs)
         return pairs
 
@@ -931,16 +993,13 @@ class StateStore:
                 a = self._allocs.pop(aid, None)
                 if a is not None:
                     pairs.append((a, None))
-                    ids = self._allocs_by_node.get(a.node_id)
-                    if ids is not None:
-                        ids.pop(aid, None)
-                    self._dirty_alloc_nodes.add(a.node_id)
-                    jk = (a.namespace, a.job_id)
-                    jids = self._allocs_by_job.get(jk)
-                    if jids is not None:
-                        jids.pop(aid, None)
-                    self._dirty_alloc_jobs.add(jk)
                 self.alloc_table.remove(aid)
+            _unindex(self._allocs_by_node,
+                     ((a.node_id, a.id) for a, _ in pairs))
+            _unindex(self._allocs_by_job,
+                     (((a.namespace, a.job_id), a.id) for a, _ in pairs))
+            _unindex(self._allocs_by_eval,
+                     ((a.eval_id, a.id) for a, _ in pairs))
             return self._bump("allocs", delta=pairs)
 
     # -- deployments ---------------------------------------------------------
@@ -969,12 +1028,13 @@ class StateStore:
             deployment.create_index = self._index + 1
         deployment.modify_index = self._index + 1
         self._deployments[deployment.id] = deployment
-        self._bump("deployments")
+        self._bump("deployments", keys=_deployment_keys((deployment,)))
 
     def delete_deployment(self, deployment_id: str) -> int:
         with self._lock:
-            self._deployments.pop(deployment_id, None)
-            return self._bump("deployments")
+            gone = self._deployments.pop(deployment_id, None)
+            return self._bump("deployments",
+                              keys=_deployment_keys((gone,)))
 
     # -- node pools / config -------------------------------------------------
     def upsert_node_pool(self, pool: NodePool) -> int:
@@ -1017,8 +1077,7 @@ class StateStore:
             return self._bump("namespaces")
 
     def namespace_by_name(self, name: str) -> Optional["Namespace"]:
-        with self._lock:
-            return self._namespaces.get(name)
+        return self._namespaces.get(name)
 
     def namespaces(self) -> List["Namespace"]:
         with self._lock:
@@ -1049,8 +1108,7 @@ class StateStore:
 
     def csi_volume_by_id(self, namespace: str, vol_id: str
                          ) -> Optional["CSIVolume"]:
-        with self._lock:
-            return self._csi_volumes.get((namespace, vol_id))
+        return self._csi_volumes.get((namespace, vol_id))
 
     def csi_volumes(self, namespace: Optional[str] = None
                     ) -> List["CSIVolume"]:
@@ -1135,8 +1193,7 @@ class StateStore:
             return sorted(self._csi_plugins.values(), key=lambda p: p.id)
 
     def csi_plugin_by_id(self, plugin_id: str) -> Optional["CSIPlugin"]:
-        with self._lock:
-            return self._csi_plugins.get(plugin_id)
+        return self._csi_plugins.get(plugin_id)
 
     # -- native service catalog (reference: state_store.go
     #    UpsertServiceRegistrations / DeleteServiceRegistrationByID) ------
@@ -1238,8 +1295,7 @@ class StateStore:
             return self._bump("root_keys")
 
     def root_key_by_id(self, key_id: str):
-        with self._lock:
-            return self._root_keys.get(key_id)
+        return self._root_keys.get(key_id)
 
     def root_keys(self) -> List:
         with self._lock:
@@ -1286,8 +1342,7 @@ class StateStore:
             return True, existing
 
     def variable_by_path(self, namespace: str, path: str):
-        with self._lock:
-            return self._variables.get((namespace, path))
+        return self._variables.get((namespace, path))
 
     def variables(self, namespace: Optional[str] = None,
                   prefix: str = "") -> List:
@@ -1331,16 +1386,14 @@ class StateStore:
             return self._bump("acl_roles")
 
     def acl_role_by_name(self, name: str) -> Optional["ACLRole"]:
-        with self._lock:
-            return self._acl_roles.get(name)
+        return self._acl_roles.get(name)
 
     def acl_roles(self) -> List["ACLRole"]:
         with self._lock:
             return list(self._acl_roles.values())
 
     def acl_policy_by_name(self, name: str) -> Optional[ACLPolicy]:
-        with self._lock:
-            return self._acl_policies.get(name)
+        return self._acl_policies.get(name)
 
     def acl_policies(self) -> List[ACLPolicy]:
         with self._lock:
@@ -1368,8 +1421,7 @@ class StateStore:
             return self._bump("acl_tokens")
 
     def acl_token_by_accessor(self, accessor_id: str) -> Optional[ACLToken]:
-        with self._lock:
-            return self._acl_tokens.get(accessor_id)
+        return self._acl_tokens.get(accessor_id)
 
     def acl_token_by_secret(self, secret_id: str) -> Optional[ACLToken]:
         with self._lock:
@@ -1400,8 +1452,7 @@ class StateStore:
             return True
 
     def acl_bootstrapped(self) -> bool:
-        with self._lock:
-            return self._acl_bootstrapped
+        return self._acl_bootstrapped
 
     def set_scheduler_config(self, cfg: SchedulerConfiguration) -> int:
         with self._lock:
@@ -1410,8 +1461,7 @@ class StateStore:
             return self._bump("scheduler_config")
 
     def scheduler_config(self) -> SchedulerConfiguration:
-        with self._lock:
-            return self._scheduler_config
+        return self._scheduler_config
 
     # -- plan application ----------------------------------------------------
     def _stage_plan_result_locked(self, result: PlanResult,
@@ -1421,9 +1471,10 @@ class StateStore:
         """Apply one plan result's dict/object writes (stop merges,
         deployments, eval updates) WITHOUT touching the tensor table or
         secondary indexes, which the caller batches across plans. Returns
-        (merged_stops, placements, delta_pairs) -- the first two for
-        those deferred columnar writes, the pairs for the _bump journal.
-        Lock held; no index bump here."""
+        (merged_stops, placements, delta_pairs, watch_keys) -- the first
+        two for those deferred columnar writes, the pairs for the _bump
+        journal, the keys of the evals' and deployments' jobs for the
+        watchers. Lock held; no index bump here."""
         stops: List[Allocation] = []
         for allocs in result.node_update.values():
             stops.extend(allocs)
@@ -1456,8 +1507,10 @@ class StateStore:
             merged.append(alloc)
             pairs.append((existing, alloc))
 
+        touched_d = []
         if result.deployment is not None:
             d = result.deployment
+            touched_d.append(d)
             existing_d = self._deployments.get(d.id)
             if existing_d is not None:
                 d.create_index = existing_d.create_index
@@ -1473,12 +1526,14 @@ class StateStore:
                 nd.status_description = du.status_description
                 nd.modify_index = self._index + 1
                 self._deployments[nd.id] = nd
+                touched_d.append(nd)
 
         if eval_updates:
             for ev in eval_updates:
                 ev.modify_index = self._index + 1
-                self._evals[ev.id] = ev
-        return merged, placements, pairs
+                self._put_eval_locked(ev)
+        return merged, placements, pairs, \
+            _deployment_keys(touched_d) + _eval_keys(eval_updates or ())
 
     def upsert_plan_results(self, result: PlanResult,
                             eval_updates: Optional[List[Evaluation]] = None
@@ -1487,8 +1542,8 @@ class StateStore:
         (reference: state_store.go:382 UpsertPlanResults, applied by the FSM
         for ApplyPlanResultsRequestType)."""
         with self._lock:
-            merged, placements, pairs = self._stage_plan_result_locked(
-                result, eval_updates)
+            merged, placements, pairs, keys = \
+                self._stage_plan_result_locked(result, eval_updates)
             # refresh the tensor rows (batched): the allocs just became
             # server-terminal, and the verify fast path's live_strict
             # column mirrors the applier's AllocsByNodeTerminal(false)
@@ -1504,7 +1559,7 @@ class StateStore:
                     self._csi_claim_locked(alloc)
 
             idx = self._bump("allocs", "deployments", "evals",
-                             delta=pairs)
+                             delta=pairs, keys=keys)
             result.alloc_index = idx
             return idx
 
@@ -1534,11 +1589,12 @@ class StateStore:
             merged_all: List[Allocation] = []
             placements_all: List[Allocation] = []
             pairs_all: list = []
+            keys_all: list = []
             staged: List[Tuple[PlanResult, List[Allocation]]] = []
             for result, eval_updates in entries:
                 try:
                     faults.fire("plan.commit")
-                    merged, placements, pairs = \
+                    merged, placements, pairs, keys = \
                         self._stage_plan_result_locked(result, eval_updates)
                 except BaseException as e:  # noqa: BLE001 -- split batch
                     outcomes.append(e)
@@ -1546,6 +1602,7 @@ class StateStore:
                 merged_all.extend(merged)
                 placements_all.extend(placements)
                 pairs_all.extend(pairs)
+                keys_all.extend(keys)
                 staged.append((result, placements))
                 outcomes.append(None)
             self.alloc_table.upsert_many(merged_all)
@@ -1555,7 +1612,7 @@ class StateStore:
                     for alloc in placements:
                         self._csi_claim_locked(alloc)
             idx = self._bump("allocs", "deployments", "evals",
-                             delta=pairs_all)
+                             delta=pairs_all, keys=keys_all)
             for result, _ in staged:
                 result.alloc_index = idx
             return idx, outcomes
@@ -1596,9 +1653,15 @@ class StateStore:
 
     # -- snapshot passthrough reads (so StateStore satisfies the scheduler's
     #    State interface directly in tests) --------------------------------
+    #
+    # Point reads take no lock: one dict.get on one table whose values a
+    # writer replaces and never edits in place (LOCK_FREE_POINT_READS;
+    # tests/test_store_reads.py holds each body to that shape). Walks of
+    # a copy-on-write index take none either (LOCK_FREE_WALKS): one read
+    # of the published id tuple, then a point read an id. Everything
+    # that iterates a live table keeps the lock.
     def node_by_id(self, node_id):
-        with self._lock:
-            return self._nodes.get(node_id)
+        return self._nodes.get(node_id)
 
     def nodes(self):
         with self._lock:
@@ -1614,60 +1677,48 @@ class StateStore:
         return self.snapshot().nodes_pack_key(nodes)
 
     def job_by_id(self, namespace, job_id):
-        with self._lock:
-            return self._jobs.get((namespace, job_id))
+        return self._jobs.get((namespace, job_id))
 
     def jobs(self):
         with self._lock:
             return list(self._jobs.values())
 
     def eval_by_id(self, eval_id):
-        with self._lock:
-            return self._evals.get(eval_id)
+        return self._evals.get(eval_id)
 
     def evals(self):
         with self._lock:
             return list(self._evals.values())
 
     def evals_by_job(self, namespace, job_id):
-        with self._lock:
-            return [e for e in self._evals.values()
-                    if e.namespace == namespace and e.job_id == job_id]
+        return _walk(self._evals_by_job.get((namespace, job_id), ()),
+                     self._evals)
 
     def alloc_by_id(self, alloc_id):
-        with self._lock:
-            return self._allocs.get(alloc_id)
+        return self._allocs.get(alloc_id)
 
     def allocs(self):
         with self._lock:
             return list(self._allocs.values())
 
     def allocs_by_node(self, node_id):
-        with self._lock:
-            return [self._allocs[i]
-                    for i in self._allocs_by_node.get(node_id, ())
-                    if i in self._allocs]
+        return _walk(self._allocs_by_node.get(node_id, ()), self._allocs)
 
     def allocs_by_job(self, namespace, job_id, anyCreateIndex=True):
-        with self._lock:
-            return [self._allocs[i]
-                    for i in self._allocs_by_job.get((namespace, job_id), ())
-                    if i in self._allocs]
+        return _walk(self._allocs_by_job.get((namespace, job_id), ()),
+                     self._allocs)
 
     def num_allocs_by_job(self, namespace, job_id) -> int:
         """O(1) alloc count off the secondary index (any status).
         Monitoring loops that only need a progress number must not pay
         the allocs_by_job object-list materialization per poll."""
-        with self._lock:
-            return len(self._allocs_by_job.get((namespace, job_id), ()))
+        return len(self._allocs_by_job.get((namespace, job_id), ()))
 
     def allocs_by_eval(self, eval_id):
-        with self._lock:
-            return [a for a in self._allocs.values() if a.eval_id == eval_id]
+        return _walk(self._allocs_by_eval.get(eval_id, ()), self._allocs)
 
     def deployment_by_id(self, deployment_id):
-        with self._lock:
-            return self._deployments.get(deployment_id)
+        return self._deployments.get(deployment_id)
 
     def latest_deployment_by_job(self, namespace, job_id):
         return self.snapshot().latest_deployment_by_job(namespace, job_id)
@@ -1677,5 +1728,24 @@ class StateStore:
             return list(self._deployments.values())
 
     def node_pool_by_name(self, name):
-        with self._lock:
-            return self._node_pools.get(name)
+        return self._node_pools.get(name)
+
+
+def _walk(ids, table: dict) -> list:
+    """The objects a published id tuple names, each one point read of
+    ``table``; an id a later write deleted is left out."""
+    return [o for o in map(table.get, ids) if o is not None]
+
+
+# The reader contract's two lists (tests/test_store_reads.py checks the
+# bodies): getters that are ONE read of ONE replace-on-write table ...
+LOCK_FREE_POINT_READS = (
+    "latest_index", "table_index", "node_by_id", "job_by_id", "eval_by_id",
+    "alloc_by_id", "deployment_by_id", "node_pool_by_name", "job_version",
+    "scaling_policy_by_id", "namespace_by_name", "csi_volume_by_id",
+    "csi_plugin_by_id", "root_key_by_id", "variable_by_path",
+    "acl_role_by_name", "acl_policy_by_name", "acl_token_by_accessor",
+    "acl_bootstrapped", "scheduler_config", "num_allocs_by_job")
+# ... and walks of a copy-on-write index
+LOCK_FREE_WALKS = ("allocs_by_job", "allocs_by_node", "allocs_by_eval",
+                   "evals_by_job")

@@ -1,7 +1,9 @@
 """The state store's one lock, with an account of who waited for it.
 
-Every table read and write of the control plane goes through
-``StateStore._lock``. This is that RLock behind a thin wrapper that
+Every write of the control plane, and every read that iterates a live
+table, goes through ``StateStore._lock`` (point reads and blocking
+queries do not: store.py's reader contract, watch.py). This is that
+RLock behind a thin wrapper that
 says how long threads wait for it, which kind of thread waited, and
 which store method held it meanwhile -- the convoy that
 ``nomad.broker.eval_wait``, ``solver.barrier`` and the HTTP handlers all
@@ -24,10 +26,8 @@ telemetry call an acquire would double the wrapper's cost). The waiter
 books its wait when it lets the lock go again, after the release: the
 lock is what everything waits for, so nothing is booked under it.
 
-Usable as a ``threading.Condition``'s lock (the store's watch condition
-shares it): a waiter woken by ``notify_all`` re-acquires through
-``_acquire_restore``, and what it waits there -- sixteen watchers woken
-by one write take turns -- is charged the same way.
+No condition is built on this lock: the store's watchers wait on the
+watch registry's own lock (watch.py), never here.
 
 With ``NOMAD_TPU_TRACE=0`` at construction the store holds the raw RLock
 (``make_store_lock``).
@@ -53,17 +53,12 @@ _WAIT_SERIES = {
 # samples (PR 24) and the first chip runs of the account (PR 25) name;
 # the rest share `.other`
 _BLOCKED_BY_SERIES = {
-    "job_by_id": "nomad.state.lock_blocked_by_us.job_by_id",
-    "latest_index": "nomad.state.lock_blocked_by_us.latest_index",
+    "upsert_job": "nomad.state.lock_blocked_by_us.upsert_job",
     "upsert_evals": "nomad.state.lock_blocked_by_us.upsert_evals",
     "update_job_status": "nomad.state.lock_blocked_by_us.update_job_status",
     # solver/service.py folds the live alloc table under the lock
     "_pack_usage_from_table":
         "nomad.state.lock_blocked_by_us.pack_usage_from_table",
-    "allocs_by_eval": "nomad.state.lock_blocked_by_us.allocs_by_eval",
-    "allocs_by_job": "nomad.state.lock_blocked_by_us.allocs_by_job",
-    "evals_by_job": "nomad.state.lock_blocked_by_us.evals_by_job",
-    "node_by_id": "nomad.state.lock_blocked_by_us.node_by_id",
     "upsert_plan_results":
         "nomad.state.lock_blocked_by_us.upsert_plan_results",
     "apply_plan_results_batch":
@@ -71,7 +66,6 @@ _BLOCKED_BY_SERIES = {
     "update_allocs_from_client":
         "nomad.state.lock_blocked_by_us.update_allocs_from_client",
     "snapshot": "nomad.state.lock_blocked_by_us.snapshot",
-    "block_until": "nomad.state.lock_blocked_by_us.block_until",
 }
 _BLOCKED_BY_OTHER = "nomad.state.lock_blocked_by_us.other"
 
@@ -80,9 +74,6 @@ _WORKER_THREADS = ("batch-", "scheduler-worker-", "lpq-eval-",
 _CORE_THREADS = frozenset(("core-gc", "heartbeat", "periodic",
                            "deploy-watch", "volume-watch", "drainer"))
 _FLUSH_MASK = 255
-# a re-acquire after a condition wait that took longer than this had to
-# wait for another holder
-_RESTORE_CONTENDED_S = 1e-4
 
 
 def thread_role(name: str) -> str:
@@ -132,12 +123,6 @@ class StoreLock:
         # read by waiters without the lock: a name or None, possibly
         # one holder stale
         self._holder = None
-
-    # lockcheck sees through to the lock it instruments
-    # (_InstrumentedCondition)
-    @property
-    def _lc_wrapped(self):
-        return self._inner
 
     def __enter__(self):
         if schedcheck._ACTIVE:
@@ -206,33 +191,6 @@ class StoreLock:
         self._acquire()     # paired with the caller's __exit__ / release
         self._owed = (blocker, perf_counter() - t0)
         self._entered(taker)
-
-    # -- threading.Condition's owner protocol ---------------------------
-    def _release_save(self):
-        state = (self._depth, self._holder)
-        self._depth = 0
-        owed, n = self._owed, self._n
-        if owed is not None:
-            self._owed, self._n = None, 0
-        inner_state = self._inner._release_save()
-        if owed is not None:
-            _charge(*owed, n)
-        return state, inner_state
-
-    def _acquire_restore(self, saved) -> None:
-        (depth, holder), inner_state = saved
-        blocker = self._holder
-        t0 = perf_counter()
-        self._inner._acquire_restore(inner_state)
-        waited = perf_counter() - t0
-        self._depth = depth
-        self._holder = holder
-        self._n += 1
-        if waited > _RESTORE_CONTENDED_S:
-            self._owed = (blocker, waited)
-
-    def _is_owned(self) -> bool:
-        return self._inner._is_owned()
 
     def __repr__(self) -> str:
         return f"<StoreLock depth={self._depth} inner={self._inner!r}>"

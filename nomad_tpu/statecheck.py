@@ -589,7 +589,7 @@ def mark_uncoverable(reason: str) -> _Uncoverable:
     return _Uncoverable(reason)
 
 
-def _patched_bump(self, *tables, delta=None):
+def _patched_bump(self, *tables, delta=None, keys=()):
     if _ACTIVE:
         if "allocs" in tables:
             _counters["journal_writes"] += 1
@@ -604,7 +604,7 @@ def _patched_bump(self, *tables, delta=None):
                     m = _metrics()
                     if m is not None:
                         m.incr("nomad.statecheck.journal_gap")
-    idx = _REAL["store._bump"](self, *tables, delta=delta)
+    idx = _REAL["store._bump"](self, *tables, delta=delta, keys=keys)
     if _ACTIVE and "nodes" in tables:
         ni = self._table_index.get("nodes", 0)
         with _slock:

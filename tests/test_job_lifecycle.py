@@ -373,3 +373,129 @@ def test_raft_replicates_stability_and_scaling_events(tmp_path):
     finally:
         for s in servers:
             s.shutdown()
+
+
+# -- blocking queries wake on their own job's or node's writes only ---------
+
+class _BlockingGet:
+    """One `GET <path>?index=N&wait=...` on a thread of its own."""
+
+    def __init__(self, api, path):
+        import json
+        import threading
+        import urllib.request
+        self.body = self.index = self.returned_at = None
+
+        def run():
+            with urllib.request.urlopen(api.address + path,
+                                        timeout=30) as r:
+                self.body = json.loads(r.read())
+                self.index = int(r.headers["X-Nomad-Index"])
+            self.returned_at = time.monotonic()
+        self.thread = threading.Thread(target=run, daemon=True)
+        self.thread.start()
+
+    def done(self, timeout):
+        self.thread.join(timeout)
+        return not self.thread.is_alive()
+
+
+def _parked(server, n=1, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while server.state._watch.parked() < n:
+        assert time.monotonic() < deadline, "the query never parked"
+        time.sleep(0.005)
+
+
+def _write_other_job(state, node, job_id="other"):
+    from nomad_tpu.structs import PlanResult
+    job = mock.job(id=job_id)
+    state.upsert_job(job)
+    state.upsert_evals([mock.evaluation(job_id=job_id)])
+    state.upsert_plan_results(PlanResult(node_allocation={
+        node.id: [mock.alloc_for(job, node, i) for i in range(2)]}))
+    state.update_job_status("default", job_id, "running")
+
+
+def test_http_job_summary_blocks_until_its_own_jobs_plan_commit(agent):
+    from nomad_tpu.structs import PlanResult
+    server, api = agent
+    state = server.state
+    nodes = [mock.node(), mock.node()]
+    for n in nodes:
+        state.upsert_node(n)
+    job = mock.job(id="A")
+    state.upsert_job(job)           # no eval: nothing schedules it
+    start = state.latest_index()
+    get = _BlockingGet(api, f"/v1/job/A/summary?index={start}&wait=2s")
+    _parked(server)
+    wakes = state._watch.wakes
+    _write_other_job(state, nodes[1])
+    assert not get.done(0.1)        # four writes to another job: asleep
+    assert state._watch.wakes == wakes
+    t0 = time.monotonic()
+    state.upsert_plan_results(PlanResult(node_allocation={
+        nodes[0].id: [mock.alloc_for(job, nodes[0], i) for i in range(3)]}))
+    assert get.done(5)
+    assert get.returned_at - t0 < 0.2
+    tg = job.task_groups[0].name
+    assert get.body["summary"][tg]["starting"] == 3
+    assert get.index == state.latest_index() > start
+    # asked again with that reply's index: A has nothing newer, the
+    # other job's writes do not count, and `wait` runs out
+    get = _BlockingGet(api, f"/v1/job/A/summary?index={get.index}&wait=0.3s")
+    _parked(server)
+    _write_other_job(state, nodes[1], "other2")
+    assert get.done(5)
+    assert get.body["summary"][tg]["starting"] == 3
+    assert get.index == state.latest_index()
+
+
+@pytest.mark.parametrize("route", ["", "/allocations", "/evaluations",
+                                   "/deployment"])
+def test_http_job_routes_wake_on_the_jobs_key(agent, route):
+    server, api = agent
+    state = server.state
+    node = mock.node()
+    state.upsert_node(node)
+    state.upsert_job(mock.job(id="A"))
+    start = state.latest_index()
+    get = _BlockingGet(api, f"/v1/job/A{route}?index={start}&wait=5s")
+    _parked(server)
+    _write_other_job(state, node)
+    assert not get.done(0.05)
+    t0 = time.monotonic()
+    state.upsert_evals([mock.evaluation(job_id="A")])
+    assert get.done(5) and get.returned_at - t0 < 0.2
+    assert get.index == state.latest_index()
+
+
+def test_http_node_allocations_block_by_node(agent):
+    server, api = agent
+    state = server.state
+    mine, other = mock.node(), mock.node()
+    state.upsert_node(mine)
+    state.upsert_node(other)
+    job = mock.job(id="A")
+    state.upsert_job(job)
+    start = state.latest_index()
+    get = _BlockingGet(
+        api, f"/v1/node/{mine.id}/allocations?index={start}&wait=2s")
+    _parked(server)
+    wakes = state._watch.wakes
+    _write_other_job(state, other)
+    state.upsert_allocs([mock.alloc_for(job, other)])
+    assert not get.done(0.1)
+    assert state._watch.wakes == wakes
+    t0 = time.monotonic()
+    state.upsert_allocs([mock.alloc_for(job, mine)])
+    assert get.done(5)
+    assert get.returned_at - t0 < 0.2
+    assert len(get.body["allocs"]) == 1
+    assert get.index == get.body["index"] == state.latest_index() > start
+    # a list route keeps waking on any write
+    get = _BlockingGet(api, f"/v1/node/pools?index={get.index}&wait=5s")
+    _parked(server)
+    t0 = time.monotonic()
+    state.upsert_job(mock.job(id="B"))
+    assert get.done(5) and get.returned_at - t0 < 0.2

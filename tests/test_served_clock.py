@@ -98,8 +98,8 @@ def wait_until(cond, timeout=10.0, msg="condition"):
 
 
 def parked(store):
-    """A watcher waits on the store's shared condition."""
-    return len(store._watch_cond._waiters) > 0
+    """A watcher waits in the store's watch registry."""
+    return store._watch.parked() > 0
 
 
 def timers():
@@ -277,14 +277,15 @@ def test_contending_threads_are_charged_by_role_and_holder(monkeypatch):
         return time.perf_counter()
     monkeypatch.setattr(storelock, "perf_counter", clock)
 
-    def allocs_by_job():            # the holder's note is its caller's name
+    def snapshot():                 # the holder's note is its caller's name
         with store._lock:
             holding.set()
             release.wait(10)
 
-    holder = threading.Thread(target=allocs_by_job, name="http-holder",
+    holder = threading.Thread(target=snapshot, name="http-holder",
                               daemon=True)
-    waiter = threading.Thread(target=store.latest_index,
+    # a getter that still takes the lock: it walks a live table
+    waiter = threading.Thread(target=store.nodes,
                               name="batch-eval-deadbeef", daemon=True)
     holder.start()
     assert holding.wait(10)
@@ -297,7 +298,7 @@ def test_contending_threads_are_charged_by_role_and_holder(monkeypatch):
     assert not holder.is_alive() and not waiter.is_alive()
     c = counters()
     assert c["nomad.state.lock_wait_us.worker"] >= 40_000
-    assert c["nomad.state.lock_blocked_by_us.allocs_by_job"] == \
+    assert c["nomad.state.lock_blocked_by_us.snapshot"] == \
         c["nomad.state.lock_wait_us.worker"]
     assert c["nomad.state.lock_contended"] == 1
     assert c["nomad.state.lock_acquires"] == 2
@@ -326,7 +327,7 @@ def test_uncontended_acquire_reads_no_clock(monkeypatch):
     monkeypatch.setattr(storelock, "perf_counter", clock)
     store = StateStore()
     for _ in range(300):            # past one flush of the acquire count
-        store.latest_index()
+        store.nodes()
         store.snapshot()
     store.upsert_node(mock.node())
     assert reads == []
@@ -362,9 +363,15 @@ def test_reentrant_acquires_count_once_and_keep_the_outer_holder():
     assert got == [True]
 
 
-def test_block_until_wakes_on_a_write_through_the_shared_condition():
+def test_block_until_wakes_on_a_write_off_the_store_lock():
+    """A watcher waits in the watch registry, on a lock of its own: it
+    parks, wakes on a write and returns the store's index without one
+    acquire of the store's lock, and a writer that holds the store's
+    lock meanwhile does not stand in its way."""
     store = StateStore()
+    store.upsert_node(mock.node())
     start = store.latest_index()
+    acquires = store._lock._n
     out = []
 
     def watch():
@@ -374,12 +381,21 @@ def test_block_until_wakes_on_a_write_through_the_shared_condition():
     wait_until(lambda: parked(store), msg="the watcher to park")
     t0 = time.monotonic()
     store.upsert_node(mock.node())
-    t.join(10)
-    assert not t.is_alive()
+    with store._lock:               # the watcher returns under a held lock
+        t.join(10)
+        assert not t.is_alive()
     assert out == [start + 1]
     assert time.monotonic() - t0 < 2.0
     assert store._lock._depth == 0
+    # the write and this test's own `with`, nothing of the watcher's
+    assert store._lock._n - acquires == 2
     assert store.block_until(start + 1, timeout=0.05) == start + 1
+    assert not parked(store)
+    c = counters()
+    assert c["nomad.state.watch_waits"] == 2
+    assert c["nomad.state.watch_wakes"] == 1
+    assert "nomad.state.watch_wakes_spurious" not in c
+    assert not any(k.startswith("nomad.state.lock_wait_us.") for k in c)
 
 
 def test_raw_rlock_when_the_tracer_is_off(monkeypatch):
@@ -395,14 +411,15 @@ def test_lockcheck_and_the_account_stack():
     """Under the lock-order sanitizer the store's lock is the account
     over lockcheck's wrapper over the RLock: both keep working, the
     sanitizer's witness sites still name the store's methods, and a
-    watcher parked on the shared condition is not reported as holding
-    the lock it waits on."""
+    watcher parked in the watch registry (whose lock the sanitizer
+    instruments too) is not reported as holding a lock it waits on."""
     was = lockcheck.enabled()
     lockcheck.enable()
     try:
         store = StateStore()
         assert isinstance(store._lock, storelock.StoreLock)
         assert isinstance(store._lock._inner, lockcheck._LockWrapper)
+        assert isinstance(store._watch._lock, lockcheck._LockWrapper)
         start = store.latest_index()
         seen = []
         real_record = lockcheck._record_acquire
@@ -429,8 +446,13 @@ def test_lockcheck_and_the_account_stack():
         rep = lockcheck.state()
         mine = [r for kind in ("cycles", "held_across", "escaped")
                 for r in rep.get(kind, ())
-                if "storelock" in json.dumps(r, default=str)]
+                if "storelock" in json.dumps(r, default=str)
+                or "watch.py" in json.dumps(r, default=str)]
         assert mine == []
+        # the one order there is: store, then watch
+        assert any(w["from"].startswith("nomad_tpu/state/storelock.py")
+                   and w["to"].startswith("nomad_tpu/state/watch.py")
+                   for w in lockcheck._edge_wit.values())
         assert store._lock._depth == 0
     finally:
         if not was:
@@ -487,7 +509,9 @@ def test_core_gc_counts_what_it_scans(server):
     server.run_gc_once()
     c = counters()
     assert c["nomad.core.gc_evals_scanned"] == len(evs)
-    assert c["nomad.core.gc_allocs_scanned"] == (len(evs) + 1) * 2
+    # the placing eval's two allocs off the by-eval index, then the
+    # whole table once for the sweep
+    assert c["nomad.core.gc_allocs_scanned"] == 2 + 2
     # the eval's status update carried the index of its snapshot
     placed = [e for e in evs if e.triggered_by == "job-register"]
     assert placed and all(0 < e.snapshot_index <= e.modify_index
