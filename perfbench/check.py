@@ -16,7 +16,9 @@ on the chip, PERF.md section 2):
   index order;
 - unreproduced_evals: sampled evals whose first plan the reference does
   not reproduce from any state the eval can have been solved against
-  (`compare_plan`);
+  (`compare_plan`); those of which it replayed no state at all
+  (`scan_reach` admitted none) the run reports beside it, as
+  `unreplayed_evals`: the instrument never looked;
 - choice_mismatches: over the sampled evals' first plans, placements
   that sit on another node than the reference chose in the same scan
   order from the same committed state, and that no other eval's
@@ -193,6 +195,44 @@ def against(served: dict, seq: list, job: dict, group: str, eval_id: str,
             "mismatches": mismatches}
 
 
+def scan_reach(n: int, limit: int):
+    """(reach, share): a snapshot index is replayed when its shuffle of
+    the `n` nodes puts `share` of the plan or more in its first `reach`
+    positions, for an eval that looks at `limit` fitting nodes a
+    placement. (None, 0.0), which every index passes, where no such rule
+    can tell.
+
+    Under the right index every placement is the best of the first
+    `limit` nodes in scan order that the ask fits. With a share f of the
+    fleet unable to take it those lie over limit / (1 - f) positions,
+    and the plan over that span wherever the scores put it: three
+    quarters of it inside 3 * limit at f = 3/4. 4 * limit leaves a third
+    as much again for the nodes the eval itself fills and holds to f =
+    0.81. Under a wrong index the plan's m nodes lie anywhere, and three
+    quarters of them fall inside 4 * limit with the upper tail of
+    Binomial(m, 4 * limit / n): at the sweep's far corner (1,200 of
+    10,000) 8e-7 for 80 nodes and 2e-18 for 250. Past three fifths of
+    the fleet 250 nodes pass three times in ten million and fewer more
+    often, while the right index's three quarters may lie beyond: a
+    window wider than 3/20 of the fleet is not filtered.
+
+    A window of which four fit into an eighth of the fleet (the 14 nodes
+    of a binpack eval, whose plan slides down the scan as it fills node
+    after node) keeps the rule it was given first: half of the plan
+    inside that eighth, 1e-8 for a wrong index and 40 nodes."""
+    floor = max(8 * ref.scan_limit(n, 0, False), n // 8)
+    if 4 * limit <= floor:
+        return floor, 0.5
+    return (4 * limit, 0.75) if 4 * limit <= 3 * n // 5 else (None, 0.0)
+
+
+def share_in_reach(served: dict, order: list, reach) -> float:
+    """The share of a plan's placements on the first `reach` nodes of a
+    scan order (on any of them where `reach` is None)."""
+    head = set(order[:reach])
+    return sum(node in head for node, _s in served.values()) / len(served)
+
+
 def compare_plan(served: dict, job: dict, group: str, eval_id: str,
                  plan_index: int, indexes, base_order: list, fetch) -> dict:
     """One eval's first plan against the reference.
@@ -206,23 +246,22 @@ def compare_plan(served: dict, job: dict, group: str, eval_id: str,
     The eval's scan order is the shuffle its snapshot's index seeds; the
     usage it packs is the live alloc table's when it packs, a committed
     state no older than the snapshot and older than the plan. So for
-    each snapshot index that puts the plan's nodes at the head of the
-    scan, and each later state at which a node the scan reached changed,
-    the reference replays the eval and is compared with `served`
-    (`against`); the pair with the fewest unexplained placements stands.
+    each snapshot index that puts the plan at the head of the scan, as
+    far as this eval's own window reaches (`scan_reach`), and each later
+    state at which a node the scan reached changed, the reference
+    replays the eval and is compared with `served` (`against`); the pair
+    with the fewest unexplained placements stands.
     Returns that comparison with `reproduced` (REPRODUCED_SHARE of the
     plan sits where the reference put it), `index`, `usage_index` and
-    `order`."""
-    n = len(base_order)
-    reach = max(8 * ref.scan_limit(n, 0, False), n // 8)
+    `order`; `index` is None when no index was replayed at all."""
+    count, _ask = plan_ask(job, group)
+    reach, share = scan_reach(len(base_order), ref.scan_limit(
+        len(base_order), count, spread_attribute(job) is not None))
     best = None
     for index in indexes:
         order = ref.shuffled(base_order, eval_id, index)
-        # a wrong index puts the plan's nodes anywhere in the scan, the
-        # right one at its head: no node is read for most wrong ones
-        head = set(order[:reach])
-        if sum(node in head for node, _s in served.values()) * 2 < len(served):
-            continue
+        if share_in_reach(served, order, reach) < share:
+            continue    # no node is read for a wrong index
         usage_index = index
         while usage_index is not None:
             seen: set = set()

@@ -288,9 +288,9 @@ def read_back(api: Api, cell: dict, window_jobs: list, seed: int,
                 api.get(f"/v1/node/{node_id}/allocations")["allocs"])
         return nodes_seen[node_id]
     def fresh():
-        return {"evals": 0, "unreproduced": 0, "mismatches": [], "filled": 0,
-                "touched": 0, "retried": 0, "gaps": [], "back": [],
-                "ahead": []}
+        return {"evals": 0, "unreproduced": 0, "unreplayed": 0,
+                "mismatches": [], "filled": 0, "touched": 0, "retried": 0,
+                "gaps": [], "back": [], "ahead": []}
     tally, ctl = fresh(), fresh() if control else None
     for rec in order[:int(mix["check_jobs"])]:
         job = api.get(f"/v1/job/{rec['id']}")
@@ -336,6 +336,9 @@ def read_back(api: Api, cell: dict, window_jobs: list, seed: int,
                 "score_gap_max": max(t["gaps"]) if t["gaps"] else None}
     gaps = sorted(tally["gaps"])
     info = {"sampled_evals": tally["evals"],
+            # unreproduced because no index was replayed at all: the
+            # instrument never looked (check.scan_reach)
+            "unreplayed_evals": tally["unreplayed"],
             "placements_compared": len(gaps) + len(tally["mismatches"])
             + tally["filled"] + tally["touched"],
             "moved_off_filled_node": tally["filled"],
@@ -346,12 +349,15 @@ def read_back(api: Api, cell: dict, window_jobs: list, seed: int,
             "usage_minus_snapshot_index": tally["ahead"],
             "score_gap_p50": readers.percentile(gaps, 50) if gaps else None,
             "nodes_read": len(nodes_seen)}
+    if control:
+        info["control_unreplayed_evals"] = ctl["unreplayed"]
     return numbers(tally), numbers(ctl) if control else None, info
 
 
 def add(tally: dict, got: dict, retried: int, plan_index: int) -> None:
     tally["evals"] += 1
     tally["unreproduced"] += 0 if got["reproduced"] else 1
+    tally["unreplayed"] += 1 if got["index"] is None else 0
     tally["mismatches"] += got["mismatches"]
     tally["filled"] += got["moved"]["filled"]
     tally["touched"] += got["moved"]["touched"]
@@ -493,7 +499,7 @@ def main(argv=None) -> int:
         lead_in = float(mix["lead_in_s"])
         held = hold_for_phase(config["scheduler"]["window_phase"],
                               hello["started"], lead_in)
-        gen.start(time.monotonic(), lead_in + args.seconds)
+        gen.start(time.monotonic(), [lead_in, args.seconds])
         time.sleep(lead_in)
         win = measure_window(server, gen, args.seconds,
                              float(mix["trace_s"]) if args.trace else 0.0,
@@ -504,7 +510,9 @@ def main(argv=None) -> int:
         t_end = time.monotonic()
         records = gen.snapshot()
         if mix["loop"] == "open":
-            window_jobs = [r for r in records if t0 <= r["due"] < t1]
+            # the schedule's second stretch, due from the instant the
+            # lead-in ended: the window, but for the snapshot's few ms
+            window_jobs = [r for r in records if r["stretch"] == 1]
         else:
             window_jobs = [r for r in records
                            if r["placed"] is None or r["placed"] >= t0]

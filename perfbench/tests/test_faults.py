@@ -7,7 +7,7 @@ import os
 
 import check
 import pytest
-from conftest import HERE, run_cell
+from conftest import HERE, run_cell, standin_tree
 
 LIMITS = check.load_limits("served_placements")
 
@@ -44,6 +44,18 @@ def test_nodes_swapped_among_a_lanes_placements_is_not_correct():
     rc, res, err = run_cell("binpack-drain", env=plant("altered_answer"))
     assert rc == 0 and res["correct"] is False, err[-2000:]
     assert over(res, "unreproduced_evals") or over(res, "choice_mismatches")
+
+
+def test_nodes_swapped_in_a_wide_window_is_a_mismatch_not_unreplayed(
+        tmp_path, manifest):
+    """The stand-in spread deployment, whose evals look at max(count,
+    100) nodes a placement: the replay looked, and says so."""
+    standin_tree(str(tmp_path), manifest)
+    rc, res, err = run_cell("standin-trickle", root=str(tmp_path),
+                            env=plant("altered_answer"))
+    assert rc == 0 and res["correct"] is False, err[-2000:]
+    assert over(res, "unreproduced_evals") or over(res, "choice_mismatches")
+    assert res["info"]["unreplayed_evals"] == 0
 
 
 def test_a_score_altered_where_it_is_produced_is_not_correct():

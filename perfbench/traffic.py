@@ -10,9 +10,11 @@ which acknowledges the stops so the capacity frees.
 
 - loop "closed": `submitters` threads, each sending its next job when
   its last one is placed and stopped;
-- loop "open": jobs due at times fixed by the seed (the same multiset of
-  exponential gaps for every seed, in an order drawn from it), each
-  timed from the instant it was due, whenever it was really sent.
+- loop "open": jobs due at times fixed by the seed, each timed from the
+  instant it was due, whenever it was really sent. The schedule is made
+  stretch by stretch (the lead-in, then the window): each stretch gets
+  the quantile gaps of its own length in an order drawn from the seed,
+  so it holds the same number of jobs and the same gaps for every seed.
 """
 from __future__ import annotations
 
@@ -64,11 +66,12 @@ class Generator:
             self._seq += 1
             return f"{self.tag}-s{self.seed}-{self._seq:06d}"
 
-    def lifecycle(self, due: float) -> dict:
+    def lifecycle(self, due: float, stretch: int = 0) -> dict:
         job_id = self._next_id()
         spec, count = self.make_job(job_id)
         rec = {"id": job_id, "count": count, "due": due, "ok": False,
-               "seen": 0, "placed": None, "error": None}
+               "stretch": stretch, "seen": 0, "placed": None,
+               "error": None}
         with self._lock:
             self.records.append(rec)
         try:
@@ -113,37 +116,42 @@ class Generator:
         while not self._stop.is_set():
             self.lifecycle(time.monotonic())
 
-    def _open(self, t0: float, horizon_s: float) -> None:
-        due = [t0 + t for t in arrival_times(
-            float(self.mix["rate_per_s"]), horizon_s, self.seed)]
+    def _open(self, t0: float, stretches: list) -> None:
+        rate = float(self.mix["rate_per_s"])
+        due, at = [], t0
+        for k, span_s in enumerate(stretches):
+            due += [(at + t, k)
+                    for t in arrival_times(rate, span_s, self.seed + k)]
+            at += span_s
         todo: "queue.Queue" = queue.Queue()
 
         def worker():
             while True:
-                t = todo.get()
-                if t is None:
+                job = todo.get()
+                if job is None:
                     return
-                self.lifecycle(t)
+                self.lifecycle(*job)
         pool = [threading.Thread(target=worker, daemon=True,
                                  name=f"open-{i}")
                 for i in range(int(self.mix.get("max_in_flight", 48)))]
         for th in pool:
             th.start()
-        for t in due:
-            wait = t - time.monotonic()
+        for job in due:
+            wait = job[0] - time.monotonic()
             if wait > 0 and self._stop.wait(wait):
                 break
             if self._stop.is_set():
                 break
-            todo.put(t)
+            todo.put(job)
         for _ in pool:
             todo.put(None)
         for th in pool:
             th.join()
 
-    def start(self, t0: float, horizon_s: float) -> None:
-        """Load from `t0` on; an open loop's schedule covers
-        [t0, t0 + horizon_s)."""
+    def start(self, t0: float, stretches: list) -> None:
+        """Load from `t0` on; an open loop's schedule covers the
+        `stretches` (seconds of each) in turn, and a job's record says
+        which one it was due in."""
         if self.mix["loop"] == "closed":
             for i in range(int(self.mix["submitters"])):
                 th = threading.Thread(target=self._closed, daemon=True,
@@ -151,7 +159,7 @@ class Generator:
                 self._threads.append(th)
         elif self.mix["loop"] == "open":
             self._threads.append(threading.Thread(
-                target=self._open, args=(t0, horizon_s), daemon=True,
+                target=self._open, args=(t0, stretches), daemon=True,
                 name="open-dispatch"))
         else:
             raise ValueError(f"traffic loop {self.mix['loop']!r}")
