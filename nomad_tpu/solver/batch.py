@@ -392,12 +392,30 @@ def _fuse_group(lanes: List[PackedLane], idxs: List[int], key: tuple,
     A = 1 if lane0.ptab is not None else 0
     e_real = len(idxs)
     e_pad = _e_bucket(e_real)
-    if e_pad_hint and lane0.wavefront_ok():
+    wave = lane0.wavefront_ok()
+    # a whole-axis group without preemption tables is pinned like a
+    # wave group: the scan's trip count is an operand, so a padded step
+    # is never run, and on one device the lanes run in turn
+    # (binpack._make_fused_fn), so a padded lane is not either. On a
+    # mesh (parallel/mesh.mesh_solve_fn) the lanes ride the sharded eval
+    # axis up to the widest lane's steps: a padded lane is inert work
+    # on a device that the tight bucket would have lent to the node
+    # axis (PERF.md section 6, PR 30, has both on four chips). A
+    # preempting group still scans every padded step over (N, A) tables
+    # and keeps the tight buckets
+    whole_axis = not wave and A == 0
+    if e_pad_hint and (wave or whole_axis):
         e_pad = max(e_pad, _e_bucket(min(e_pad_hint, E_BUCKETS[-1])))
     # floor of 32: many lane sizes share one compiled variant (an
     # inert padded step costs ~us; a fresh XLA compile costs seconds)
     p_pad = max(32, _e_bucket(max(
         lanes[i].batch.ask_cpu.shape[0] for i in idxs)))
+    if whole_axis:
+        # one placement width a job: a retry of what a partial commit
+        # left carries the group's count (service._limit keeps it too),
+        # so it lands in its first attempt's program
+        p_pad = max(p_pad, _e_bucket(max(
+            int(np.max(lanes[i].batch.count)) for i in idxs)))
     # gauge, not sample_ms: this is a lane COUNT; recording it
     # through the millisecond sampler made dashboards read "lanes"
     # as a latency series
@@ -450,7 +468,7 @@ def _fuse_group(lanes: List[PackedLane], idxs: List[int], key: tuple,
     return _FusedGroup(
         idxs=list(idxs), const=const, init=init, batch=batch, ptab=ptab,
         pinit=pinit, A=A, e_real=e_real, e_pad=e_pad, p_pad=p_pad,
-        wave=lane0.wavefront_ok(), spread_alg=lane0.spread_alg,
+        wave=wave, spread_alg=lane0.spread_alg,
         dtype_name=lane0.dtype_name,
         cache_version=getattr(lane0, "table_version", None),
         delta_src=getattr(lane0, "delta_src", None),
@@ -561,8 +579,11 @@ def fuse_and_solve(lanes: List[PackedLane], use_mesh: bool = True,
     groups to one bucket regardless of how many lanes actually arrived:
     retry batches come in arbitrary sizes, and every fresh E bucket is a
     fresh XLA program (seconds of compile stalling the whole batch) while
-    an inert wave lane costs only O(B*P) padded compute. Dense groups
-    keep the tight bucket -- their padding costs O(N*P) per lane.
+    an inert wave lane costs only O(B*P) padded compute. Whole-axis
+    groups are pinned alike: no padded step is run, and a padded lane
+    is skipped on one device and inert beside the real ones on a mesh
+    (_fuse_group). Only preempting whole-axis groups keep the tight
+    bucket -- their padding costs O(N*A*P) per lane.
 
     ``staged`` carries groups pre-filled by the pipeline's prepare stage
     (fuse_lanes run while the previous generation was in flight) so the
@@ -587,7 +608,7 @@ def _dispatch(const, init, batch, spread_alg: bool, dtype_name: str,
     import jax
     import jax.numpy as jnp
 
-    from .binpack import solve_lane_fused
+    from .binpack import active_steps, solve_lane_fused
 
     if ptab is not None:
         if wave:
@@ -604,6 +625,8 @@ def _dispatch(const, init, batch, spread_alg: bool, dtype_name: str,
                                 wave=True, cache_version=cache_version,
                                 delta_src=delta_src)
     metrics.incr("nomad.solver.dense_dispatches")
+    metrics.sample("nomad.solver.dense_steps",
+                   float(active_steps(np.asarray(batch.active)).sum()))
 
     E = const.cpu_cap.shape[0]
     N = const.cpu_cap.shape[1]
@@ -612,7 +635,12 @@ def _dispatch(const, init, batch, spread_alg: bool, dtype_name: str,
         from ..parallel.mesh import pick_mesh, shard_solver_inputs
         mesh = pick_mesh(E, N)
 
-    if mesh is not None:
+    with tracer.span("solver.dense_solve", E=E, P=batch.active.shape[1]):
+        if mesh is None:
+            return solve_lane_fused(
+                const, init, batch, spread_alg=spread_alg,
+                dtype_name=dtype_name, batched=True,
+                cache_version=cache_version, delta_src=delta_src)
         from ..parallel.mesh import mesh_solve_fn
         metrics.incr("nomad.solver.mesh_dispatches")
         with mesh:
@@ -632,10 +660,6 @@ def _dispatch(const, init, batch, spread_alg: bool, dtype_name: str,
                 n_yielded.astype(scores.dtype)[None]], axis=0))
         xferobs.note_fetch(combined.nbytes, "mesh")
         return combined[0], combined[1], combined[2]
-    return solve_lane_fused(const, init, batch, spread_alg=spread_alg,
-                            dtype_name=dtype_name, batched=True,
-                            cache_version=cache_version,
-                            delta_src=delta_src)
 
 
 def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
@@ -725,6 +749,7 @@ def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
         order = np.asarray(lane.order)
         conflicted: List[int] = []
         accepted_own: List[int] = []
+        unresolvable = 0
         for pi in range(chosen.shape[0]):
             pos = int(chosen[pi])
             if pos < 0 or pos >= order.shape[0] or not active[pi]:
@@ -734,8 +759,12 @@ def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
                 accepted_own.append(pos)
             elif resolvable:
                 conflicted.append(pi)
-            # else: leave the placement for the applier to adjudicate;
-            # its capacity was NOT charged (the applier will reject it)
+            else:
+                # left for the applier to adjudicate; its capacity was
+                # NOT charged (the applier will reject it)
+                unresolvable += 1
+        if unresolvable:
+            metrics.incr("nomad.solver.fixpoint_unresolvable", unresolvable)
         if not conflicted:
             continue
         metrics.incr("nomad.solver.fixpoint_conflicts", len(conflicted))

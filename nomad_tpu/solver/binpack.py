@@ -650,15 +650,39 @@ def _commit_tables(state: NodeState, new_state: NodeState,
     return new_state
 
 
+def active_steps(active):
+    """Scan steps a placement axis needs: one past its last active
+    placement, along the last axis (numpy or jnp)."""
+    xp = jnp if isinstance(active, jax.Array) else np
+    p = active.shape[-1]
+    return xp.max(xp.where(active, xp.arange(1, p + 1, dtype=np.int32), 0),
+                  axis=-1)
+
+
+def _unplaced(shapes, lead: tuple = ()):
+    """(chosen, score, n_yielded) of steps that never ran."""
+    return tuple(jnp.full(lead + y.shape, v, dtype=y.dtype)
+                 for v, y in zip((-1, -jnp.inf, 0), shapes))
+
+
 def _solve_placements_impl(const: NodeConst, init: NodeState,
                            batch: PlacementBatch, spread_alg: bool = False,
-                           dtype_name: str = "float32"):
+                           dtype_name: str = "float32", n_steps=None):
     """Place a batch of allocations sequentially via lax.scan.
 
     Each step reproduces one Stack.Select call (stack.go:128): score every
     node against current usage, select within the limited window, commit the
     winner's resources into the carry. Returns (chosen (P,), scores (P,),
     n_yielded (P,), final NodeState).
+
+    The trip count is an operand, not a shape: the scan runs in blocks
+    of the unroll width over the first ``n_steps`` placements only (by
+    default up to the last active one) and leaves the rest of the axis
+    at its fill (chosen -1, score -inf), so one program serves every
+    lane width up to P and a lane pays for its own steps, not for its
+    padding. Batched callers pass ``n_steps`` as one scalar for all
+    their lanes: a trip count that rides the vmapped axis would turn
+    the loop's carry into a select per block.
     """
     dtype = jnp.dtype(dtype_name)
     n_total = const.cpu_cap.shape[0]
@@ -717,11 +741,30 @@ def _solve_placements_impl(const: NodeConst, init: NodeState,
 
     ask_cores_xs = (batch.ask_cores if batch.ask_cores.shape[0]
                     else jnp.zeros_like(batch.count))
-    final_state, (chosen, scores, n_yielded) = jax.lax.scan(
-        step, init,
-        (batch.ask_cpu, batch.ask_mem, batch.ask_disk, batch.n_dyn_ports,
-         batch.has_static, batch.limit, batch.count, batch.penalty_idx,
-         batch.active, ask_cores_xs), unroll=_dense_unroll())
+    xs = (batch.ask_cpu, batch.ask_mem, batch.ask_disk, batch.n_dyn_ports,
+          batch.has_static, batch.limit, batch.count, batch.penalty_idx,
+          batch.active, ask_cores_xs)
+    unroll = _dense_unroll()
+    if n_steps is None:
+        n_steps = active_steps(batch.active)
+    p = batch.ask_cpu.shape[0]
+    block = unroll if p % unroll == 0 else 1
+
+    def run_block(k, carry):
+        state, outs = carry
+        lo = k * block
+        state, ys = jax.lax.scan(
+            step, state,
+            tuple(jax.lax.dynamic_slice_in_dim(x, lo, block) for x in xs),
+            unroll=block)
+        return state, tuple(
+            jax.lax.dynamic_update_slice_in_dim(o, y, lo, 0)
+            for o, y in zip(outs, ys))
+
+    _, ys = jax.eval_shape(step, init, tuple(x[0] for x in xs))
+    final_state, (chosen, scores, n_yielded) = jax.lax.fori_loop(
+        0, (n_steps + block - 1) // block, run_block,
+        (init, _unplaced(ys, (p,))))
     return chosen, scores, n_yielded, final_state
 
 
@@ -857,10 +900,14 @@ def solve_eval_batch(const: NodeConst, init: NodeState, batch: PlacementBatch,
     The eval axis is the data-parallel axis for multi-chip sharding; the
     node axis shards as the model axis (see parallel/mesh.py).
     """
-    import functools as _ft
-    inner = _ft.partial(solve_placements, spread_alg=spread_alg,
-                        dtype_name=dtype_name)
-    return jax.vmap(inner)(const, init, batch)
+    def inner(c, i, b, n_steps):
+        return solve_placements(c, i, b, spread_alg=spread_alg,
+                                dtype_name=dtype_name, n_steps=n_steps)
+    # one trip count for the dispatch, its widest lane's: the eval axis
+    # is a vector (and, on a mesh, a device) axis here, so a narrower
+    # lane's further steps are inert, not skipped
+    return jax.vmap(inner, in_axes=(0, 0, 0, None))(
+        const, init, batch, jnp.max(active_steps(batch.active)))
 
 
 # ---------------------------------------------------------------------------
@@ -921,6 +968,8 @@ def _make_fused_fn(metas, treedef, group_keys, spread_alg: bool,
     exactly one trace per bucket (jitcheck's retrace gate; the old
     module dict kept the same keys but hid the `@jax.jit` behind a
     bare call site)."""
+    from ..server.telemetry import metrics
+    metrics.incr("nomad.solver.dense_programs")
     gpos = {k: i for i, k in enumerate(group_keys)}
 
     def rebuild(buffers):
@@ -949,15 +998,45 @@ def _make_fused_fn(metas, treedef, group_keys, spread_alg: bool,
             return out, evict_rows
         return fn
 
-    inner = functools.partial(_solve_placements_impl, spread_alg=spread_alg,
-                              dtype_name=dtype_name)
-    if batched:
-        inner = jax.vmap(inner)
+    def one(const, init, batch, n_steps=None):
+        return _solve_placements_impl(
+            const, init, batch, spread_alg=spread_alg,
+            dtype_name=dtype_name, n_steps=n_steps)[:3]
+
+    def _solve_lanes_in_turn(const, init, batch):
+        """The lanes of a dispatch one after the other, each over its
+        own active steps, up to the last lane that has any. A step is a
+        chain of scans over the node axis and costs the same a lane
+        whether lanes ride a vector axis or a loop (v5e, N 16,384: 225
+        us alone, 202 us each of 8 under vmap, 221 each of 8 here), so
+        the loop loses a tenth at full width, while a padded lane and a
+        padded step cost nothing and a retry of 40 placements beside a
+        first attempt of 1,200 pays for 40. One program serves every
+        lane count and width up to its buffers' (E, P). Each lane runs
+        as a batch of one: as plain (N,) vectors the same step read 277
+        us, the (1, N) layout is the one the vmapped scan has."""
+        steps = active_steps(batch.active)
+        trees = (const, init, batch)
+        row_of_one = jax.vmap(one, in_axes=(0, 0, 0, None))
+
+        def lane(e, outs):
+            ys = row_of_one(*jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_slice_in_dim(a, e, 1, 0), trees),
+                steps[e])
+            return tuple(jax.lax.dynamic_update_slice_in_dim(o, y, e, 0)
+                         for o, y in zip(outs, ys))
+        return jax.lax.fori_loop(
+            0, active_steps(steps > 0), lane,
+            _unplaced(jax.eval_shape(row_of_one, *trees, steps[0])))
 
     @jax.jit
     def fn(*buffers):
         const, init, batch = rebuild(buffers)
-        chosen, scores, n_yielded, _ = inner(const, init, batch)
+        if batched:
+            chosen, scores, n_yielded = _solve_lanes_in_turn(
+                const, init, batch)
+        else:
+            chosen, scores, n_yielded = one(const, init, batch)
         return jnp.stack([chosen.astype(scores.dtype), scores,
                           n_yielded.astype(scores.dtype)])
     return fn
@@ -978,7 +1057,8 @@ def solve_lane_fused(const, init, batch, ptab=None, pinit=None, *,
     packing snapshot's node_table_index (solver/constcache.py);
     ``delta_src`` is that snapshot's (store, index) pair for the
     ISSUE-20 version chain -- journal-covered generations ship only
-    their diff and scatter it into the resident buffers on device."""
+    their diff and scatter it into the resident buffers on device. The
+    wave transports ride it; the whole-axis branch below ships whole."""
     if wave and ptab is None:
         return solve_lane_wave(const, init, batch, spread_alg=spread_alg,
                                dtype_name=dtype_name, batched=batched,
@@ -1000,13 +1080,19 @@ def solve_lane_fused(const, init, batch, ptab=None, pinit=None, *,
     # deltas change every dispatch and would churn the LRU. Tags name
     # each stacked buffer's tree group for the transfer ledger; the
     # stacked buffers are _fuse_trees' fresh np.stack outputs, so the
-    # version chain may retain them as frozen shadows without copying.
+    # content cache may retain them as frozen shadows without copying.
+    # No version chain (delta_src) for any caller of this branch: a
+    # whole-axis lane's tables lie in its own eval's scan order, so
+    # between dispatches they change wholesale (where they do not, a
+    # fleet of one node size, the content cache already holds them),
+    # and the chain's scatter programs, one an update-count bucket and
+    # buffer shape, are a second family of compiles that a drained
+    # window keeps meeting (PERF.md section 6, PR 30).
     stages.mark("put")
     buffers, _ = device_put_cached(
         stacked, version=cache_version,
         cacheable=[k[0] == 0 for k in group_keys],
-        tags=[_FUSE_TREE_NAMES[k[0]] for k in group_keys],
-        delta_src=delta_src)
+        tags=[_FUSE_TREE_NAMES[k[0]] for k in group_keys])
     stages.mark("launch")
     out = fn(*buffers)
     stages.mark("fetch")
