@@ -163,28 +163,21 @@ def _binpack_score(free_cpu, free_mem, spread_alg: bool):
     return jnp.clip(raw, 0.0, BINPACK_MAX) / BINPACK_MAX
 
 
-def _spread_score(state: NodeState, const: NodeConst, dtype):
-    """Vectorized SpreadIterator.Next + evenSpreadScoreBoost
-    (reference: spread.go:128-270). Returns (N,) total spread boost."""
-    S, N = const.spread_vidx.shape
-    if S == 0:
-        return jnp.zeros(N, dtype=dtype)
-
-    def one_spread(vidx, desired, has_targets, weight, counts):
-        # vidx: (N,) value index; counts: (V,) current counts
-        missing = vidx < 0
-        safe_vidx = jnp.maximum(vidx, 0)
-        used = counts[safe_vidx] + 1          # include this placement
+def _spread_value_rows(state: NodeState, const: NodeConst, dtype):
+    """Vectorized SpreadIterator.Next + evenSpreadScoreBoost (reference:
+    spread.go:128-270) along the value axis: the (S, V) boost of a node
+    that holds each value. A node's boost depends on its value alone."""
+    def value_row(desired, has_targets, weight, counts):
+        used = counts + 1                     # include this placement
         weight_frac = weight / jnp.maximum(const.spread_sum_weights, 1e-9)
 
         # -- target path (reference: spread.go:171-200)
-        des = desired[safe_vidx]
-        no_target = des < 0.0
+        no_target = desired < 0.0
         boost_t = jnp.where(
             no_target, -1.0,
-            jnp.where(des == 0.0, -1.0,
-                      (des - used.astype(dtype)) / jnp.maximum(des, 1e-9)
-                      * weight_frac))
+            jnp.where(desired == 0.0, -1.0,
+                      (desired - used.astype(dtype))
+                      / jnp.maximum(desired, 1e-9) * weight_frac))
 
         # -- even-spread path (reference: spread.go:216-270)
         present = counts > 0
@@ -192,24 +185,60 @@ def _spread_score(state: NodeState, const: NodeConst, dtype):
         big = jnp.iinfo(jnp.int32).max
         min_c = jnp.min(jnp.where(present, counts, big))
         max_c = jnp.max(jnp.where(present, counts, 0))
-        current = counts[safe_vidx]
         min_f = min_c.astype(dtype)
         max_f = max_c.astype(dtype)
-        cur_f = current.astype(dtype)
+        cur_f = counts.astype(dtype)
         even = jnp.where(
-            current != min_c,
+            counts != min_c,
             jnp.where(min_c == 0, -1.0, (min_f - cur_f) / jnp.maximum(min_f, 1e-9)),
             jnp.where(min_c == max_c, -1.0,
                       (max_f - min_f) / jnp.maximum(min_f, 1e-9)))
         boost_e = jnp.where(any_present, even, 0.0)
+        return jnp.where(has_targets, boost_t, boost_e).astype(dtype)
 
-        per_node = jnp.where(has_targets, boost_t, boost_e)
-        return jnp.where(missing, -1.0, per_node).astype(dtype)
-
-    boosts = jax.vmap(one_spread)(
-        const.spread_vidx, const.spread_desired, const.spread_has_targets,
+    return jax.vmap(value_row)(
+        const.spread_desired, const.spread_has_targets,
         const.spread_weights, state.spread_counts)
-    return jnp.sum(boosts, axis=0)
+
+
+# The widest value axis (V, a static shape) that _spread_score lays over
+# the node axis by compare-and-select; wider, it gathers the row. One
+# step of the whole-axis scan, one lane, v5e, N 16,384, S 1 (us; PR 31):
+#   V            72    75   100   128   300   512  1,024 2,048 4,096 8,192 16,384
+#   select     63.7  64.1  64.7  64.3  67.2  70.5  78.4  92.9  122   181   299
+#   one gather  190 (188-190 at every V: ~118 us of it the gather)
+#   the tables gathered, then the arithmetic a node: 220 (1.25 gathers a step)
+# The select is one fused compare-select-reduce, 0.9 ps a (value, node)
+# pair, and writes no (V, N) words; the gather is 7 ns a node whatever
+# V. They meet near V 8,500; 4,096 is the widest reading at which the
+# select wins. The value axis is padded to the sublane tile (8): unpadded,
+# V 75 and 100 read 74 us and V 300 105.
+SPREAD_SELECT_V = 4096
+
+
+def _spread_score(state: NodeState, const: NodeConst, dtype):
+    """(N,) total spread boost: the value rows laid over the node axis.
+    Up to SPREAD_SELECT_V values a node's index is compared with every
+    value's and the one that matches added up (x + 0.0 is x, so the
+    bits are a gather's); wider, the row is gathered. Batched gathers
+    are the TPU's slow path (see _spread_boosts, the wave kernel's)."""
+    S, N = const.spread_vidx.shape
+    if S == 0:
+        return jnp.zeros(N, dtype=dtype)
+
+    def over_nodes(vidx, row):
+        # vidx: (N,) value index, -1 = the node lacks the attribute
+        if row.shape[0] <= SPREAD_SELECT_V:
+            row = jnp.pad(row, (0, -row.shape[0] % 8))  # held by no node
+            hit = (vidx[None, :]
+                   == jnp.arange(row.shape[0], dtype=vidx.dtype)[:, None])
+            per_node = jnp.sum(jnp.where(hit, row[:, None], 0.0), axis=0)
+        else:
+            per_node = row[jnp.maximum(vidx, 0)]
+        return jnp.where(vidx < 0, -1.0, per_node)
+
+    rows = _spread_value_rows(state, const, dtype)
+    return jnp.sum(jax.vmap(over_nodes)(const.spread_vidx, rows), axis=0)
 
 
 def _select_window(score, fit, limit, dtype):
@@ -1005,16 +1034,22 @@ def _make_fused_fn(metas, treedef, group_keys, spread_alg: bool,
 
     def _solve_lanes_in_turn(const, init, batch):
         """The lanes of a dispatch one after the other, each over its
-        own active steps, up to the last lane that has any. A step is a
-        chain of scans over the node axis and costs the same a lane
-        whether lanes ride a vector axis or a loop (v5e, N 16,384: 225
-        us alone, 202 us each of 8 under vmap, 221 each of 8 here), so
-        the loop loses a tenth at full width, while a padded lane and a
-        padded step cost nothing and a retry of 40 placements beside a
-        first attempt of 1,200 pays for 40. One program serves every
-        lane count and width up to its buffers' (E, P). Each lane runs
-        as a batch of one: as plain (N,) vectors the same step read 277
-        us, the (1, N) layout is the one the vmapped scan has."""
+        own active steps, up to the last lane that has any: a padded
+        lane and a padded step cost nothing, and a retry of 40
+        placements beside a first attempt of 1,200 pays for 40. One
+        program serves every lane count and width up to its buffers'
+        (E, P). A step is a chain of some hundred small scans and
+        reductions over the node axis, bound by their latency (v5e,
+        N 16,384, S 1, V 75, PR 31): 64 us alone, as a batch of one or
+        as plain (N,) vectors; 64 us each of 8 here; 24 us each of 8 as
+        a vector axis under vmap (193 us for the eight). So the loop
+        loses 2.7x where eight lanes are equally wide and wins where
+        they differ, which is what a drained queue sends (`spread-drain`:
+        a first attempt beside retries of tens, 1,330 steps a launch, 85
+        ms in turn against 232 ms for the vector's 1,200 steps). A
+        gather in the step is linear in the lanes and levels the three
+        (SPREAD_SELECT_V). Lanes of like width as one vector: ROADMAP
+        A4."""
         steps = active_steps(batch.active)
         trees = (const, init, batch)
         row_of_one = jax.vmap(one, in_axes=(0, 0, 0, None))
@@ -1685,10 +1720,12 @@ def _solve_wave_compact_impl(compact, scal_f, scal_i, pen, sp=None,
         weight_fracs = sp.weights / jnp.maximum(sp.sum_weights, 1e-9)
 
     def _spread_boosts(slot, counts):
-        """(S, B) per-slot spread boost, mirroring _spread_score op for
-        op; slot value indexes live in columns 8.. as exact int floats.
-        Gathers go through one-hot matmuls (V is small; batched gathers
-        under vmap hit TPU slow paths)."""
+        """(S, B) per-slot spread boost, mirroring the whole-axis
+        kernel's _spread_value_rows op for op; slot value indexes live
+        in columns 8.. as exact int floats. The tables are laid over
+        the slots by compare-and-select, not gathered: a batched
+        gather is the TPU's slow path, 7 ns an element on a v5e
+        (measured where it cost most: SPREAD_SELECT_V, _spread_score)."""
         def one_spread(vidx_f, desired, has_targets, weight_frac, cnts):
             missing = vidx_f < 0
             safe = jnp.maximum(vidx_f, 0.0).astype(jnp.int32)
