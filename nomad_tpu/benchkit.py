@@ -1,9 +1,14 @@
-"""Tier-shaped benchmark/parity worlds (BASELINE.md config tiers 1-5).
+"""Tier-shaped worlds (BASELINE.md config tiers 1-5): fixtures, not a
+benchmark.
 
-Shared by tests/test_parity_scale.py (CI scale, CPU) and bench.py (full
-scale, TPU): the same generators build the same world shapes at any size,
-so the parity CI gates exactly what the bench measures
-(reference sweep analog: scheduler/benchmarks/benchmarks_test.go:36-79).
+What tier 1 and ``chip_smoke.py`` call: the fleet and job generators
+(``make_fleet``, ``seed_utilization``, ``tier_job``), the parity and
+placement runs over them (``run_tier_parity``, ``run_tier_placements``)
+and the served-pipeline drives (``run_scale_northstar``,
+``run_scale_churn``, ``run_worker_scaling``), the same world shapes at
+any size (reference sweep analog:
+scheduler/benchmarks/benchmarks_test.go:36-79). Speed is measured by
+``perfbench/run.py`` alone (BENCHMARK.json, PERF.md).
 
 Tiers (BASELINE.md "Targets"):
   1: 3-TG service job (web/api/worker) on a 5-node dev cluster
@@ -32,224 +37,11 @@ from .structs import (
 RACK_COUNT = 25   # reference sweep uses {10,25,50,75} racks
 
 
-def dispatch_health_stamp(platform: str) -> dict:
-    """Breaker/guard/dispatch state for bench artifacts.
-
-    Every artifact carries an EXPLICIT ``degraded`` verdict plus the
-    dispatch-layer state that justifies it, so a run whose device
-    wedged mid-round -- its evals completed by the host oracle -- can
-    never masquerade as a chip result. ``degraded`` is False only for
-    a healthy TPU round; otherwise it names the reason (a CPU backend
-    reads ``cpu-fallback``).
-    """
-    from .solver import guard
-
-    st = guard.state()
-    if platform != "tpu":
-        degraded = "cpu-fallback"
-    elif st["checked"] and not st["ok"]:
-        degraded = "backend-unavailable"
-    elif st["breaker"]["state"] != guard.BREAKER_CLOSED:
-        degraded = f"breaker-{st['breaker']['state']}"
-    else:
-        degraded = False
-    cc = st.get("const_cache", {})
-    pipe = st.get("dispatch_pipeline", {})
-    pc = st.get("pack_cache", {})
-    ar = st.get("pack_arena", {})
-    return {
-        "degraded": degraded,
-        "dispatch_state": {
-            "breaker": st["breaker"]["state"],
-            "breaker_trips": st["breaker"]["trips"],
-            "breaker_recoveries": st["breaker"]["recoveries"],
-            "last_probe": st["breaker"]["last_probe"],
-            "dispatch_ok": st["dispatch"]["ok"],
-            "dispatch_timeout": st["dispatch"]["timeout"],
-            "dispatch_error": st["dispatch"]["error"],
-            "host_fallback_dispatches": st["host_fallback_dispatches"],
-            "backend_ok": st["ok"],
-        },
-        # transfer layer (ISSUE 2): shipped bytes + const-cache hit
-        # rate belong in every artifact so the delta-streaming claim is
-        # measured, not inferred
-        "transfer_state": {
-            "dispatch_bytes_total": st["dispatch"].get("bytes_total", 0),
-            "const_cache_hits": cc.get("hits", 0),
-            "const_cache_misses": cc.get("misses", 0),
-            "const_cache_bytes_saved": cc.get("bytes_saved_total", 0),
-            "const_cache_resident_bytes": cc.get("resident_bytes", 0),
-            "dispatch_depth": pipe.get("depth", 1),
-            # host pack layer (ISSUE 4): the warm-path claim -- packing
-            # amortized across the snapshot -- is measured, not inferred
-            "pack_cache_hits": pc.get("hits", 0),
-            "pack_cache_misses": pc.get("misses", 0),
-            "pack_usage_base_hits": pc.get("usage_base_hits", 0),
-            "pack_arena_reuses": ar.get("reuses", 0),
-            "pack_arena_resident_bytes": ar.get("resident_bytes", 0),
-            "pipeline_staged_total": pipe.get("staged_total", 0),
-        },
-    }
-
-
-def jitcheck_stamp() -> dict:
-    """Dispatch-discipline fields for bench artifacts (ISSUE 10):
-    steady-state retraces, hot-path host syncs and x64 leaks observed
-    during the run. All zero when the sanitizer is off (the default)
-    -- the regress gate (scripts/check_bench_regress.py) only bites on
-    a round that RAN the sanitizer and found violations, and on any
-    round where a previously-zero field goes positive."""
-    from . import jitcheck
-
-    st = jitcheck.state()
-    return {
-        "jitcheck_enabled": st["enabled"],
-        "jit_retrace_count": st["retrace_count"],
-        "jit_host_sync_count": st["host_sync_count"],
-        "jit_x64_leaks": st["x64_leak_count"],
-    }
-
-
-def statecheck_stamp() -> dict:
-    """Snapshot-isolation fields for bench artifacts (ISSUE 11): torn
-    reads, aliasing writes, journal gaps, write skews and stale memos
-    observed during the run. All zero when the sanitizer is off (the
-    default) -- the regress gate (scripts/check_bench_regress.py) only
-    bites on a round that RAN the sanitizer and found violations, and
-    on any round where a previously-zero field goes positive."""
-    from . import statecheck
-
-    st = statecheck.state()
-    return {
-        "statecheck_enabled": st["enabled"],
-        "state_torn_reads": st["torn_read_count"],
-        "state_aliasing_writes": st["aliasing_write_count"],
-        "state_journal_gaps": st["journal_gap_count"],
-        "state_write_skews": st["write_skew_count"],
-        "state_stale_memos": st["stale_memo_count"],
-    }
-
-
-def shardcheck_stamp() -> dict:
-    """Sharding-discipline fields for bench artifacts (ISSUE 15):
-    spec drift vs the parallel/mesh.py registry, implicit transfers
-    into mesh callables and collective-budget excess observed during
-    the run. All zero when the sanitizer is off (the default) -- the
-    regress gate (scripts/check_bench_regress.py) only bites on a
-    round that RAN the sanitizer and found violations, and on any
-    round where a previously-zero field goes positive."""
-    from . import shardcheck
-
-    st = shardcheck.state()
-    return {
-        "shardcheck_enabled": st["enabled"],
-        "shard_spec_drift": st["spec_drift_count"],
-        "shard_implicit_xfer": st["implicit_xfer_count"],
-        "shard_collective_excess": st["collective_excess_count"],
-    }
-
-
-def xferobs_stamp() -> dict:
-    """Transfer-observatory artifact fields (ISSUE 13): ledger byte
-    decomposition totals, byte parity vs the dispatch_bytes counter
-    (must be 0), and the live link-model fit -- so payload-bytes
-    regressions and link-model drift are gated per round
-    (scripts/check_bench_regress.py direction rows) instead of
-    rediscovered by manual capture."""
-    from .solver import xferobs
-
-    return xferobs.bench_fields()
-
-
-def delta_stream_stamp() -> dict:
-    """Delta-streaming artifact fields (ISSUE 20): version-chain
-    promotions/reuses vs wholesale fallbacks and the cumulative delta
-    payload, so the journal->device scatter path's win (and any
-    regression back to re-shipping full tables) is read off every
-    artifact. Gated by scripts/check_bench_regress.py direction rows."""
-    from .solver import constcache
-
-    cc = constcache.stats()
-    return {
-        "delta_stream_enabled": bool(
-            cc.get("delta_stream_enabled", False)),
-        "delta_promotions": cc.get("delta_promotions", 0),
-        "delta_reuses": cc.get("delta_reuses", 0),
-        "delta_fallbacks": cc.get("delta_fallbacks", 0),
-        "delta_bytes_total": cc.get("delta_bytes_total", 0),
-        "delta_chain_resident_bytes": cc.get("chain_resident_bytes", 0),
-    }
-
-
-def artifact_stamp(repo_root: Optional[str] = None) -> dict:
-    """Provenance stamp for every bench artifact so trend tooling can
-    line BENCH_rNN.json files up without guessing (ISSUE 7 satellite):
-
-    - ``round_id``: ``BENCH_ROUND_ID`` env when set, else derived as
-      max(existing BENCH_rNN round numbers) + 1;
-    - ``git_sha``: HEAD at run time (None outside a git checkout);
-    - ``run_id``: a wall-clock-free monotonic sequence number persisted
-      in ``.bench_run_seq`` next to the artifacts -- two runs of the
-      same round stay distinguishable and orderable even on machines
-      with a wandering clock.
-
-    Never raises: a bench run must not die on provenance."""
-    import re
-    import subprocess
-
-    root = repo_root or os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))
-    sha = None
-    try:
-        sha = subprocess.check_output(
-            ["git", "-C", root, "rev-parse", "--short", "HEAD"],
-            stderr=subprocess.DEVNULL, timeout=10).decode().strip() or None
-    except Exception:  # noqa: BLE001 -- not a git checkout / no git
-        pass
-    round_id = os.environ.get("BENCH_ROUND_ID")
-    if not round_id:
-        seen = [0]
-        try:
-            for name in os.listdir(root):
-                m = re.match(r"BENCH_r(\d+)", name)
-                if m:
-                    seen.append(int(m.group(1)))
-        except OSError:
-            pass
-        round_id = f"r{max(seen) + 1:02d}"
-    seq_path = os.path.join(root, ".bench_run_seq")
-    run_id = 0
-    try:
-        with open(seq_path, encoding="utf-8") as f:
-            run_id = int(f.read().strip() or 0)
-    except (OSError, ValueError):
-        pass
-    run_id += 1
-    try:
-        with open(seq_path, "w", encoding="utf-8") as f:
-            f.write(str(run_id))
-    except OSError:
-        pass
-    return {"round_id": round_id, "git_sha": sha, "run_id": run_id}
-
-
-def quality_stamp() -> dict:
-    """Quality/saturation artifact fields (ISSUE 7): fragmentation,
-    shadow-audit drift/mismatch counts and per-stage busy shares from
-    the process-global observatory.  Call while the measured Server is
-    still attached (its shutdown detaches the observatory)."""
-    from .server.quality import observatory
-
-    return observatory.bench_fields()
-
-
 def export_chrome_trace(path: str) -> "str | None":
     """Write the flight recorder's retained eval traces as a
     chrome://tracing / Perfetto JSON artifact (the per-eval span view
-    that explains WHERE a bench round's latency went), meant to land
-    next to the BENCH_*.json line. Returns the written path, or None
-    when tracing is off or nothing was retained -- artifact emission
-    must never fail a bench run."""
+    that explains WHERE a round's latency went). Returns the written
+    path, or None when tracing is off or nothing was retained."""
     import json
 
     from .server.tracing import trace_enabled, tracer
@@ -431,7 +223,7 @@ def run_scale_churn(live_target: int, n_nodes: int = 10000,
     maxrss alone can't show re-use), and ``parity_mismatch`` (must be
     0). The same code path shrinks to a tier-1 smoke
     (tests/test_scale_churn.py), mirroring test_scale_northstar's
-    split; the full-scale run is bench.py ``time_scale_churn``."""
+    split."""
     import os
     import resource
     import time
@@ -670,7 +462,6 @@ def run_scale_churn(live_target: int, n_nodes: int = 10000,
     shipped = (xo1.get("shipped_bytes_total", 0) or 0) - \
               (xo0.get("shipped_bytes_total", 0) or 0)
     out.update({
-        "delta_stream_enabled": bool(cc1.get("delta_stream_enabled")),
         "delta_promotions": cc1["delta_promotions"]
         - cc0["delta_promotions"],
         "delta_reuses": cc1["delta_reuses"] - cc0["delta_reuses"],

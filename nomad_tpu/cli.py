@@ -768,17 +768,17 @@ def cmd_operator_solver(args) -> int:
                   "lpq_dispatches"):
             print(f"mesh.{k:23s} = {me.get(k)}")
         cc = st.get("const_cache") or {}
-        for k in ("enabled", "entries", "resident_bytes", "hits",
+        for k in ("entries", "resident_bytes", "hits",
                   "misses", "bytes_saved_total", "invalidations",
                   "shard_entries", "shard_resident_bytes"):
             print(f"const_cache.{k:16s} = {cc.get(k)}")
         pc = st.get("pack_cache") or {}
-        for k in ("enabled", "hits", "misses", "matrix_hits",
+        for k in ("hits", "misses", "matrix_hits",
                   "matrix_misses", "usage_base_hits",
                   "usage_base_misses", "invalidations"):
             print(f"pack_cache.{k:17s} = {pc.get(k)}")
         ar = st.get("pack_arena") or {}
-        for k in ("enabled", "entries", "in_use", "resident_bytes",
+        for k in ("entries", "in_use", "resident_bytes",
                   "reuses", "allocs", "evictions", "pad_fills_skipped"):
             print(f"pack_arena.{k:17s} = {ar.get(k)}")
         pk = st.get("pack") or {}

@@ -56,9 +56,8 @@ partials are covered by nomadlint's ``no-callsite-jit`` rule instead).
 State rides the usual surfaces: ``stats.jitcheck`` in
 ``/v1/agent/self``, ``operator jitcheck [--sites]`` CLI (exit 1 on
 steady-state retraces), ``jitcheck.json`` in operator debug bundles,
-``nomad.jitcheck.{retrace,host_sync,x64_leak,mutated_cache}``
-counters, and ``jit_*`` fields in bench artifacts gated by
-scripts/check_bench_regress.py.
+and the ``nomad.jitcheck.{retrace,host_sync,x64_leak,mutated_cache}``
+counters.
 
 Knobs: ``NOMAD_TPU_JITCHECK`` (off; ``1`` installs at import),
 ``NOMAD_TPU_JITCHECK_WARMUP`` (1: traces allowed per (site, sig)),
@@ -766,7 +765,7 @@ def maybe_install_from_env() -> None:
 
 def state(sites: bool = False) -> dict:
     """Full checker state (capped); rides /v1/agent/self, the operator
-    CLI, debug bundles and bench artifacts. ``sites=True`` adds the
+    CLI and debug bundles. ``sites=True`` adds the
     per-site trace table (the CLI's --sites view)."""
     if _ACTIVE:
         verify_caches()

@@ -380,7 +380,7 @@ def shard_solver_inputs(mesh, const, init, batch, version=None,
         # change re-installs rather than scattering into a buffer
         # sharded under the old grid.
         store = token = None
-        if delta_src is not None and constcache.delta_stream_enabled():
+        if delta_src is not None:
             store, token = delta_src
             if token is None or not hasattr(store, "alloc_deltas_since"):
                 store = token = None

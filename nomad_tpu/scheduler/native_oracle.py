@@ -4,9 +4,8 @@ Packs a (nodes, job, task-group) world into the dense arrays the native
 `nt_solve_eval` kernel consumes and runs the reference scheduler's per-eval
 inner loop (seeded shuffle + log2-window binpack select + usage carry,
 reference: scheduler/rank.go:205, stack.go:82-95, select.go, util.go:167)
-as compiled C++. This is the *baseline* the TPU solver's `vs_native_host`
-speedup is measured against in bench.py; parity against the Python oracle
-is gated in tests/test_native_oracle.py.
+as compiled C++: a compiled host baseline for the TPU solver; parity
+against the Python oracle is gated in tests/test_native_oracle.py.
 
 Scope matches the bench workload: cpu/mem/disk asks, eligibility from
 job+tg constraints and driver presence, binpack or spread scoring, job

@@ -26,13 +26,6 @@ from .telemetry import metrics
 from .tracing import tracer
 
 
-def _batch_enabled() -> bool:
-    """NOMAD_TPU_PLAN_BATCH=0 is the kill switch: the dispatcher drains
-    one plan at a time and commits through the legacy single-plan path,
-    bit-for-bit the pre-group-commit applier."""
-    return os.environ.get("NOMAD_TPU_PLAN_BATCH", "1") != "0"
-
-
 def _batch_max() -> int:
     try:
         return max(1, int(os.environ.get("NOMAD_TPU_PLAN_BATCH_MAX",
@@ -232,7 +225,7 @@ class Planner:
     cycle), so an overlapping plan never commits out of queue order.
     The solve barrier hints an incoming fused generation
     (``expect_plans``) so all of its plans land in one group instead of
-    trickling into several. ``NOMAD_TPU_PLAN_BATCH=0`` kills all of it.
+    trickling into several.
     """
 
     def __init__(self, state: StateStore, pool_size: Optional[int] = None):
@@ -334,7 +327,7 @@ class Planner:
         hard deadline bound the wait, so over-counted hints (multi-TG
         evals rendezvous once per TG; failed evals submit nothing) cost
         at most the window."""
-        if n <= 0 or not _batch_enabled():
+        if n <= 0:
             return
         w = _batch_window_s()
         now = time.monotonic()
@@ -386,12 +379,9 @@ class Planner:
                 pass
 
     def _drain_locked(self) -> List[_Pending]:
-        """Pop the next commit candidates (cv held, heap non-empty).
-        Serial mode pops exactly one; batch mode drains everything
-        queued, first holding for the barrier's expected group within
-        the rolling window."""
-        if not _batch_enabled():
-            return [heapq.heappop(self._heap)[2]]
+        """Pop the next commit candidates (cv held, heap non-empty):
+        everything queued, first holding for the barrier's expected
+        group within the rolling window."""
         # cross-worker conflict backoff (bounded by the _MAX knob):
         # holding the drain lets the in-flight commit land so the
         # serialized plan re-verifies against fresh state
@@ -557,8 +547,7 @@ class Planner:
         return (future, overlay, commit_items)
 
     def _commit_one(self, item: _Pending, result: PlanResult) -> int:
-        """The legacy single-plan commit (also the batch-of-one path, so
-        NOMAD_TPU_PLAN_BATCH=0 is bit-for-bit the old applier)."""
+        """The single-plan commit: the group of one."""
         try:
             with metrics.measure("nomad.plan.commit"), \
                     tracer.span("plan.commit", ctx=item.trace_ctx,

@@ -53,9 +53,7 @@ scheduling state even when enabled (read-only by construction).
 
 Surfaces: ``GET /v1/operator/quality``, a ``quality`` block (+ sampled
 ``nomad.quality.*`` gauges) on ``/v1/metrics``, ``operator quality``
-in cli.py, ``quality_*``/``stage_busy_pct_*`` fields in bench
-artifacts (benchkit.quality_stamp), and ``quality.json`` in operator
-debug bundles.
+in cli.py, and ``quality.json`` in operator debug bundles.
 """
 from __future__ import annotations
 
@@ -947,30 +945,6 @@ class QualityObservatory:
         if store is None:
             return 0
         return self.placement.parity_mismatch(store)
-
-    def bench_fields(self) -> dict:
-        """Flat artifact fields for bench.py: quality_fragmentation,
-        quality_drift, quality_decision_mismatch, stage_busy_pct_*."""
-        rep = self.report()
-        if not rep.get("enabled"):
-            return {"quality_enabled": False}
-        out = {"quality_enabled": True}
-        p = rep["placement"]
-        if p.get("attached"):
-            out["quality_fragmentation"] = p["fragmentation_index"]
-            out["quality_packing_efficiency"] = \
-                p["packing_efficiency"]["cpu"]
-            out["quality_live_allocs"] = p["fleet"]["live_allocs"]
-        a = rep["audit"]
-        out["quality_drift"] = a["score_drift_max"]
-        out["quality_decision_mismatch"] = a["decision_mismatch_total"]
-        out["quality_audited"] = a["audited"]
-        sat = rep["saturation"]
-        out["stage_bottleneck"] = sat["bottleneck"]
-        for stage, d in sat["stages"].items():
-            key = "stage_busy_pct_" + stage.replace(".", "_")
-            out[key] = d["busy_pct"]
-        return out
 
     def _reset_for_tests(self) -> None:
         self.detach()

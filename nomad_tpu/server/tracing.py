@@ -39,8 +39,7 @@ ctx is ever created, no span recorded) -- the untraced path.
 Surfaces: ``GET /v1/agent/trace`` (list + single fetch, filters
 ``?degraded=1&slowest=N``), ``operator trace <eval-id>`` waterfall
 rendering in cli.py, and a Perfetto/chrome://tracing JSON export
-(``chrome_trace``) that bench runs ship next to their BENCH_*.json
-artifacts (benchkit.export_chrome_trace).
+(``chrome_trace``, written by benchkit.export_chrome_trace).
 """
 from __future__ import annotations
 

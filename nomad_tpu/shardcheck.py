@@ -68,10 +68,9 @@ State rides the usual surfaces: ``stats.shardcheck`` in
 ``/v1/agent/self``, ``operator shardcheck [--compile-audit]
 [--stacks]`` CLI (exit 1 on spec drift / implicit transfers /
 collective excess), the fifth row in ``operator sanitizers``,
-``shardcheck.json`` in operator debug bundles,
+``shardcheck.json`` in operator debug bundles, and the
 ``nomad.shardcheck.{spec_drift,implicit_xfer,collective_excess,
-shard_parity}`` counters, and ``shard_*`` fields in bench artifacts
-gated by scripts/check_bench_regress.py zero-tolerance rows.
+shard_parity}`` counters.
 
 Knobs: ``NOMAD_TPU_SHARDCHECK`` (off; ``1`` installs at import),
 ``NOMAD_TPU_SHARDCHECK_STACK`` (16: witness stack depth),
@@ -861,7 +860,7 @@ def maybe_install_from_env() -> None:
 
 def state(programs: bool = False) -> dict:
     """Full checker state (capped); rides /v1/agent/self, the operator
-    CLI, debug bundles and bench artifacts.  ``programs=True`` adds
+    CLI and debug bundles.  ``programs=True`` adds
     the per-program HLO inventory (the compile-audit view)."""
     with _slock:
         out = {

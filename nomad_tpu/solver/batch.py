@@ -49,19 +49,10 @@ E_BUCKETS = (1, 2, 4, 8, 16, 32)
 BARRIER_TIMEOUT_S = 10.0
 
 
-def dispatch_depth() -> int:
-    """Max fused dispatches in flight across the process
-    (NOMAD_TPU_DISPATCH_DEPTH). Depth 1 is the kill switch: every
-    barrier dispatches synchronously on the last-arriving thread,
-    exactly the pre-pipeline behavior. Depth > 1 routes dispatches
-    through the async pipeline so one generation's host packing and
-    transfer overlap another's device execution (the dispatch round
-    trip and the numpy packing per dispatch stop serializing)."""
-    try:
-        d = int(os.environ.get("NOMAD_TPU_DISPATCH_DEPTH", "2"))
-    except ValueError:
-        return 1
-    return max(1, min(d, 32))
+# Max fused dispatches in flight across the process: every barrier
+# dispatches through the async pipeline, so one generation's host
+# packing and transfer overlap another's device execution.
+DISPATCH_DEPTH = 2
 
 
 class _DispatchPipeline:
@@ -158,7 +149,7 @@ def pipeline_state() -> dict:
     with _PIPELINE_LOCK:
         pipe = _PIPELINE
     return {
-        "depth": dispatch_depth(),
+        "depth": DISPATCH_DEPTH,
         "in_flight": pipe.in_flight() if pipe is not None else 0,
         "staged_total": pipe.staged() if pipe is not None else 0,
         "active": pipe is not None,
@@ -188,13 +179,7 @@ def _e_bucket(e: int) -> int:
 #
 # The pool is a pool (not one buffer) because the pipelined barrier fills
 # generation g+1 while g's dispatch is still in flight. Bounds:
-# NOMAD_TPU_PACK_ARENA_ENTRIES / NOMAD_TPU_PACK_ARENA_MB; kill switch
-# NOMAD_TPU_PACK_ARENA=0 (fresh buffers every generation, the pre-arena
-# behavior).
-
-
-def _arena_enabled() -> bool:
-    return os.environ.get("NOMAD_TPU_PACK_ARENA", "1") != "0"
+# NOMAD_TPU_PACK_ARENA_ENTRIES / NOMAD_TPU_PACK_ARENA_MB.
 
 
 def _arena_max_entries() -> int:
@@ -214,14 +199,13 @@ def _arena_max_bytes() -> int:
 
 
 class _ArenaEntry:
-    __slots__ = ("key", "trees", "nbytes", "pad_valid", "pooled")
+    __slots__ = ("key", "trees", "nbytes", "pad_valid")
 
     def __init__(self, key, trees, nbytes: int):
         self.key = key
         self.trees = trees          # tree name -> list of np arrays
         self.nbytes = nbytes
         self.pad_valid = False      # padding rows hold valid lane data
-        self.pooled = True
 
 
 class _StackArena:
@@ -252,16 +236,15 @@ class _StackArena:
     def acquire(self, key, specs):
         """specs: tree name -> list of (shape, dtype). Returns
         (entry, reused)."""
-        if _arena_enabled():
-            with self._lock:
-                for tok, ent in self._free.items():
-                    if ent.key == key and self._specs_match(ent, specs):
-                        del self._free[tok]
-                        self._free_bytes -= ent.nbytes
-                        self._in_use += 1
-                        self._stats["reuses"] += 1
-                        self._set_writeable(ent, True)
-                        return ent, True
+        with self._lock:
+            for tok, ent in self._free.items():
+                if ent.key == key and self._specs_match(ent, specs):
+                    del self._free[tok]
+                    self._free_bytes -= ent.nbytes
+                    self._in_use += 1
+                    self._stats["reuses"] += 1
+                    self._set_writeable(ent, True)
+                    return ent, True
         trees = {}
         nbytes = 0
         for name, fields in specs.items():
@@ -274,10 +257,7 @@ class _StackArena:
         ent = _ArenaEntry(key, trees, nbytes)
         with self._lock:
             self._stats["allocs"] += 1
-            if _arena_enabled():
-                self._in_use += 1
-            else:
-                ent.pooled = False
+            self._in_use += 1
         return ent, False
 
     @staticmethod
@@ -292,12 +272,8 @@ class _StackArena:
         return True
 
     def release(self, ent) -> None:
-        if not ent.pooled:
-            return
         with self._lock:
             self._in_use -= 1
-            if not _arena_enabled():
-                return
             self._set_writeable(ent, False)
             self._seq += 1
             self._free[self._seq] = ent
@@ -324,7 +300,6 @@ class _StackArena:
             out["entries"] = len(self._free)
             out["in_use"] = self._in_use
             out["resident_bytes"] = self._free_bytes
-        out["enabled"] = _arena_enabled()
         return out
 
 
@@ -482,8 +457,8 @@ def fuse_lanes(lanes: List[PackedLane], e_pad_hint: int = 0
     safe to run while an earlier generation's dispatch is in flight
     (the pipeline's prepare stage)."""
     groups: Dict[tuple, List[int]] = {}
-    # at depth > 1 this runs on the pipeline's intake thread, outside
-    # the dispatch timer
+    # from the barrier this runs on the pipeline's intake thread,
+    # outside the dispatch timer
     with metrics.measure("nomad.solver.fuse"), tracer.span("solver.fuse"):
         for i, lane in enumerate(lanes):
             groups.setdefault(lane.fuse_key(), []).append(i)
@@ -688,12 +663,7 @@ def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
     node id and persists across a batch's barrier generations (multi-TG
     evals rendezvous once per TG) so later generations see earlier ones'
     usage. Results are edited in place.
-
-    Disable with NOMAD_TPU_BATCH_FIXPOINT=0.
     """
-    import os
-    if os.environ.get("NOMAD_TPU_BATCH_FIXPOINT", "1") == "0":
-        return
     if len(lanes) < 2 and not ledger:
         return
 
@@ -854,19 +824,15 @@ class SolveBarrier:
     """Rendezvous point for one batch of eval threads.
 
     Threads call solve() (blocking) or done() (on exit). When arrivals +
-    finished == participants the batch dispatches:
-
-      - depth 1 (NOMAD_TPU_DISPATCH_DEPTH=1, the kill switch): the LAST
-        thread to arrive performs the fused dispatch for everyone and
-        wakes them (baton-passing, the pre-pipeline behavior);
-      - depth > 1 (default): the batch is handed to the process-global
-        dispatch pipeline and the arriving thread joins the waiters.
-        Up to ``depth`` fused dispatches run in flight (each under its
-        OWN guard.run_dispatch watchdog), so a later generation's host
-        packing/transfer overlaps an earlier one's device execution.
-        Completions apply in GENERATION ORDER: the cross-lane fixpoint
-        ledger charges generation g before g+1 even when g+1's device
-        work finishes first."""
+    finished == participants the batch is handed to the process-global
+    dispatch pipeline and the arriving thread joins the waiters. Up to
+    ``depth`` fused dispatches run in flight (each under its OWN
+    guard.run_dispatch watchdog), so a later generation's host
+    packing/transfer overlaps an earlier one's device execution.
+    Completions apply in GENERATION ORDER: the cross-lane fixpoint
+    ledger charges generation g before g+1 even when g+1's device work
+    finishes first. ``depth`` is the pipeline's slot count, for tests
+    (1 = a serial order to compare with); production passes none."""
 
     def __init__(self, participants: int, use_mesh: bool = True,
                  e_pad_hint: int = 0, depth: Optional[int] = None,
@@ -877,13 +843,13 @@ class SolveBarrier:
         self._waiting: List[Tuple[PackedLane, dict]] = []
         self._use_mesh = use_mesh
         self._generation = 0
-        self._depth = dispatch_depth() if depth is None else max(1, depth)
+        self._depth = DISPATCH_DEPTH if depth is None else max(1, depth)
         # called with the lane count each time a generation's results
         # are delivered: each of those evals is about to submit a plan,
         # so the plan applier can hold its drain and commit the whole
         # generation as ONE group (Planner.expect_plans)
         self._plan_group_hint = plan_group_hint
-        # generation-ordered completion for the pipelined mode
+        # generation-ordered completion
         self._complete_cv = threading.Condition()
         self._next_complete = 1
         # pin wave groups' eval axis to the worker's CONFIGURED width, not
@@ -906,7 +872,7 @@ class SolveBarrier:
         (chosen, scores, n_yielded). A dispatch failure re-raises in EVERY
         participating thread (each eval then nacks independently)."""
         # explicit trace handoff: the eval thread's ctx rides the cell
-        # so the dispatch (running on a pipeline thread at depth > 1)
+        # so the dispatch (running on a pipeline thread)
         # can record its spans into every participating eval's trace
         cell: dict = {"trace_ctx": tracer.current()}
         t_arrive = time.time()
@@ -948,70 +914,30 @@ class SolveBarrier:
         gen = self._generation
         lanes = [lane for lane, _ in batch]
 
-        if self._depth > 1:
-            # async: hand the generation to the pipeline; the caller
-            # (an eval thread) falls back into its cv.wait loop and is
-            # woken by the completion. notify_all() is deferred to the
-            # completion path. The prepare stage fills this generation's
-            # arena buffers on the intake thread BEFORE a dispatch slot
-            # frees up, overlapping host packing with the in-flight
-            # generation's device execution.
-            staged: dict = {}
-            e_pad_hint = self._e_pad_hint
+        # hand the generation to the pipeline; the caller (an eval
+        # thread) falls back into its cv.wait loop and is woken by the
+        # completion. The prepare stage fills this generation's arena
+        # buffers on the intake thread BEFORE a dispatch slot frees up,
+        # overlapping host packing with the in-flight generation's
+        # device execution.
+        staged: dict = {}
+        e_pad_hint = self._e_pad_hint
 
-            def _prepare():
-                try:
-                    staged["groups"] = fuse_lanes(lanes,
-                                                  e_pad_hint=e_pad_hint)
-                except Exception:  # noqa: BLE001 -- best effort: the
-                    staged.clear()  # dispatch re-derives (and raises
-                    raise           # under its own watchdog)
+        def _prepare():
+            try:
+                staged["groups"] = fuse_lanes(lanes,
+                                              e_pad_hint=e_pad_hint)
+            except Exception:  # noqa: BLE001 -- best effort: the
+                staged.clear()  # dispatch re-derives (and raises
+                raise           # under its own watchdog)
 
-            _get_pipeline(self._depth).submit(
-                functools.partial(self._dispatch_job, gen, batch, lanes,
-                                  staged),
-                prepare=_prepare)
-            return
-
-        def solve_batch():
-            results = fuse_and_solve(lanes, use_mesh=self._use_mesh,
-                                     e_pad_hint=self._e_pad_hint)
-            _cross_lane_fixpoint(lanes, results, self._ledger)
-            return results
-
-        # group ctx over every waiting eval: the fused dispatch's spans
-        # belong to each of them (the dispatching thread is just the
-        # last arriver, its own eval is one lane among many)
-        gctx = tracer.group([c.get("trace_ctx") for _, c in batch])
-        try:
-            # the fused dispatch (+ the fixpoint's small re-solves) runs
-            # under the watchdog deadline: a mid-flight device wedge
-            # fails EVERY waiter with DispatchFailed, and each eval then
-            # independently degrades to the host oracle (make_solve_hook)
-            # instead of stranding the whole batch
-            from .guard import run_dispatch
-            xfer_tok = xferobs.mark()
-            with tracer.activate(gctx), \
-                    tracer.span("solver.fuse_dispatch", ctx=gctx,
-                                generation=gen, lanes=len(lanes),
-                                depth=1) as sp:
-                results = run_dispatch(solve_batch, label="solver.batch")
-                # waterfall annotation: shipped/resident bytes + link
-                # predicted-vs-actual for this generation's dispatches
-                sp.tag(**xferobs.span_tags(xfer_tok))
-            for (lane, cell), res in zip(batch, results):
-                cell["result"] = res
-        except Exception as e:  # noqa: BLE001 -- waiters must not strand
-            for _, cell in batch:
-                cell["error"] = e
-        finally:
-            self._hint_plan_group(len(batch))
-            with self._complete_cv:
-                self._next_complete = gen + 1
-            self._cv.notify_all()
+        _get_pipeline(self._depth).submit(
+            functools.partial(self._dispatch_job, gen, batch, lanes,
+                              staged),
+            prepare=_prepare)
 
     def _dispatch_job(self, gen: int, batch, lanes,
-                      staged: Optional[dict] = None) -> None:
+                      staged: dict) -> None:
         """One in-flight generation, on a pipeline thread: fused
         dispatch under its own watchdog, then generation-ordered
         fixpoint + wakeup. Every cell gets exactly one result-or-error,
@@ -1031,7 +957,7 @@ class SolveBarrier:
                     tracer.span("solver.fuse_dispatch", ctx=gctx,
                                 generation=gen, lanes=len(lanes),
                                 depth=self._depth,
-                                staged=bool(staged and "groups" in staged),
+                                staged="groups" in staged,
                                 in_flight=pipeline_state()["in_flight"]
                                 ) as sp:
                 results = run_dispatch(
@@ -1065,9 +991,7 @@ class SolveBarrier:
         # only pay a second watchdog when the fixpoint can actually do
         # work (its own early-return conditions); its re-solves are real
         # device dispatches and deserve the same deadline as the fuse
-        fixpoint_needed = (
-            os.environ.get("NOMAD_TPU_BATCH_FIXPOINT", "1") != "0"
-            and (len(lanes) >= 2 or bool(self._ledger)))
+        fixpoint_needed = len(lanes) >= 2 or bool(self._ledger)
         try:
             if err is None and fixpoint_needed:
                 try:

@@ -1212,24 +1212,9 @@ def _wave_p_bucket(p: int) -> int:
 def _wave_unroll() -> int:
     """Scan unroll: 8 on TPU (amortizes per-step loop overhead), 1
     elsewhere (unrolling multiplies the compiled body; CPU/virtual-mesh
-    runs are compile-time-bound, not step-overhead-bound).
-    NOMAD_TPU_WAVE_UNROLL overrides (perf experiments)."""
-    import os
-
+    runs are compile-time-bound, not step-overhead-bound)."""
     import jax as _jax
-    ov = os.environ.get("NOMAD_TPU_WAVE_UNROLL")
-    if ov:
-        return max(1, int(ov))
     return 8 if _jax.default_backend() == "tpu" else 1
-
-
-def _wave_gather_dynslice() -> bool:
-    """Refill-row gather strategy: one-hot masked reduce (default; safe
-    under vmap on TPU) vs dynamic_slice (NOMAD_TPU_WAVE_GATHER=dynslice;
-    perf experiments -- vmapped scalar-index slices lower to gathers,
-    which are fast or slow depending on backend/shape)."""
-    import os
-    return os.environ.get("NOMAD_TPU_WAVE_GATHER") == "dynslice"
 
 
 def _wave_refill_shift(compact, cursor, w, j2, slot, gate, arangeB,
@@ -1241,13 +1226,10 @@ def _wave_refill_shift(compact, cursor, w, j2, slot, gate, arangeB,
     implementation (tests/test_wave_block.py)."""
     C = compact.shape[0]
     B = arangeB.shape[0]
-    if _wave_gather_dynslice():
-        entry_row = jax.lax.dynamic_slice_in_dim(
-            compact, jnp.clip(cursor, 0, C - 1), 1, axis=0)[0]
-    else:
-        oh_c = arangeC == jnp.clip(cursor, 0, C - 1)
-        entry_row = jnp.sum(jnp.where(oh_c[:, None], compact, 0.0),
-                            axis=0)
+    # refill row by a one-hot masked reduce (safe under vmap on TPU;
+    # a vmapped scalar-index slice lowers to a gather)
+    oh_c = arangeC == jnp.clip(cursor, 0, C - 1)
+    entry_row = jnp.sum(jnp.where(oh_c[:, None], compact, 0.0), axis=0)
     take_next = arangeB >= w
     is_last = arangeB == B - 1
     j_sh = jnp.where(is_last, 0,
@@ -1916,14 +1898,6 @@ def _wave_block_shape() -> tuple:
     return 16, 32
 
 
-def _wave_block_enabled() -> bool:
-    """Run-block dispatch gate: on by default everywhere (the CPU test
-    suite then parity-gates it continuously); NOMAD_TPU_WAVE_BLOCK=0
-    falls back to the per-placement compact scan."""
-    import os
-    return os.environ.get("NOMAD_TPU_WAVE_BLOCK", "1") != "0"
-
-
 def _solve_wave_block_impl(compact, scal_f, scal_i, pen,
                            spread_alg: bool = False,
                            dtype_name: str = "float32",
@@ -2576,13 +2550,9 @@ def _solve_wave_preempt_impl(compact, cand, scal_f, scal_i, pen, counts0,
         z = jnp.maximum(pending, 0)
         oh_z = arangeB == z
         zomb = (pending >= 0) & ~jnp.any(oh_z & fit_c)
-        if _wave_gather_dynslice():
-            entry_row = jax.lax.dynamic_slice_in_dim(
-                compact, jnp.clip(cursor, 0, C - 1), 1, axis=0)[0]
-        else:
-            oh_c = arangeC == jnp.clip(cursor, 0, C - 1)
-            entry_row = jnp.sum(jnp.where(oh_c[:, None], compact, 0.0),
-                                axis=0)
+        oh_c = arangeC == jnp.clip(cursor, 0, C - 1)
+        entry_row = jnp.sum(jnp.where(oh_c[:, None], compact, 0.0),
+                            axis=0)
         entry_cd = {
             kk: jnp.sum(jnp.where(oh_c[:, None], vv,
                                   jnp.zeros((), dtype=vv.dtype)),
@@ -2877,8 +2847,7 @@ def solve_lane_wave(const, init, batch, *, spread_alg: bool,
     # step per window event, ~10x fewer sequential steps -- see the
     # block comment at _solve_wave_block_impl); others take the
     # per-placement compact scan.
-    use_block = (_wave_block_enabled()
-                 and sp.counts.shape[-2] == 0
+    use_block = (sp.counts.shape[-2] == 0
                  and bool((np.asarray(pen) < 0).all()))
     fn = _wave_compact_program(compact.shape, sp.counts.shape,
                                spread_alg, dtype_name, batched, B,

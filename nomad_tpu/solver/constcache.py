@@ -27,13 +27,12 @@ Accounting: every dispatch path reports bytes actually shipped through
 ``note_dispatch_bytes`` -> the ``nomad.solver.dispatch_bytes`` gauge +
 ``nomad.solver.dispatch_bytes_total`` counter, and hits/misses ride
 ``nomad.solver.const_cache_{hit,miss}`` -- so the transfer cut is
-visible in /v1/agent/self, ``operator solver status`` and bench
-artifacts rather than inferred.
+visible in /v1/agent/self and ``operator solver status`` rather than
+inferred.
 
-Kill switch: NOMAD_TPU_CONST_CACHE=0 (every dispatch ships everything,
-exactly the pre-cache behavior). Bounds: NOMAD_TPU_CONST_CACHE_ENTRIES
-(default 64), NOMAD_TPU_CONST_CACHE_MB (default 256). Arrays smaller
-than NOMAD_TPU_CONST_CACHE_MIN_BYTES (default 4096) are always shipped
+Bounds: NOMAD_TPU_CONST_CACHE_ENTRIES (default 64),
+NOMAD_TPU_CONST_CACHE_MB (default 256). Arrays smaller than
+NOMAD_TPU_CONST_CACHE_MIN_BYTES (default 4096) are always shipped
 fresh -- they ARE the delta traffic the design wants on the wire, and
 caching them would churn the LRU for nothing.
 
@@ -56,10 +55,9 @@ program per shape/dtype/update-count bucket). The entry at v_old plus
 the applied delta IS the entry at v_new: same content-key discipline
 (the promoted content's fingerprint re-registers with jitcheck and
 enters the content cache), with wholesale re-upload as the fallback on
-journal gaps/overflow or oversized diffs, and NOMAD_TPU_DELTA_STREAM=0
-as the bit-for-bit kill switch. Every delta payload is tagged into the
-transfer ledger's ``delta`` tree group, so the zero-tolerance byte
-parity and the fold-parity gate remain the correctness net.
+journal gaps/overflow or oversized diffs. Every delta payload is tagged
+into the transfer ledger's ``delta`` tree group, so the zero-tolerance
+byte parity and the fold-parity gate remain the correctness net.
 """
 from __future__ import annotations
 
@@ -142,21 +140,6 @@ class _ChainEntry:
         self.deltas_applied = 0      # scatters since the wholesale put
         self.created_at = time.time()
         self.hits = 0
-
-
-def enabled() -> bool:
-    return os.environ.get("NOMAD_TPU_CONST_CACHE", "1") != "0"
-
-
-def delta_stream_enabled() -> bool:
-    """Delta-streaming master switch (ISSUE 20). Off
-    (``NOMAD_TPU_DELTA_STREAM=0``) every chain-eligible array ships
-    through the plain content-cache path, bit-for-bit the pre-delta
-    behavior -- the rollback oracle the OPERATIONS.md delta-streaming
-    runbook documents. Rides the const-cache switch: no resident
-    buffers means nothing to delta against."""
-    return (enabled()
-            and os.environ.get("NOMAD_TPU_DELTA_STREAM", "1") != "0")
 
 
 def _max_entries() -> int:
@@ -476,11 +459,11 @@ def device_put_cached(arrays: Sequence[np.ndarray],
 
     ``delta_src`` is the ISSUE-20 delta-streaming hookup: a
     ``(store, token)`` pair -- the state store owning the alloc-delta
-    journal and the dispatch's snapshot index. When set (and
-    NOMAD_TPU_DELTA_STREAM is on), arrays that miss the content cache
-    route through the version chain (``chain_apply``): journal-covered
-    generations ship only their bitwise diff and scatter it into the
-    resident buffer on device, instead of re-uploading the table."""
+    journal and the dispatch's snapshot index. When set, arrays that
+    miss the content cache route through the version chain
+    (``chain_apply``): journal-covered generations ship only their
+    bitwise diff and scatter it into the resident buffer on device,
+    instead of re-uploading the table."""
     import jax
 
     from ..server.telemetry import metrics
@@ -490,17 +473,10 @@ def device_put_cached(arrays: Sequence[np.ndarray],
         return tags[i] if tags is not None else "untagged"
 
     arrays = [np.asarray(a) for a in arrays]
-    if not enabled():
-        shipped = sum(a.nbytes for a in arrays)
-        for i, a in enumerate(arrays):
-            xferobs.note_payload(tag_of(i), a.nbytes)
-        note_dispatch_bytes(shipped)
-        return list(jax.device_put(arrays)) if arrays else [], shipped
-
     from .. import jitcheck
 
     store = token = None
-    if delta_src is not None and delta_stream_enabled():
+    if delta_src is not None:
         store, token = delta_src
         if token is None or not hasattr(store, "alloc_deltas_since"):
             store = token = None
@@ -668,9 +644,8 @@ def device_put_sharded_cached(arrays: Sequence[np.ndarray],
     (xferobs.note_shard_bytes): the production-path source of the
     ``per_shard`` rows shardcheck otherwise only writes while enabled.
     ``fallback_put(arr, sharding)`` performs the whole-array sharded
-    put for small / cache-disabled arrays; callers pass a
-    parallel/mesh.py closure so the no-implicit-put lint discipline
-    holds."""
+    put for small arrays; callers pass a parallel/mesh.py closure so
+    the no-implicit-put lint discipline holds."""
     import jax
 
     from ..server.telemetry import metrics
@@ -684,7 +659,6 @@ def device_put_sharded_cached(arrays: Sequence[np.ndarray],
 
     arrays = [np.asarray(a) for a in arrays]
     min_b = _min_bytes()
-    use_cache = enabled()
     buffers: List = [None] * len(arrays)
     shipped = 0
     hits = misses = saved = 0
@@ -693,7 +667,7 @@ def device_put_sharded_cached(arrays: Sequence[np.ndarray],
     per_arr_parts: dict = {}
     with _LOCK:
         for i, (arr, sharding) in enumerate(zip(arrays, shardings)):
-            if not use_cache or arr.nbytes < min_b:
+            if arr.nbytes < min_b:
                 continue                     # fallback path, below
             idx_map = sharding.addressable_devices_indices_map(arr.shape)
             devs = sorted(idx_map, key=lambda d: d.id)
@@ -748,8 +722,8 @@ def device_put_sharded_cached(arrays: Sequence[np.ndarray],
     for i, (sharding, parts) in per_arr_parts.items():
         buffers[i] = jax.make_array_from_single_device_arrays(
             arrays[i].shape, sharding, parts)
-    # fallback: small / cache-disabled arrays ship whole via the
-    # caller's parallel/mesh.py put closure
+    # fallback: small arrays ship whole via the caller's
+    # parallel/mesh.py put closure
     fresh_idx = [i for i, b in enumerate(buffers)
                  if b is None]
     for i in fresh_idx:
@@ -935,8 +909,6 @@ def stats() -> dict:
         out["entries"] = len(_CACHE)
         out["shard_entries"] = len(_SHARD_CACHE)
         out["chain_entries"] = len(_CHAIN)
-    out["enabled"] = enabled()
-    out["delta_stream_enabled"] = delta_stream_enabled()
     return out
 
 

@@ -36,8 +36,8 @@ host path); a background recovery thread then probes with exponential
 backoff (``NOMAD_TPU_BREAKER_BACKOFF`` .. ``_BACKOFF_MAX``) -- one
 trivial jitted dispatch under the dispatch deadline -- and auto-closes
 the breaker when a probe passes. Breaker state, trip/recovery counters
-and per-dispatch outcomes flow into ``state()`` -> /v1/agent/self,
-telemetry, and the bench artifacts (benchkit.dispatch_health_stamp).
+and per-dispatch outcomes flow into ``state()`` -> /v1/agent/self and
+telemetry.
 """
 from __future__ import annotations
 
@@ -770,8 +770,8 @@ def reprobe(timeout_s: Optional[float] = None) -> dict:
 
 
 def state() -> dict:
-    """Guard snapshot for /v1/agent/self, telemetry dumps, and bench
-    artifacts. ``degraded`` is the one-glance verdict: True whenever ANY
+    """Guard snapshot for /v1/agent/self and telemetry dumps.
+    ``degraded`` is the one-glance verdict: True whenever ANY
     layer is routing evals to the host oracle."""
     from ..server.telemetry import metrics
     with _LOCK:

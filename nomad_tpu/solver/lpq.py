@@ -137,7 +137,7 @@ def lpq_active(state) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# stats (bench + status surfaces)
+# stats
 # ---------------------------------------------------------------------------
 
 _STATS_LOCK = threading.Lock()
@@ -159,7 +159,7 @@ def _stat_set(name: str, v) -> None:
 
 
 def lpq_stats() -> dict:
-    """Snapshot for bench.py time_lpq / status surfaces."""
+    """Snapshot of the LP tier's counters."""
     with _STATS_LOCK:
         out = dict(_STATS)
     solves = out["solves"]

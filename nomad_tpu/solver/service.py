@@ -22,9 +22,7 @@ from ..structs import (
     CONSTRAINT_DISTINCT_HOSTS,
 )
 from ..tensor import (
-    pack_affinities, pack_affinities_cached, pack_feasibility,
-    pack_feasibility_cached, pack_nodes, pack_spreads, pack_spreads_cached,
-    pack_usage,
+    pack_affinities_cached, pack_feasibility_cached, pack_spreads_cached,
 )
 from ..scheduler.util import shuffled_order
 
@@ -111,10 +109,7 @@ class PackedLane:
         return self._wave
 
     def _wavefront_check(self) -> bool:
-        import os
         from .binpack import wavefront_buffer_size
-        if os.environ.get("NOMAD_TPU_WAVEFRONT", "1") == "0":
-            return False
         if self.ptab is not None:
             # windowed preemption (solve_lane_wave_preempt): spreads stay
             # dense (the preempt slot kernel carries no spread columns);
@@ -122,8 +117,6 @@ class PackedLane:
             # tg_solver_eligible(preempt=True); devices ride via the
             # capacity-countdown column when _wave_devices_ok passes
             # (checked in the shared section below)
-            if os.environ.get("NOMAD_TPU_WAVEFRONT_PREEMPT", "1") == "0":
-                return False
             if self.const.spread_vidx.shape[0]:
                 return False
             # max_parallel penalties couple the greedy's pick ORDER to the
@@ -450,7 +443,6 @@ class TpuPlacementService:
                     ) -> Optional[PackedLane]:
         from .binpack import (
             PlacementBatch, make_node_const, make_node_state)
-        from ..tensor.pack import pack_cache_enabled
 
         if (not tg_solver_eligible(tg, self.job, preempt=self.preempt)
                 or not places):
@@ -492,39 +484,24 @@ class TpuPlacementService:
         if (table is not None and not table.has_port_overflow
                 and proposed_by_node is None):
             usage = self._pack_usage_from_table(table, matrix, nodes, tg)
-        elif pack_cache_enabled():
+        else:
             # incremental path: snapshot-scoped base fold + this eval's
             # own plan deltas -- O(plan) per eval instead of O(allocs)
             usage = self._pack_usage_incremental(matrix, nodes, tg)
-        else:
-            if proposed_by_node is None:
-                proposed_by_node = {
-                    node.id: self.ctx.proposed_allocs(node.id)
-                    for node in nodes}
-            usage = pack_usage(matrix, proposed_by_node, self.job.id, tg.name,
-                               self.job.namespace, nodes)
 
         feasible = pack_feasibility_cached(
             self.ctx, None, tg, nodes, n_pad,
-            alloc_name=places[0].name, matrix=matrix) \
-            if pack_cache_enabled() else \
-            pack_feasibility(self.ctx, None, tg, nodes, n_pad,
-                             alloc_name=places[0].name, matrix=matrix)
+            alloc_name=places[0].name, matrix=matrix)
 
         affinities = (list(self.job.affinities) + list(tg.affinities)
                       + [a for t in tg.tasks for a in t.affinities])
         spreads = list(self.job.spreads) + list(tg.spreads)
         existing_counts = self._existing_spread_counts(spreads, tg)
-        if pack_cache_enabled():
-            affinity = pack_affinities_cached(affinities, self.ctx, nodes,
-                                              n_pad, matrix=matrix)
-            spread_info = pack_spreads_cached(spreads, nodes, n_pad,
-                                              tg.count, existing_counts,
-                                              matrix=matrix)
-        else:
-            affinity = pack_affinities(affinities, self.ctx, nodes, n_pad)
-            spread_info = pack_spreads(spreads, nodes, n_pad, tg.count,
-                                       existing_counts)
+        affinity = pack_affinities_cached(affinities, self.ctx, nodes,
+                                          n_pad, matrix=matrix)
+        spread_info = pack_spreads_cached(spreads, nodes, n_pad,
+                                          tg.count, existing_counts,
+                                          matrix=matrix)
 
         distinct_job_level = any(
             c.operand == CONSTRAINT_DISTINCT_HOSTS
@@ -1136,70 +1113,41 @@ class TpuPlacementService:
         O(nodes x allocs) walk. Bases carrying a port bitmap are refolded
         per eval rather than memoized (an 80MB bitmap per snapshot is the
         same trade _pack_usage_from_table's fold cache makes)."""
-        from ..state.alloc_table import pack_delta_enabled
         from ..tensor.pack import (
             UsageState, _stat_incr, fold_usage_base, freeze_usage_base)
 
         snap = self.ctx.state
         token = snap.latest_index()
         base = None
-        if pack_delta_enabled():
-            # matrix-attached memo: the matrix is stable across snapshots
-            # while the node table is unchanged, so a base folded for an
-            # EARLIER snapshot catches up by applying the alloc deltas
-            # the store journaled in between (_bump delta context) --
-            # O(changed allocs) per snapshot instead of O(all allocs)
-            store = getattr(snap, "_store", snap)
-            ent = getattr(matrix, "_usage_base", None)
-            if ent is not None and ent[0] is store:
-                if ent[1] == token:
-                    base = ent[2]
-                    _stat_incr("usage_base_hits")
-                    from .. import statecheck
-                    if statecheck._ACTIVE:
-                        # version-token discipline (statecheck check e):
-                        # a hit must serve exactly the snapshot's index
-                        statecheck.note_memo_served(
-                            "usage_base", ent[1], token)
-                elif ent[1] < token:
-                    base = self._catch_up_usage_base(
-                        matrix, store, ent, token)
-            if base is None:
-                base = fold_usage_base(
-                    matrix, nodes,
-                    lambda nid: [a for a in snap.allocs_by_node(nid)
-                                 if not a.client_terminal_status()])
-                _stat_incr("usage_base_misses")
-                if base["ports"] is None:
-                    freeze_usage_base(base)
-                    matrix._usage_base = (store, token, base)
-        else:
-            # NOMAD_TPU_PACK_DELTA=0 kill switch: the PR-4/5 wholesale
-            # path -- snapshot-scoped memo, full refold per snapshot
-            memo = snap.__dict__.get("_usage_base_memo")
-            if memo is not None:
-                ent = memo.get(id(matrix))
-                # identity + index check: a live store's memo must die on
-                # any write; a snapshot's latest_index() never moves
-                if ent is not None and ent[0] is matrix and \
-                        ent[1] == token:
-                    base = ent[2]
-                    from .. import statecheck
-                    if statecheck._ACTIVE:
-                        statecheck.note_memo_served(
-                            "usage_base", ent[1], token)
-            if base is None:
-                base = fold_usage_base(
-                    matrix, nodes,
-                    lambda nid: [a for a in snap.allocs_by_node(nid)
-                                 if not a.client_terminal_status()])
-                _stat_incr("usage_base_misses")
-                if base["ports"] is None:
-                    freeze_usage_base(base)
-                    snap.__dict__.setdefault("_usage_base_memo", {})[
-                        id(matrix)] = (matrix, token, base)
-            else:
+        # matrix-attached memo: the matrix is stable across snapshots
+        # while the node table is unchanged, so a base folded for an
+        # EARLIER snapshot catches up by applying the alloc deltas the
+        # store journaled in between (_bump delta context) -- O(changed
+        # allocs) per snapshot instead of O(all allocs)
+        store = getattr(snap, "_store", snap)
+        ent = getattr(matrix, "_usage_base", None)
+        if ent is not None and ent[0] is store:
+            if ent[1] == token:
+                base = ent[2]
                 _stat_incr("usage_base_hits")
+                from .. import statecheck
+                if statecheck._ACTIVE:
+                    # version-token discipline (statecheck check e): a
+                    # hit must serve exactly the snapshot's index
+                    statecheck.note_memo_served(
+                        "usage_base", ent[1], token)
+            elif ent[1] < token:
+                base = self._catch_up_usage_base(
+                    matrix, store, ent, token)
+        if base is None:
+            base = fold_usage_base(
+                matrix, nodes,
+                lambda nid: [a for a in snap.allocs_by_node(nid)
+                             if not a.client_terminal_status()])
+            _stat_incr("usage_base_misses")
+            if base["ports"] is None:
+                freeze_usage_base(base)
+                matrix._usage_base = (store, token, base)
 
         n_pad = matrix.n_pad
         placed = np.zeros(n_pad, dtype=np.int32)

@@ -53,10 +53,8 @@ before touching any state (bitwise no-op, parity-tested).  Bounds:
 Surfaces: ``stats.xferobs`` in ``GET /v1/agent/self``, ``operator
 transfers`` in cli.py (ledger table + residency map + link fit),
 ``xferobs.json`` in operator debug bundles, ``nomad.xfer.*`` telemetry
-series, Perfetto counter tracks (shipped bytes / resident bytes /
-in-flight depth) in ``benchkit.export_chrome_trace``, and ``xfer_*``
-fields in bench artifacts (benchkit.xferobs_stamp) gated by
-scripts/check_bench_regress.py direction rows.
+series, and Perfetto counter tracks (shipped bytes / resident bytes /
+in-flight depth) in ``benchkit.export_chrome_trace``.
 """
 from __future__ import annotations
 
@@ -71,7 +69,7 @@ __all__ = [
     "enabled", "note_payload", "note_shipped", "note_fetch",
     "note_resident_level", "note_shard_bytes", "begin_dispatch",
     "end_dispatch", "mark", "span_tags", "tree_nbytes", "state",
-    "parity", "shard_parity", "bench_fields", "counter_events",
+    "parity", "shard_parity", "counter_events",
     "residency_report",
 ]
 
@@ -257,6 +255,13 @@ class _Ledger:
             g[2] += 1
 
     def note_shipped(self, n: int) -> None:
+        rec = self._rec()
+        if rec is not None:
+            # deferred with the record's payload notes: the tagged sum
+            # and its mirror move under ONE lock acquisition, so
+            # parity() reads 0 while a dispatch is in flight too
+            rec["mirror"] += int(n)
+            return
         with self._lock:
             self._shipped_mirror += int(n)
 
@@ -303,7 +308,7 @@ class _Ledger:
     # -- dispatch records -----------------------------------------------
     def begin_dispatch(self, **meta) -> None:
         self._tls.rec = {"t0": time.time(), "bytes": {}, "fetched": 0,
-                         "fetch_tags": {}, "meta": meta}
+                         "fetch_tags": {}, "mirror": 0, "meta": meta}
 
     def end_dispatch(self, dur_ms: float) -> Optional[dict]:
         rec = self._rec()
@@ -328,6 +333,7 @@ class _Ledger:
                     f = self._fetches[group] = [0, 0]
                 f[0] += fb[0]
                 f[1] += fb[1]
+            self._shipped_mirror += rec["mirror"]
             self._dispatches += 1
             self._seq += 1
             self.link.add(payload, dur_ms)
@@ -600,42 +606,6 @@ def state() -> dict:
         out["residency"] = residency_report()
     except Exception:  # noqa: BLE001 -- status must never fail the agent
         out["residency"] = {}
-    return out
-
-
-def bench_fields() -> dict:
-    """Flat xfer_* artifact fields for bench.py (both the headline and
-    tier tails), gated by check_bench_regress.py direction rows."""
-    if not enabled():
-        return {"xferobs_enabled": False}
-    snap = _LEDGER.snapshot()
-    out = {
-        "xferobs_enabled": True,
-        "xfer_payload_bytes_shipped": snap["shipped_bytes_total"],
-        "xfer_payload_bytes_resident": snap["resident_bytes_total"],
-        "xfer_payload_bytes_fetched": snap["fetched_bytes_total"],
-        "xfer_resident_hwm_bytes": snap["resident_hwm_bytes"],
-        "xfer_dispatches": snap["dispatches"],
-        # absolute value: drift in EITHER direction (bytes missing from
-        # the decomposition, or double-attributed) fails the
-        # lower-better zero-tolerance regress row
-        "xfer_ledger_parity": abs(snap["parity_bytes"]),
-    }
-    if snap["dispatches"]:
-        out["xfer_shipped_bytes_per_dispatch"] = round(
-            snap["shipped_bytes_total"] / snap["dispatches"], 1)
-    fit = snap["link"]
-    if fit is not None and fit["samples"] >= _FIT_MIN_SAMPLES:
-        out["xfer_rtt_ms"] = fit["rtt_ms"]
-        # null when no bandwidth term is identifiable (a local backend
-        # whose wall time is compute-bound fits slope 0): the field
-        # stays present so trend tooling sees "unidentifiable", not
-        # "observatory absent"; the regress gate warns on non-numeric
-        out["xfer_bw_mbps"] = fit["bw_mbps"]
-        if fit["crossover_bytes"] is not None:
-            out["xfer_crossover_bytes"] = fit["crossover_bytes"]
-        out["xfer_fit_samples"] = fit["samples"]
-        out["xfer_fit_residual_ms"] = fit["residual_rms_ms"]
     return out
 
 

@@ -11,7 +11,6 @@ incrementally at write time.
 from __future__ import annotations
 
 import hashlib
-import os
 import threading
 from functools import lru_cache
 from typing import Dict, Optional
@@ -21,16 +20,6 @@ import numpy as np
 from .. import native
 
 MAX_PORTS = native.MAX_PORTS_PER_ALLOC
-
-
-def pack_delta_enabled() -> bool:
-    """Incremental fold maintenance (ISSUE 6): every alloc write adjusts
-    the resident per-slot usage/verify folds in place instead of
-    invalidating them wholesale, so sustained churn pays O(write) rather
-    than an O(rows) refold per table version. ``NOMAD_TPU_PACK_DELTA=0``
-    is the kill switch restoring the wholesale-invalidation path
-    bit-for-bit (test-gated)."""
-    return os.environ.get("NOMAD_TPU_PACK_DELTA", "1") != "0"
 
 
 @lru_cache(maxsize=65536)
@@ -82,14 +71,9 @@ class AllocTable:
         self._node_cap = 256
         self.dyn_lo = np.full(self._node_cap, 20000, dtype=np.int32)
         self.dyn_hi = np.full(self._node_cap, 32000, dtype=np.int32)
-        # verify-fold memo: one vectorized per-slot usage fold per table
-        # VERSION, shared by every plan the applier verifies between two
-        # commits (a batch of 32 plans used to pay 32 full-table folds).
-        # Only used on the NOMAD_TPU_PACK_DELTA=0 kill-switch path; with
-        # deltas on, _fold_inc below is maintained in place instead.
-        self._verify_fold_cache: Optional[tuple] = None
         # incremental per-slot fold columns (built lazily on first use,
-        # then adjusted by every upsert/remove): uc/um/ud under the
+        # then adjusted by every upsert/remove, so sustained churn pays
+        # O(write) not an O(rows) refold a version): uc/um/ud under the
         # scheduler's `live` filter (serves pack()'s non-port lanes),
         # vc/vm/vd/vspec under the applier's `live_strict` filter
         # (serves _fold_verify_all). vspec is a COUNT of live special
@@ -119,7 +103,7 @@ class AllocTable:
         self.dyn_hi[slot] = node.node_resources.max_dynamic_port
         return slot
 
-    # -- incremental fold maintenance (NOMAD_TPU_PACK_DELTA) ------------
+    # -- incremental fold maintenance ------------------------------------
     def _fold_inc_build(self) -> dict:
         """Full recount into the per-slot incremental fold columns; the
         ground truth every delta adjustment must stay equal to
@@ -149,9 +133,7 @@ class AllocTable:
         self._fold_inc = inc
         return inc
 
-    def _fold_inc_get(self) -> Optional[dict]:
-        if not pack_delta_enabled():
-            return None
+    def _fold_inc_get(self) -> dict:
         inc = self._fold_inc
         if inc is None:
             inc = self._fold_inc_build()
@@ -220,14 +202,8 @@ class AllocTable:
         """Per-node-id (used_cpu, used_mem, used_disk) under the
         scheduler's `live` filter, served from the incremental fold
         columns (built on demand).  Caller holds the owning store's
-        lock.  On the NOMAD_TPU_PACK_DELTA=0 kill-switch path the fold
-        is computed fresh and NOT retained, so the wholesale-
-        invalidation write path stays bit-for-bit untouched."""
+        lock."""
         inc = self._fold_inc_get()
-        transient = inc is None
-        if transient:
-            inc = self._fold_inc_build()
-            self._fold_inc = None
         out = {}
         for nid, slot in self._slot_of_node.items():
             out[nid] = (float(inc["uc"][slot]), float(inc["um"][slot]),
@@ -456,8 +432,8 @@ class AllocTable:
         # (potentially 80MB) bitmap fold entirely otherwise.
         use_ports = with_ports and (self.rows_with_ports > 0
                                     or port_words_seed is not None)
-        inc = None if use_ports else self._fold_inc_get()
-        if inc is not None:
+        if not use_ports:
+            inc = self._fold_inc_get()
             # incremental path: gather the resident per-slot fold into the
             # caller's node ordering -- O(nodes) per pack instead of the
             # O(rows) native fold per table version (what sustained churn
@@ -478,49 +454,22 @@ class AllocTable:
             native.pack_usage(
                 mapped.astype(np.int32), self.cpu[:n], self.mem[:n],
                 self.disk[:n], self.live[:n],
-                self.ports[:n] if use_ports else None,
-                dyn_lo_pos, dyn_hi_pos, n_pad,
-                port_words_seed=port_words_seed if with_ports else None)
+                self.ports[:n], dyn_lo_pos, dyn_hi_pos, n_pad,
+                port_words_seed=port_words_seed)
         return {"used_cpu": used_cpu, "used_mem": used_mem,
                 "used_disk": used_disk, "dyn_used": dyn_used,
                 "port_words": port_words, "row_slots": mapped}
 
     def _fold_verify_all(self):
         """Per-SLOT (used_cpu, used_mem, used_disk, special_any) under the
-        applier's live_strict filter, memoized by table version. One
-        vectorized pass over all rows serves every fold_verify call until
-        the next mutation -- the group-commit applier verifies a whole
-        batch of plans between two commits, so the fold amortizes across
-        the batch (and across the barrier's 32 lanes at headline shape).
-        With NOMAD_TPU_PACK_DELTA on (the default) the fold is served
-        straight from the incrementally-maintained columns -- no refold
-        on version change at all; the version-keyed memo below is the
-        kill-switch (wholesale invalidation) path."""
+        applier's live_strict filter, served straight from the
+        incrementally-maintained columns: no refold on a version change,
+        so the group-commit applier's whole batch of plans between two
+        commits reads one resident fold."""
         inc = self._fold_inc_get()
-        if inc is not None:
-            n = self.n_nodes
-            return (inc["vc"][:n], inc["vm"][:n], inc["vd"][:n],
-                    inc["vspec"][:n] > 0)
-        cache = self._verify_fold_cache
-        if cache is not None and cache[0] == self.version:
-            return cache[1]
-        n = self.n_rows
-        nslots = self.n_nodes
-        used_c = np.zeros(nslots)
-        used_m = np.zeros(nslots)
-        used_d = np.zeros(nslots)
-        spec = np.zeros(nslots, dtype=bool)
-        if n and nslots:
-            slots = self.node_slot[:n]
-            live = (self.live_strict[:n] > 0) & (slots >= 0)
-            m = slots[live]
-            np.add.at(used_c, m, self.cpu[:n][live])
-            np.add.at(used_m, m, self.mem[:n][live])
-            np.add.at(used_d, m, self.disk[:n][live])
-            spec[slots[live & (self.special[:n] > 0)]] = True
-        folded = (used_c, used_m, used_d, spec)
-        self._verify_fold_cache = (self.version, folded)
-        return folded
+        n = self.n_nodes
+        return (inc["vc"][:n], inc["vm"][:n], inc["vd"][:n],
+                inc["vspec"][:n] > 0)
 
     def fold_verify(self, node_ids):
         """Per-node (used_cpu, used_mem, used_disk, special_any, found)
@@ -584,7 +533,6 @@ class AllocTable:
         self.n_rows = k
         self._cap = new_cap
         self.version += 1
-        self._verify_fold_cache = None
         self._fold_inc = None       # lazily rebuilt from the dense rows
         return {"rows_before": old_rows, "rows_after": k,
                 "cap_before": old_cap, "cap_after": new_cap}

@@ -73,9 +73,8 @@ churn-storm / lpq suites) installs the patches.
 State rides the usual surfaces: ``stats.statecheck`` in
 ``/v1/agent/self``, ``operator statecheck [--stacks]`` CLI (exit 1 on
 torn reads or aliasing writes), ``statecheck.json`` in operator debug
-bundles, ``nomad.statecheck.{torn_read,aliasing_write,journal_gap,
-write_skew,stale_memo}`` counters, and ``state_*`` fields in bench
-artifacts gated by scripts/check_bench_regress.py.
+bundles, and the ``nomad.statecheck.{torn_read,aliasing_write,
+journal_gap,write_skew,stale_memo}`` counters.
 
 Knobs: ``NOMAD_TPU_STATECHECK`` (off; ``1`` installs at import),
 ``NOMAD_TPU_STATECHECK_STACK`` (16: witness stack depth),
@@ -887,7 +886,7 @@ def maybe_install_from_env() -> None:
 
 def state() -> dict:
     """Full checker state (capped); rides /v1/agent/self, the operator
-    CLI, debug bundles and bench artifacts."""
+    CLI and debug bundles."""
     if _ACTIVE:
         verify_state()
     with _slock:

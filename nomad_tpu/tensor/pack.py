@@ -11,7 +11,6 @@ per fleet size (SURVEY.md section 7 hard part 6: bucket-and-pad).
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -130,14 +129,6 @@ _NODE_MATRIX_LOCK = _threading.Lock()
 # existing hooks: a node-table write mints a new matrix key (state/store
 # _bump also drops stale-version matrices here), and the dispatch
 # breaker's trip/recovery edges clear everything (solver/guard.py).
-#
-# Kill switch: NOMAD_TPU_PACK_CACHE=0 bypasses every memo and restores
-# the per-eval repack path bit-for-bit.
-
-
-def pack_cache_enabled() -> bool:
-    return os.environ.get("NOMAD_TPU_PACK_CACHE", "1") != "0"
-
 
 _PACK_STATS = {
     "hits": 0,              # feasibility/spread/affinity memo hits
@@ -191,7 +182,6 @@ def pack_cache_stats() -> dict:
         out = dict(_PACK_STATS)
     with _NODE_MATRIX_LOCK:
         out["matrix_entries"] = len(_NODE_MATRIX_CACHE)
-    out["enabled"] = pack_cache_enabled()
     return out
 
 
@@ -300,7 +290,7 @@ def _matrix_memo(matrix, key, build):
     Results are shared across concurrent evals, so cached arrays are
     frozen read-only -- every consumer copies before mutating (the
     make_node_const/state assemblers permute into fresh arrays)."""
-    if matrix is None or not pack_cache_enabled():
+    if matrix is None:
         return build()
     memo = matrix.__dict__.get("_pack_memo")
     if memo is None:

@@ -3,7 +3,7 @@
 in a docs/OPERATIONS.md knob table row.
 
 The mirror of scripts/check_metrics_doc.py for configuration: scans
-nomad_tpu/ + bench.py for ``os.environ.get/.pop/[...]`` call sites
+nomad_tpu/ for ``os.environ.get/.pop/[...]`` call sites
 naming a ``NOMAD_TPU_*`` variable, and fails listing any knob missing
 from the doc's knob tables (markdown table rows -- lines starting with
 ``|`` -- anywhere in OPERATIONS.md).  Prose mentions do NOT count: the
@@ -49,7 +49,7 @@ def code_knobs(root: str = None) -> dict:
     """knob -> first 'file:line' reading it."""
     root = root or ROOT
     out: dict = {}
-    scan = [os.path.join(root, "bench.py")]
+    scan = []
     for dirpath, dirnames, filenames in os.walk(
             os.path.join(root, "nomad_tpu")):
         dirnames[:] = [d for d in dirnames if d != "__pycache__"]

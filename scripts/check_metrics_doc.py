@@ -2,7 +2,7 @@
 """CI gate: every telemetry series the code emits must appear in the
 docs/OPERATIONS.md "Metrics reference" table.
 
-Scans nomad_tpu/ + bench.py for ``metrics.incr/sample/sample_ms/measure``
+Scans nomad_tpu/ for ``metrics.incr/sample/sample_ms/measure``
 call sites (any local alias -- the codebase uses both ``metrics`` and
 ``_tm``), extracts the literal series names (f-string placeholders
 normalize to ``<...>`` wildcards, ternaries contribute both arms), and
@@ -42,7 +42,7 @@ def _normalize(name: str) -> str:
 def emitted_series() -> dict:
     """name -> first 'file:line' emitting it."""
     out: dict = {}
-    scan = [os.path.join(ROOT, "bench.py")]
+    scan = []
     for dirpath, dirnames, filenames in os.walk(
             os.path.join(ROOT, "nomad_tpu")):
         dirnames[:] = [d for d in dirnames if d != "__pycache__"]

@@ -3,7 +3,7 @@
 
 One gate for the invariants that keep the concurrent control plane
 honest -- the static complement of the runtime lock-order sanitizer
-(nomad_tpu/lockcheck.py).  Scans nomad_tpu/ + bench.py (rules that
+(nomad_tpu/lockcheck.py).  Scans nomad_tpu/ (rules that
 read docs/tests pull those in too) and fails listing violations.
 
 AST rules:
@@ -137,14 +137,9 @@ unchanged):
 
   metrics-doc        scripts/check_metrics_doc.py
   knob-doc           scripts/check_knob_doc.py
-  bench-regress      scripts/check_bench_regress.py (takes the
-                     artifact argv after ``--``, e.g.
-                     ``nomadlint.py --rule bench-regress -- BENCH.json``)
 
-The default run (no ``--rule``) is every AST rule plus metrics-doc and
-knob-doc; bench-regress needs an artifact argument so it only runs
-when selected.  Tier-1 gates the default run via
-tests/test_nomadlint.py.
+The default run (no ``--rule``) is every AST rule plus both.  Tier-1
+gates the default run via tests/test_nomadlint.py.
 
 Waivers (per rule, justification REQUIRED after ``--``)::
 
@@ -206,9 +201,6 @@ class Ctx:
         self.files: List[Tuple[str, str, ast.AST]] = []
         self.parse_errors: List[Violation] = []
         scan = []
-        bench = os.path.join(root, "bench.py")
-        if os.path.exists(bench):
-            scan.append(bench)
         for dirpath, dirnames, filenames in os.walk(
                 os.path.join(root, "nomad_tpu")):
             dirnames[:] = [d for d in dirnames if d != "__pycache__"]
@@ -1375,7 +1367,7 @@ RULE_IDS = ("fire-registered", "killswitch-tested", "telemetry-literal",
             "no-sleep-sync", "daemon-declared", "spec-declared",
             "mesh-factory", "no-implicit-put")
 
-LEGACY_RULES = ("metrics-doc", "knob-doc", "bench-regress")
+LEGACY_RULES = ("metrics-doc", "knob-doc")
 
 
 # ----------------------------------------------------------------------
@@ -1392,11 +1384,9 @@ def _load_legacy(name: str):
     return mod
 
 
-def run_legacy(name: str, argv: List[str]) -> int:
+def run_legacy(name: str) -> int:
     mod = _load_legacy(name)
     try:
-        if name == "bench-regress":
-            return mod.main(argv or [])
         return mod.main()
     except SystemExit as e:         # legacy argparse usage errors
         return int(e.code or 0)
@@ -1449,14 +1439,11 @@ def apply_waivers(root: str, violations: List[Violation],
 
 def collect_waiver_comments(root: str) -> List[Tuple[str, int, str]]:
     """Every ``nomadlint: waive=<rules>`` comment in the scanned tree
-    (nomad_tpu/ + bench.py + tests/, which no-sleep-sync lints) as
+    (nomad_tpu/ + tests/, which no-sleep-sync lints) as
     (rel_path, line, rule) triples -- one per rule id the comment
     names."""
     out: List[Tuple[str, int, str]] = []
     scan = []
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        scan.append(bench)
     for sub in ("nomad_tpu", "tests"):
         for dirpath, dirnames, filenames in os.walk(
                 os.path.join(root, sub)):
@@ -1637,9 +1624,6 @@ def main(argv=None) -> int:
                    "--apply to rewrite the files")
     p.add_argument("--apply", action="store_true",
                    help="with --fix-stale-waivers: actually rewrite")
-    p.add_argument("rest", nargs="*",
-                   help="extra argv for legacy rules (bench-regress "
-                   "artifact)")
     args = p.parse_args(argv)
 
     if args.fix_stale_waivers:
@@ -1719,7 +1703,7 @@ def main(argv=None) -> int:
             print(f"nomadlint: skipping legacy rule {name} under "
                   f"--root (it scans the real repo)")
             continue
-        lrc = run_legacy(name, args.rest or None)
+        lrc = run_legacy(name)
         if lrc:
             print(f"nomadlint: legacy rule {name} failed (rc={lrc})")
             rc = 1

@@ -314,8 +314,6 @@ def test_solve_barrier_straggler_timeout_dispatches_without_it():
         def fuse_key(self):
             return ("t",)
 
-    import os
-
     dispatched = []
     orig_fuse = batch_mod.fuse_and_solve
     batch_mod.fuse_and_solve = lambda lanes, use_mesh=True, **kw: (
@@ -323,7 +321,8 @@ def test_solve_barrier_straggler_timeout_dispatches_without_it():
         or [("ok", ln.tag) for ln in lanes])
     orig_timeout = batch_mod.BARRIER_TIMEOUT_S
     batch_mod.BARRIER_TIMEOUT_S = 0.3
-    os.environ["NOMAD_TPU_BATCH_FIXPOINT"] = "0"    # fake lanes/results
+    orig_fix = batch_mod._cross_lane_fixpoint    # fake lanes/results
+    batch_mod._cross_lane_fixpoint = lambda lanes, results, ledger: None
     try:
         # 3 participants; only 2 ever arrive -- the third is a straggler
         barrier = SolveBarrier(participants=3)
@@ -346,4 +345,4 @@ def test_solve_barrier_straggler_timeout_dispatches_without_it():
     finally:
         batch_mod.fuse_and_solve = orig_fuse
         batch_mod.BARRIER_TIMEOUT_S = orig_timeout
-        os.environ.pop("NOMAD_TPU_BATCH_FIXPOINT", None)
+        batch_mod._cross_lane_fixpoint = orig_fix

@@ -339,25 +339,25 @@ def test_faults_http_endpoints_and_agent_self():
         server.shutdown()
 
 
-def test_bench_stamp_reports_breaker_degraded(monkeypatch):
-    from nomad_tpu.benchkit import dispatch_health_stamp
-
+def test_guard_state_reports_breaker_degraded(monkeypatch):
+    """What /v1/agent/self serves: ``degraded`` follows the breaker."""
     monkeypatch.setenv("NOMAD_TPU_BREAKER_BACKOFF", "30")
     _recovery_in_process(monkeypatch)
-    stamp = dispatch_health_stamp("cpu")
-    assert stamp["degraded"] == "cpu-fallback"
+    assert guard.state()["degraded"] is False
     for _ in range(guard._breaker_threshold()):
         guard.record_dispatch_failure("timeout")
-    stamp = dispatch_health_stamp("tpu")
-    assert stamp["degraded"] == "breaker-open"
-    assert stamp["dispatch_state"]["breaker_trips"] == 1
+    st = guard.state()
+    assert st["degraded"] is True
+    assert st["breaker"]["state"] == guard.BREAKER_OPEN
+    assert st["breaker"]["trips"] == 1
     guard.reset_breaker()
-    stamp = dispatch_health_stamp("tpu")
-    assert stamp["degraded"] is False
+    st = guard.state()
+    assert st["degraded"] is False
+    assert st["breaker"]["state"] == guard.BREAKER_CLOSED
 
 
 # ----------------------------------------------------------------------
-# Pipelined dispatch (NOMAD_TPU_DISPATCH_DEPTH > 1) under injected
+# Pipelined dispatch under injected
 # faults: every waiter gets exactly one result-or-fallback (no lost
 # evals, no double-wake), and the const cache invalidates cleanly
 # across a breaker trip/recovery cycle.
@@ -375,7 +375,9 @@ def test_pipelined_dispatch_fault_every_waiter_exactly_one_outcome(
     from nomad_tpu.solver.batch import SolveBarrier
 
     monkeypatch.setenv("NOMAD_TPU_BREAKER_THRESHOLD", "100")
-    monkeypatch.setenv("NOMAD_TPU_BATCH_FIXPOINT", "0")
+    # fake lanes/results: nothing for the fixpoint to read
+    monkeypatch.setattr(batch_mod, "_cross_lane_fixpoint",
+                        lambda lanes, results, ledger: None)
 
     class Lane:
         def __init__(self, tag):
@@ -429,13 +431,12 @@ def test_pipelined_dispatch_fault_every_waiter_exactly_one_outcome(
 
 
 def test_pack_cache_never_stale_across_table_write_mid_pipeline():
-    """ISSUE 4 chaos: with the pipelined barrier (depth>1) and warm
-    pack caches, a node-table write + alloc write landing BETWEEN
-    generations must never let an eval solve against a stale usage base
-    or stale fleet tables -- the post-write generation's placements
-    must equal an uncached (NOMAD_TPU_PACK_CACHE=0) control solved from
-    the same snapshot."""
-    import os
+    """ISSUE 4 chaos: with the pipelined barrier and warm pack caches,
+    a node-table write + alloc write landing BETWEEN generations must
+    never let an eval solve against a stale usage base or stale fleet
+    tables -- the post-write generation's placements must equal a
+    control packed from emptied caches and solved from the same
+    snapshot."""
     import threading
 
     import numpy as np
@@ -514,14 +515,11 @@ def test_pack_cache_never_stale_across_table_write_mid_pipeline():
     # generation 2 packs from the NEW snapshot with warm caches
     hot = run_barrier(pack_round("after", all_nodes))
 
-    # control: identical evals, every pack cache disabled
-    os.environ["NOMAD_TPU_PACK_CACHE"] = "0"
-    os.environ["NOMAD_TPU_PACK_ARENA"] = "0"
-    try:
-        cold = run_barrier(pack_round("after", all_nodes))
-    finally:
-        os.environ.pop("NOMAD_TPU_PACK_CACHE", None)
-        os.environ.pop("NOMAD_TPU_PACK_ARENA", None)
+    # control: identical evals, every pack cache and the arena emptied
+    from nomad_tpu.solver import batch as batch_mod
+    tpack._reset_pack_caches_for_tests()
+    batch_mod.arena_clear("cold control")
+    cold = run_barrier(pack_round("after", all_nodes))
     for a_res, b_res in zip(hot, cold):
         assert (np.asarray(a_res[0]) == np.asarray(b_res[0])).all(), \
             "eval solved against a stale pack cache"

@@ -65,7 +65,7 @@ def test_directory_is_set_at_one_place_in_the_tree():
             hits += [os.path.join(dirpath, f) for f in files
                      if f.endswith(".py")]
     hits += [os.path.join(REPO, f) for f in
-             ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+             ("chip_smoke.py", "__graft_entry__.py")]
     setters = [
         os.path.relpath(p, REPO) for p in hits
         if re.search(r"""update\(\s*["']jax_compilation_cache_dir""",

@@ -1,13 +1,13 @@
-"""Delta streaming (ISSUE 20): journal-edge and kill-switch nets for
+"""Delta streaming (ISSUE 20): journal-edge and byte-parity nets for
 the device-resident version chain (solver/constcache.py chain_apply +
 device_put_cached delta_src route).
 
 The correctness contract under test: the scatter path can be SKIPPED
 (wholesale fallback) but never WRONG -- every outcome's device buffer
 must equal the wholesale upload bit for bit; journal overflow, delta-
-less writes and snapshot restores force counted fallbacks; and
-``NOMAD_TPU_DELTA_STREAM=0`` is a bit-for-bit kill switch on the real
-pipelined dispatch path.
+less writes and snapshot restores force counted fallbacks; and a
+promoted buffer, read back from the device, is the host's array, on the
+real pipelined dispatch path too.
 """
 import numpy as np
 import pytest
@@ -257,13 +257,17 @@ def test_snapshot_restore_is_a_gap(monkeypatch):
 
 
 # ----------------------------------------------------------------------
-# kill switch: NOMAD_TPU_DELTA_STREAM=0 is bit-for-bit
+# a promoted buffer equals the host's array, read back from the device
 
 
-def test_kill_switch_disables_chain_bitwise_parity(monkeypatch):
-    """The same generation sequence with NOMAD_TPU_DELTA_STREAM=0 must
-    produce bitwise-identical device buffers through the plain path,
-    and build NO chain state."""
+@pytest.mark.parametrize("chained", [True, False],
+                         ids=["delta_src", "no_delta_src"])
+def test_device_buffers_equal_the_host_arrays(chained):
+    """Every generation's device buffer, read back, is the host's array
+    bit for bit (-0.0 and inf included) whether it was installed,
+    promoted by a scatter, or -- for a caller that names no
+    ``delta_src``, the only way left to ask for no chain -- shipped
+    whole through the content path, which builds NO chain state."""
     gens = [table(seed=8)]
     g = gens[0].copy()
     g[3, 33] = -0.0
@@ -273,50 +277,23 @@ def test_kill_switch_disables_chain_bitwise_parity(monkeypatch):
     gens.append(g2)
 
     store = FakeStore(covered=True)
-    on = []
     for t, a in enumerate(gens):
-        bufs, _ = put_chain([a], store, token=t + 1)
-        on.append(np.asarray(bufs[0]))
-    assert constcache.stats()["delta_promotions"] >= 1
-
-    constcache._reset_for_tests()
-    monkeypatch.setenv("NOMAD_TPU_DELTA_STREAM", "0")
-    assert not constcache.delta_stream_enabled()
-    off = []
-    for t, a in enumerate(gens):
-        bufs, shipped = put_chain([a], store, token=t + 1)
-        assert shipped == a.nbytes        # every generation re-ships
-        off.append(np.asarray(bufs[0]))
+        if chained:
+            bufs, shipped = put_chain([a], store, token=t + 1)
+        else:
+            bufs, shipped = constcache.device_put_cached(
+                [np.array(a)], version=t + 1, cacheable=[False],
+                tags=["compact"])
+            assert shipped == a.nbytes    # every generation re-ships
+        got = np.asarray(jax.device_get(bufs[0]))
+        assert got.dtype == a.dtype and got.shape == a.shape
+        assert (got.view(np.uint8) == a.view(np.uint8)).all()
     st = constcache.stats()
-    assert st["chain_entries"] == 0
-    assert st["delta_promotions"] == 0 and st["delta_reuses"] == 0
-    for x, y in zip(on, off):
-        assert (x.view(np.uint8) == y.view(np.uint8)).all()
-
-
-def test_kill_switch_on_real_pipelined_dispatch(monkeypatch):
-    """NOMAD_TPU_DELTA_STREAM=0 through the REAL pipelined path
-    (benchkit.run_scale_churn: Server + fused dispatch + group commit):
-    placements land, fold parity holds, and the chain never engages --
-    the rollback story the OPERATIONS.md runbook promises."""
-    monkeypatch.setenv("NOMAD_TPU_DELTA_STREAM", "0")
-    monkeypatch.setenv("NOMAD_TPU_FLAP_THRESHOLD", "2")
-    monkeypatch.setenv("NOMAD_TPU_FLAP_BASE_S", "0.3")
-    monkeypatch.setenv("NOMAD_TPU_FLAP_MAX_S", "0.6")
-    from nomad_tpu.benchkit import run_scale_churn
-
-    out = run_scale_churn(240, n_nodes=20, e_evals=2, per_eval=40,
-                          rounds=3, churn_jobs=1, flap_nodes=1,
-                          round_timeout_s=120.0)
-    assert out["truncated"] is False
-    assert out["live_allocs"] == 240
-    assert out["parity_mismatch"] == 0
-    assert out["delta_stream_enabled"] is False
-    assert out["delta_promotions"] == 0
-    assert out["delta_reuses"] == 0
-    assert out["delta_fallbacks"] == 0
-    assert out["xfer_ledger_parity"] == 0
-    assert constcache.stats()["chain_entries"] == 0
+    if chained:
+        assert st["delta_promotions"] == 2 and st["delta_fallbacks"] == 0
+    else:
+        assert st["chain_entries"] == 0 and store.calls == []
+        assert st["delta_promotions"] == 0 and st["delta_reuses"] == 0
 
 
 def test_chain_on_real_pipelined_dispatch_stays_consistent(monkeypatch):
@@ -336,7 +313,6 @@ def test_chain_on_real_pipelined_dispatch_stays_consistent(monkeypatch):
     assert out["truncated"] is False
     assert out["parity_mismatch"] == 0
     assert out["xfer_ledger_parity"] == 0
-    assert out["delta_stream_enabled"] is True
     with constcache._LOCK:
         entries = list(constcache._CHAIN.values())
     assert entries, "the pipelined dispatch must populate the chain"

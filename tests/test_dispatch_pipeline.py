@@ -1,9 +1,9 @@
 """Async dispatch pipeline + eval-axis padding semantics (ISSUE 2).
 
 Tier-1 smoke for the pipelined SolveBarrier: tiny shapes on the CPU
-backend, one pipelined round at depth > 1 asserted bit-identical to the
-synchronous (NOMAD_TPU_DISPATCH_DEPTH=1) path, so the async path is
-gated on every CI run rather than only in bench. Plus the straggler
+backend, one pipelined round at three slots asserted bit-identical to
+the same pipeline with one slot (a serial order) and to each lane's
+solo dispatch, on every CI run. Plus the straggler
 regression (a timeout racing a newer generation must re-check the
 result cell under the condvar, never read it unset) and the
 fuse-and-solve padding contracts: padded eval lanes (replicas of lane 0
@@ -78,10 +78,10 @@ def run_barrier(lanes, depth):
     return out
 
 
-def test_pipelined_round_matches_synchronous_path():
+def test_pipelined_round_matches_serial_order():
     """The tier-1 gate for the async dispatch path: one pipelined round
-    at depth > 1 must produce bit-identical placements to both the
-    synchronous barrier and each lane's solo dispatch."""
+    at three slots must produce bit-identical placements to both the
+    one-slot pipeline (a serial order) and each lane's solo dispatch."""
     h, nodes = build_world()
     lanes = [pack_lane(h, nodes, i) for i in range(3)]
     solo = [dispatch_lane(lane) for lane in lanes]
@@ -93,6 +93,36 @@ def test_pipelined_round_matches_synchronous_path():
         assert np.allclose(np.asarray(piped[i][1], dtype=np.float64),
                            np.asarray(sync[i][1], dtype=np.float64))
         assert (piped[i][2] == sync[i][2]).all()
+
+
+def test_barrier_has_one_dispatch_route():
+    """Production passes no depth and gets the module's constant; a
+    barrier of one slot is the same pipeline (its generation is staged
+    on the intake thread and dispatched off the eval thread), not a
+    second, synchronous route."""
+    assert SolveBarrier(participants=1)._depth \
+        == batch_mod.DISPATCH_DEPTH == 2
+    assert batch_mod.pipeline_state()["depth"] == 2
+    h, nodes = build_world()
+    lanes = [pack_lane(h, nodes, 60 + i, count=2) for i in range(2)]
+    ran_on = []
+    orig = batch_mod.fuse_and_solve
+
+    def spy(lanes, use_mesh=True, **kw):
+        ran_on.append(threading.current_thread().name)
+        return orig(lanes, use_mesh=use_mesh, **kw)
+
+    batch_mod.fuse_and_solve = spy
+    try:
+        pipe = batch_mod._get_pipeline(1)
+        staged0 = pipe.staged()
+        run_barrier(lanes, depth=1)
+        assert batch_mod._get_pipeline(1) is pipe
+        assert pipe.staged() == staged0 + 1
+    finally:
+        batch_mod.fuse_and_solve = orig
+    assert ran_on and not any(n.startswith("Thread-") or n == "MainThread"
+                              for n in ran_on), ran_on
 
 
 def test_pipeline_overlaps_generations():
@@ -144,7 +174,6 @@ def test_straggler_timeout_racing_generation_never_reads_unset_cell():
     generation, a waiter's barrier timeout must re-check its cell under
     the condvar and keep waiting -- the old code broke out of the loop
     and KeyError'd on cell["result"] before the completion landed."""
-    import os
     import time as _time
 
     h, nodes = build_world()
@@ -161,7 +190,6 @@ def test_straggler_timeout_racing_generation_never_reads_unset_cell():
     orig_timeout = batch_mod.BARRIER_TIMEOUT_S
     batch_mod.BARRIER_TIMEOUT_S = 0.2
     batch_mod.fuse_and_solve = slow_fuse
-    os.environ["NOMAD_TPU_BATCH_FIXPOINT"] = "0"
     try:
         # participants=2: A arrives, B never does -> A's timeout fires a
         # partial dispatch (gen 1, async). A's NEXT timeout lands while
@@ -186,7 +214,6 @@ def test_straggler_timeout_racing_generation_never_reads_unset_cell():
     finally:
         batch_mod.fuse_and_solve = orig
         batch_mod.BARRIER_TIMEOUT_S = orig_timeout
-        os.environ.pop("NOMAD_TPU_BATCH_FIXPOINT", None)
 
 
 def test_pad_placement_axis_semantics():
@@ -224,7 +251,7 @@ def _ledger_total_charges(lanes, results):
     return ledger
 
 
-def test_eval_axis_padding_lanes_are_inert():
+def test_eval_axis_padding_lanes_are_inert(monkeypatch):
     """fuse_and_solve pins wave groups to the e_pad_hint bucket by
     replicating lane 0 into padding lanes with active masked False:
     results must stay bit-identical to each lane's solo dispatch (the
@@ -252,20 +279,18 @@ def test_eval_axis_padding_lanes_are_inert():
                   for i, res in enumerate(results)
                   for pos in np.asarray(res[0]) if pos >= 0}
     assert charged_nodes <= real_nodes
-    # and dense grouping takes the same padding contract: disable the
-    # wave path so the vmapped dense kernel sees the inert lanes
-    import os
-    os.environ["NOMAD_TPU_WAVEFRONT"] = "0"
-    try:
-        dense_lanes = [pack_lane(h, nodes, 50 + i, count=3)
-                       for i in range(3)]
-        assert not dense_lanes[0].wavefront_ok()
-        dense_solo = [dispatch_lane(lane) for lane in dense_lanes]
-        dense_res = fuse_and_solve(dense_lanes, e_pad_hint=0)
-        for res, ref in zip(dense_res, dense_solo):
-            assert (res[0] == ref[0]).all()
-    finally:
-        os.environ.pop("NOMAD_TPU_WAVEFRONT", None)
+    # and dense grouping takes the same padding contract: patch the
+    # wave predicate off so the vmapped dense kernel sees the inert lanes
+    from nomad_tpu.solver.service import PackedLane
+    monkeypatch.setattr(PackedLane, "_wavefront_check",
+                        lambda self: False)
+    dense_lanes = [pack_lane(h, nodes, 50 + i, count=3)
+                   for i in range(3)]
+    assert not dense_lanes[0].wavefront_ok()
+    dense_solo = [dispatch_lane(lane) for lane in dense_lanes]
+    dense_res = fuse_and_solve(dense_lanes, e_pad_hint=0)
+    for res, ref in zip(dense_res, dense_solo):
+        assert (res[0] == ref[0]).all()
 
 
 def test_program_factories_single_flight():

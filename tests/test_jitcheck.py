@@ -331,13 +331,12 @@ def test_arena_pool_buffers_freeze_on_release():
     arr = ent.trees["t"][0]
     arr[:] = 1.0                      # checked out: writable
     batch_mod._ARENA.release(ent)
-    if batch_mod._arena_enabled():
-        with pytest.raises(ValueError):
-            arr[:] = 2.0              # pooled: frozen
-        ent2, reused2 = batch_mod._ARENA.acquire(("jck", 4, 8), specs)
-        assert reused2 and ent2 is ent
-        ent2.trees["t"][0][:] = 3.0   # re-acquired: thawed
-        batch_mod._ARENA.release(ent2)
+    with pytest.raises(ValueError):
+        arr[:] = 2.0                  # pooled: frozen
+    ent2, reused2 = batch_mod._ARENA.acquire(("jck", 4, 8), specs)
+    assert reused2 and ent2 is ent
+    ent2.trees["t"][0][:] = 3.0       # re-acquired: thawed
+    batch_mod._ARENA.release(ent2)
 
 
 def test_usage_base_memo_is_frozen():

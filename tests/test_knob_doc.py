@@ -23,12 +23,25 @@ def test_code_knob_scan_finds_known_call_sites():
     # single-line .get, multi-line .get, subscript, pop, and the
     # module-constant indirection are all call-site shapes in-repo
     for k in ("NOMAD_TPU_LPQ", "NOMAD_TPU_LPQ_BATCH",
-              "NOMAD_TPU_DELTA_JOURNAL", "NOMAD_TPU_PACK_CACHE",
+              "NOMAD_TPU_DELTA_JOURNAL", "NOMAD_TPU_PACK_ARENA_MB",
               "NOMAD_TPU_LEAN_ALLOC_METRICS", "NOMAD_TPU_PLUGIN_MAGIC",
               "NOMAD_TPU_PACK_ARENA_ENTRIES"):
         assert k in knobs, f"{k} not detected ({sorted(knobs)[:5]}...)"
     # locations are file:line
     assert all(":" in at for at in knobs.values())
+
+
+def test_knob_census_holds_after_the_switches_went():
+    """PR 32 took the thirteen kill switches no benchmark cell sets,
+    each with its off side; the census may shrink, never grow back."""
+    gone = {"NOMAD_TPU_" + k for k in (
+        "DISPATCH_DEPTH", "BATCH_FIXPOINT", "PACK_ARENA", "PACK_CACHE",
+        "PACK_DELTA", "CONST_CACHE", "DELTA_STREAM", "PLAN_BATCH",
+        "WAVEFRONT", "WAVEFRONT_PREEMPT", "WAVE_BLOCK", "WAVE_UNROLL",
+        "WAVE_GATHER")}
+    knobs = set(ckd.code_knobs())
+    assert not knobs & gone, sorted(knobs & gone)
+    assert len(knobs) <= 87, len(knobs)
 
 
 def test_documented_knobs_parse_tables_only():
@@ -59,7 +72,6 @@ def test_missing_knob_fails(tmp_path, monkeypatch, capsys):
         'A = os.environ.get("NOMAD_TPU_DOCUMENTED", "1")\n'
         'B = os.environ.get(\n'
         '    "NOMAD_TPU_FORGOTTEN", "0")\n')
-    (tmp_path / "bench.py").write_text("")
     docs = tmp_path / "docs"
     docs.mkdir()
     (docs / "OPERATIONS.md").write_text(

@@ -1,8 +1,8 @@
 """Parity: compiled C++ host-baseline oracle (native/pack_kernels.cc
 nt_solve_eval) vs the Python reference oracle (GenericStack.select loop).
 
-The native kernel is the compiled-host baseline bench.py reports
-`vs_native_host` against; these tests gate that it reproduces the Python
+The native kernel is the compiled-host baseline; these tests gate that
+it reproduces the Python
 oracle's placements exactly -- same shuffle, same log2 window, same skip
 and tie-break semantics (reference: scheduler/rank.go:205, stack.go:82-95,
 select.go, util.go:167).
@@ -144,7 +144,7 @@ def test_spread_algorithm():
 
 
 def test_bench_shape_smoke():
-    """The exact shape bench.py times, scaled down."""
+    """The 10,000-node shape, scaled down."""
     h, nodes = build_world(1000)
     job = mock.job(id="bench-job")
     job.task_groups[0].count = 300

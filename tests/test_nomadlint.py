@@ -72,28 +72,6 @@ def test_legacy_rules_run_under_the_driver(capsys):
     capsys.readouterr()
 
 
-def test_legacy_bench_regress_gets_driver_argv(capsys):
-    """bench-regress receives the argv after `--`; an unreadable
-    artifact is a failure the driver surfaces as rc 1."""
-    rc = nl.main(["--rule", "bench-regress", "--",
-                  "/nonexistent/BENCH.json"])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "bench-regress failed" in out
-
-
-def test_legacy_bench_regress_passes_on_identical_pair(tmp_path,
-                                                       capsys):
-    art = {"schema": 1, "placements_per_sec": 100.0}
-    cur = tmp_path / "BENCH_new.json"
-    prev = tmp_path / "BENCH_old.json"
-    cur.write_text(json.dumps(art))
-    prev.write_text(json.dumps(art))
-    rc = nl.main(["--rule", "bench-regress", "--",
-                  str(cur), "--against", str(prev)])
-    assert rc == 0, capsys.readouterr().out
-
-
 def test_legacy_rules_skipped_under_fixture_root(tmp_path, capsys):
     """--root points rules at a synthetic tree; the legacy checkers
     scan the real repo so the driver skips them rather than lint the

@@ -4,11 +4,11 @@ Covers: the node-matrix cache's true-LRU recency (a hit must refresh
 move-to-end order), pack_nodes_cached keying (key_hint vs computed key,
 filtered-subset isolation, table-bump invalidation), the
 feasibility/spread/affinity memos and the incremental usage base (all
-parity-gated against the NOMAD_TPU_PACK_CACHE=0 kill switch, bit for
-bit on the packed trees), and the tier-1 warm-path regression guard:
-two identical fused dispatches where the second must reuse arena
-buffers (zero fresh large host allocations) and place identically with
-the caches on vs off.
+parity-gated, bit for bit on the packed trees, against a pack from a
+fresh NodeMatrix whose memo is empty), and the tier-1 warm-path
+regression guard: two identical fused dispatches where the second must
+reuse arena buffers (zero fresh large host allocations) and place
+identically to a freshly built stack.
 """
 import threading
 
@@ -21,7 +21,7 @@ from nomad_tpu.scheduler.context import EvalContext
 from nomad_tpu.scheduler.reconcile import AllocPlaceResult
 from nomad_tpu.solver import batch as batch_mod
 from nomad_tpu.solver.service import TpuPlacementService, dispatch_lane
-from nomad_tpu.structs import Plan
+from nomad_tpu.structs import Affinity, Plan, Spread
 from nomad_tpu.tensor import pack as tpack
 
 
@@ -40,6 +40,8 @@ def build_world(n_nodes=16, with_allocs=0):
     for i in range(n_nodes):
         n = mock.node()
         n.id = f"pc-node-{i:04d}"
+        n.datacenter = f"dc{i % 2 + 1}"
+        n.meta["rack"] = f"rack-{i % 3}"
         n.compute_class()
         nodes.append(n)
         h.state.upsert_node(n)
@@ -52,10 +54,16 @@ def build_world(n_nodes=16, with_allocs=0):
     return h, nodes
 
 
-def make_service(h, nodes, i, count=4, snap=None):
+def make_service(h, nodes, i, count=4, snap=None, kind="binpack"):
     job = mock.job(id=f"pc-job-{i}")
     job.task_groups[0].count = count
     tg = job.task_groups[0]
+    if kind == "spread":
+        tg.spreads = [Spread(attribute="${meta.rack}", weight=50)]
+    elif kind == "affinity":
+        job.affinities = [Affinity(l_target="${node.datacenter}",
+                                   r_target="dc1", operand="=",
+                                   weight=50)]
     plan = Plan(eval_id=f"pc-eval-{i:029d}", priority=50, job=job)
     ctx = EvalContext(snap or h.state.snapshot(), plan)
     places = [AllocPlaceResult(name=f"{job.id}.{tg.name}[{k}]",
@@ -169,18 +177,31 @@ def test_feasibility_memo_hits_and_freezes(monkeypatch):
     assert not f3[:len(nodes)].any()
 
 
-def test_kill_switch_restores_bitwise_identical_lanes(monkeypatch):
-    """NOMAD_TPU_PACK_CACHE=0 must restore today's repack path
-    bit-for-bit: every packed tree equal, placements identical."""
+@pytest.mark.parametrize("kind", ["binpack", "spread", "affinity"])
+def test_memoised_pack_equals_pack_from_fresh_matrix(kind):
+    """A lane packed from warm memos equals, bit for bit, the lane a
+    fresh NodeMatrix (empty memo) packs: every packed tree equal,
+    placements identical."""
     h, nodes = build_world(12, with_allocs=6)
     snap = h.state.snapshot()
-    svc_on, tg_on, places_on = make_service(h, nodes, 3, snap=snap)
-    lane_on = svc_on.pack(tg_on, places_on, nodes)
-    monkeypatch.setenv("NOMAD_TPU_PACK_CACHE", "0")
-    svc_off, tg_off, places_off = make_service(h, nodes, 3, snap=snap)
-    lane_off = svc_off.pack(tg_off, places_off, nodes)
-    monkeypatch.delenv("NOMAD_TPU_PACK_CACHE")
+
+    def pack_one():
+        svc, tg, places = make_service(h, nodes, 3, snap=snap, kind=kind)
+        return svc.pack(tg, places, nodes)
+
+    lane_off = pack_one()           # fresh matrix: every memo built
+    hits0 = tpack.pack_cache_stats()["hits"]
+    lane_on = pack_one()            # same eval id => same shuffle
+    assert tpack.pack_cache_stats()["hits"] > hits0
+    tpack._reset_pack_caches_for_tests()
+    lane_off2 = pack_one()          # and a second fresh matrix agrees
     assert lane_on is not None and lane_off is not None
+    assert lane_on.const.spread_vidx.shape[0] == (kind == "spread")
+    assert bool(np.asarray(lane_on.const.has_affinity)) \
+        == (kind == "affinity")
+    for tree in ("const", "init", "batch"):
+        for x, y in zip(getattr(lane_off, tree), getattr(lane_off2, tree)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
     for tree in ("const", "init", "batch"):
         a, b = getattr(lane_on, tree), getattr(lane_off, tree)
         for f, (x, y) in zip(a._fields, zip(a, b)):
@@ -290,11 +311,10 @@ def test_incremental_usage_base_memoized_per_snapshot():
 # Tier-1 warm-path regression guard: arena reuse + kill-switch parity
 
 
-def test_warm_fused_dispatch_reuses_arena_and_matches_killswitch(
-        monkeypatch):
+def test_warm_fused_dispatch_reuses_arena_and_matches_fresh_stack():
     """Two identical fused dispatches: the second must be served from
     the arena pool (entry reuse, zero fresh large host allocations) and
-    place identically to a run with BOTH kill switches off."""
+    place identically to a freshly built stack of freshly packed lanes."""
     from nomad_tpu.solver.batch import fuse_and_solve
 
     h, nodes = build_world(16)
@@ -323,12 +343,13 @@ def test_warm_fused_dispatch_reuses_arena_and_matches_killswitch(
         assert (a[0] == b[0]).all()
         assert (a[2] == b[2]).all()
 
-    # kill switches: same lanes, fresh buffers + uncached pack, same
-    # placements
-    monkeypatch.setenv("NOMAD_TPU_PACK_ARENA", "0")
-    monkeypatch.setenv("NOMAD_TPU_PACK_CACHE", "0")
+    # empty pool + empty memos: same lanes, fresh buffers + a pack
+    # from a fresh matrix, same placements
+    batch_mod.arena_clear("fresh reference")
+    tpack._reset_pack_caches_for_tests()
     off_lanes = pack_lanes(10)      # same eval ids => same shuffle
     off = fuse_and_solve(off_lanes)
+    assert batch_mod.arena_state()["allocs"] == s2["allocs"] + 1
     for a, b in zip(first, off):
         assert (a[0] == b[0]).all()
 
@@ -363,7 +384,7 @@ def test_arena_padding_rows_skipped_but_masked_inert():
         assert (res[0] == ref[0]).all()
 
 
-def test_arena_bounds_and_kill_switch(monkeypatch):
+def test_arena_bounds(monkeypatch):
     from nomad_tpu.solver.batch import _ARENA
 
     specs = {"t": [((4, 8), np.dtype(np.float64))]}
@@ -386,20 +407,12 @@ def test_arena_bounds_and_kill_switch(monkeypatch):
     for ent in held:
         _ARENA.release(ent)
     assert batch_mod.arena_state()["entries"] <= 1
-    # kill switch: nothing pooled, fresh buffers each time
-    monkeypatch.setenv("NOMAD_TPU_PACK_ARENA", "0")
-    e4, r4 = _ARENA.acquire(("k1", 4, 8), specs)
-    assert not r4
-    _ARENA.release(e4)
-    e5, r5 = _ARENA.acquire(("k1", 4, 8), specs)
-    assert not r5 and e5 is not e4
-    _ARENA.release(e5)
 
 
 def test_pipeline_staged_prepare_overlaps_and_matches_sync():
-    """Depth>1 barrier rounds route through the prepare stage (arena
-    fill on the intake thread): staged_total climbs and results stay
-    bit-identical to the synchronous path."""
+    """Barrier rounds route through the prepare stage (arena fill on
+    the intake thread): staged_total climbs and results stay
+    bit-identical to each lane's solo dispatch."""
     from nomad_tpu.solver.batch import SolveBarrier, pipeline_state
 
     h, nodes = build_world(16)
@@ -450,7 +463,6 @@ def test_pack_telemetry_emitted():
     assert snap_m["counters"].get("nomad.solver.pack_cache_miss", 0) >= 1
     assert snap_m["counters"].get("nomad.solver.pack_cache_hit", 0) >= 1
     st = guard.state()
-    assert st["pack_cache"]["enabled"] is True
     assert st["pack_cache"]["hits"] + st["pack_cache"]["matrix_hits"] >= 1
     assert "reuses" in st["pack_arena"]
     assert st["pack"]["cache_hit"] >= 1

@@ -1,18 +1,17 @@
 """Incremental memo deltas (ISSUE 6): the alloc table's verify/usage
-folds are maintained in place by every write (NOMAD_TPU_PACK_DELTA)
-instead of refolding per table version, plans carry their delta context
-through StateStore._bump into one shared cache notification, and the
-solver's usage-base memo catches a stale base up by applying journaled
-deltas. Every incremental result is parity-gated against the
-NOMAD_TPU_PACK_DELTA=0 kill switch (the PR-4/5 wholesale path) bit for
-bit, mirroring how the PR 4/5 kill switches are test-gated.
+folds are maintained in place by every write instead of refolding per
+table version, plans carry their delta context through
+StateStore._bump into one shared cache notification, and the solver's
+usage-base memo catches a stale base up by applying journaled deltas.
+Every maintained column is parity-gated, bit for bit, against the
+from-scratch fold it was built from (``AllocTable._fold_inc_build``).
 """
 import numpy as np
 import pytest
 
 from nomad_tpu import mock
 from nomad_tpu.state import StateStore
-from nomad_tpu.state.alloc_table import AllocTable, pack_delta_enabled
+from nomad_tpu.state.alloc_table import AllocTable
 from nomad_tpu.tensor import pack as tpack
 
 
@@ -117,22 +116,46 @@ def test_incremental_fold_parity_with_special_allocs():
     assert store.alloc_table.fold_parity_mismatch() == 0
 
 
-def test_killswitch_restores_wholesale_path_bitwise(monkeypatch):
-    """NOMAD_TPU_PACK_DELTA=0 must reproduce the exact same fold and
-    pack trees via the version-keyed wholesale path."""
-    store_a, nodes_a = build_store()
-    store_a.alloc_table._fold_inc_get()
-    churn_ops(store_a, nodes_a)
-    with_delta = snapshot_folds(store_a, [n.id for n in nodes_a])
-    assert pack_delta_enabled()
+def _upsert_more(store, nodes, allocs):
+    job = mock.job(id="pd-late")
+    store.upsert_job(job)
+    late = [mock.alloc_for(job, nodes[k % len(nodes)]) for k in range(9)]
+    store.upsert_allocs(late)
+    for a in allocs[2::5]:                  # rows overwritten in place
+        upd = a.copy_skip_job()
+        upd.client_status = "failed"
+        store.update_allocs_from_client([upd])
 
-    monkeypatch.setenv("NOMAD_TPU_PACK_DELTA", "0")
-    assert not pack_delta_enabled()
+
+def _remove_more(store, nodes, allocs):
+    t = store.alloc_table
+    store.delete_allocs([a.id for a in allocs[3::4] if a.id in t._row_of])
+
+
+def _compact(store, nodes, allocs):
+    assert store.alloc_table.free_rows > 0
+    store.alloc_table.compact()
+
+
+@pytest.mark.parametrize("write", [_upsert_more, _remove_more, _compact],
+                         ids=["upsert", "remove", "compact"])
+def test_maintained_folds_equal_the_from_scratch_fold(write):
+    """Columns kept alive through a write load equal, bit for bit, the
+    fold a table that never held them builds from scratch at its first
+    read: the same fold and pack trees either way."""
+    store_a, nodes_a = build_store()
+    store_a.alloc_table._fold_inc_get()     # alive before any write
+    write(store_a, nodes_a, churn_ops(store_a, nodes_a))
+    assert store_a.alloc_table._fold_inc is not None or write is _compact
+    maintained = snapshot_folds(store_a, [n.id for n in nodes_a])
+
     store_b, nodes_b = build_store()
-    churn_ops(store_b, nodes_b)
-    without = snapshot_folds(store_b, [n.id for n in nodes_b])
-    for got, want in zip(with_delta, without):
+    write(store_b, nodes_b, churn_ops(store_b, nodes_b))
+    assert store_b.alloc_table._fold_inc is None    # built at the read
+    scratch = snapshot_folds(store_b, [n.id for n in nodes_b])
+    for got, want in zip(maintained, scratch):
         np.testing.assert_array_equal(got, want)
+    assert store_a.alloc_table.fold_parity_mismatch() == 0
 
 
 def test_node_slot_growth_keeps_fold_aligned():

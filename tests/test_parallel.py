@@ -66,8 +66,8 @@ def test_sharded_parity_at_padded_scale():
 def test_batch_worker_mesh_branch_end_to_end(monkeypatch):
     """BatchWorker(use_mesh=True) over the virtual mesh: the fused batch
     must dispatch through solver/batch.py's mesh branch (asserted via the
-    mesh_dispatches counter) and place every alloc correctly. Wavefront
-    routing is pinned off -- eligible lanes would otherwise take the O(B)
+    mesh_dispatches counter) and place every alloc correctly. The wave
+    predicate is patched off -- eligible lanes would otherwise take the O(B)
     kernel, which deliberately skips mesh sharding (nothing N-heavy)."""
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
@@ -78,7 +78,9 @@ def test_batch_worker_mesh_branch_end_to_end(monkeypatch):
     from nomad_tpu.server.telemetry import metrics
     from nomad_tpu.structs import SchedulerConfiguration
 
-    monkeypatch.setenv("NOMAD_TPU_WAVEFRONT", "0")
+    from nomad_tpu.solver.service import PackedLane
+    monkeypatch.setattr(PackedLane, "_wavefront_check",
+                        lambda self: False)
     metrics.reset()
     server = Server(num_workers=4, heartbeat_ttl=30.0, eval_batching=True,
                     batch_width=4)

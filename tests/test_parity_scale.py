@@ -1,7 +1,7 @@
 """BASELINE tier 1-5 parity at scale (VERDICT r1 weak #2: round-1 parity
 was toy-scale only). CI runs the tier shapes at hundreds of nodes on the
-CPU backend; bench.py reuses the same nomad_tpu/benchkit generators at
-full 5K-10K scale on TPU, so what CI gates is what the bench measures."""
+CPU backend; chip_smoke.py runs the same nomad_tpu/benchkit generators
+at 10,000 nodes on the TPU."""
 import os
 
 import pytest

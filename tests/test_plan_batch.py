@@ -1,5 +1,5 @@
 """Group-commit plan applier (ISSUE 5): disjoint-plan batching parity
-vs the NOMAD_TPU_PLAN_BATCH=0 serial kill switch, conflict fallback
+vs the same plans submitted one at a time, conflict fallback
 ordering, and the mid-batch chaos drills (per-plan staging fault +
 whole-transaction split)."""
 import threading
@@ -94,7 +94,9 @@ def world_state(store):
 
 
 def run_world(batch, monkeypatch, n_plans=6, window_ms="500"):
-    monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH", "1" if batch else "0")
+    """``batch``: the plans arrive together behind an expect_plans
+    hint; otherwise each is submitted and waited for before the next,
+    a queue of one: the group of one through ``_commit_one``."""
     monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH_WINDOW_MS", window_ms)
     store, nodes = make_world(n_nodes=2 * n_plans)
     planner = Planner(store)
@@ -104,16 +106,20 @@ def run_world(batch, monkeypatch, n_plans=6, window_ms="500"):
                  for k in range(n_plans)]
         evals = [Evaluation(id=p.eval_id, status=EVAL_STATUS_COMPLETE,
                             job_id=p.job.id) for p in plans]
-        results, errors = submit_group(planner, plans, evals)
-        assert not any(errors), errors
+        if batch:
+            results, errors = submit_group(planner, plans, evals)
+            assert not any(errors), errors
+        else:
+            results = [planner.apply(p, [e])
+                       for p, e in zip(plans, evals)]
         return store, planner, plans, results
     finally:
         planner.shutdown()
 
 
 def test_disjoint_batch_parity(monkeypatch):
-    """The same disjoint-plan workload through the batched applier and
-    the serial kill switch must land identical allocs, eval updates and
+    """The same disjoint-plan workload arriving as one group and one
+    plan at a time must land identical allocs, eval updates and
     per-result index invariants."""
     store_b, planner_b, plans_b, res_b = run_world(True, monkeypatch)
     store_s, planner_s, plans_s, res_s = run_world(False, monkeypatch)
@@ -121,8 +127,8 @@ def test_disjoint_batch_parity(monkeypatch):
     assert world_state(store_b) == world_state(store_s)
     assert planner_b.plans_applied == planner_s.plans_applied == 6
     assert planner_b.plans_rejected == planner_s.plans_rejected == 0
-    # batch mode really grouped (>= one multi-plan transaction);
-    # serial mode must never touch the batch path
+    # the group really grouped (>= one multi-plan transaction); plans
+    # arriving alone never touch the group path
     assert planner_b.batches_committed >= 1
     assert planner_s.batches_committed == 0
     # every commit stamped its result with the index the store landed
@@ -149,7 +155,6 @@ def test_disjoint_batch_parity(monkeypatch):
 def test_batch_of_one_is_serial(monkeypatch):
     """With no concurrent arrivals the batch path degrades to exactly
     the serial applier: one plan, one commit, one index."""
-    monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH", "1")
     store, nodes = make_world(n_nodes=2)
     planner = Planner(store)
     try:
@@ -165,7 +170,6 @@ def test_conflict_falls_back_to_serial_order(monkeypatch):
     """A plan whose node set overlaps the group must not join it: it
     (and everything queued behind it) commits in a LATER transaction,
     after the group -- today's serial order."""
-    monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH", "1")
     monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH_WINDOW_MS", "500")
     store, nodes = make_world(n_nodes=6)
     planner = Planner(store)
@@ -202,7 +206,6 @@ def test_chaos_mid_batch_staging_fault(monkeypatch):
     """faultinject plan.commit mid-batch: the injected plan's waiter
     gets the fault, the batch splits around it, and every surviving
     plan commits exactly once."""
-    monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH", "1")
     monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH_WINDOW_MS", "500")
     store, nodes = make_world(n_nodes=6)
     planner = Planner(store)
@@ -261,7 +264,6 @@ class ExplodingBatchStore(StateStore):
 def test_chaos_batch_transaction_split(monkeypatch):
     """A whole-batch transaction failure splits to serial: every plan
     still commits exactly once through the single-plan path."""
-    monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH", "1")
     monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH_WINDOW_MS", "500")
     store = ExplodingBatchStore()
     nodes = []
@@ -294,7 +296,6 @@ def test_chaos_batch_transaction_split(monkeypatch):
 def test_group_window_releases_without_arrivals(monkeypatch):
     """An over-counted expect_plans hint (evals that never submit) must
     only delay the drain by the bounded window, never wedge it."""
-    monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH", "1")
     monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH_WINDOW_MS", "50")
     store, nodes = make_world(n_nodes=2)
     planner = Planner(store)

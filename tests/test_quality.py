@@ -9,8 +9,8 @@ healthy solver; (3) an injected solver fault (``quality.skew``) makes
 the drift gauge fire and the breaker-style alert latch (chaos drill);
 (4) ``NOMAD_TPU_QUALITY=0`` restores the prior path bit-for-bit;
 (5) the span-stream saturation attribution sees every pipeline stage;
-(6) all four surfaces serve the data (HTTP operator endpoint,
-/v1/metrics block + prometheus p99, bench artifact fields).
+(6) all surfaces serve the data (HTTP operator endpoint,
+/v1/metrics block + prometheus p99, the observatory's report).
 """
 import json
 import time
@@ -316,7 +316,6 @@ def test_killswitch_restores_prior_path(monkeypatch):
     # disabled -- and placements are bit-for-bit identical
     assert hook_off is None
     assert observatory.report() == {"enabled": False}
-    assert observatory.bench_fields() == {"quality_enabled": False}
     assert placed_off == placed_on
 
     monkeypatch.delenv("NOMAD_TPU_QUALITY")
@@ -348,11 +347,12 @@ def test_saturation_sees_pipeline_stages():
                    for d in stages.values()) == pytest.approx(100.0,
                                                               abs=1.0)
 
-        fields = observatory.bench_fields()
-        assert fields["quality_enabled"]
-        assert "quality_fragmentation" in fields
-        assert "quality_drift" in fields
-        assert any(k.startswith("stage_busy_pct_") for k in fields)
+        full = observatory.report()
+        assert full["enabled"]
+        assert "fragmentation_index" in full["placement"]
+        assert "score_drift_max" in full["audit"]
+        assert all("busy_pct" in d
+                   for d in full["saturation"]["stages"].values())
     finally:
         server.shutdown()
 

@@ -1,5 +1,5 @@
 """Sustained-churn pipeline (ISSUE 6): the reduced-shape tier-1 smoke
-runs the EXACT code path bench.py's time_scale_churn drives
+runs the served pipeline's churn drive
 (benchkit.run_scale_churn: Server + BatchWorker coalescing + group
 commit + flap damper + watermark GC + table compaction + incremental
 fold parity, allocations HELD live while arrivals/completions/flaps

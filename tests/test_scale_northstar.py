@@ -1,5 +1,5 @@
 """North-star-scale pipeline (ISSUE 5): the reduced-shape tier-1 smoke
-runs the EXACT code path bench.py's time_scale_northstar drives
+runs the served pipeline's north-star drive
 (benchkit.run_scale_northstar: Server + BatchWorker coalescing +
 SolveBarrier fused dispatch + group-commit applier, allocations
 accumulating LIVE across rounds with no drain); the full ~2M-alloc run

@@ -185,7 +185,9 @@ def test_stage_timers_add_up_to_the_dispatch_timer(transport, monkeypatch):
     timer's total (the stages are contiguous on one clock)."""
     from nomad_tpu.solver.batch import fuse_and_solve
     if transport != "wave":
-        monkeypatch.setenv("NOMAD_TPU_WAVEFRONT", "0")
+        from nomad_tpu.solver.service import PackedLane
+        monkeypatch.setattr(PackedLane, "_wavefront_check",
+                            lambda self: False)
     if transport == "dense":
         monkeypatch.setenv("NOMAD_TPU_MESH", "0")
     h, nodes = build_world()

@@ -5,7 +5,7 @@ TRUE no-op (module attrs raw, bitwise dispatch parity), every detector
 is proven by a seeded violation producing a witness (forced
 replication -> spec drift + per-shard byte parity, raw/host puts ->
 implicit transfer, planted extra all-gather -> collective excess), and
-the HTTP/CLI/bench surfaces mirror the siblings exactly."""
+the HTTP/CLI surfaces mirror the siblings exactly."""
 import os
 import sys
 
@@ -456,39 +456,18 @@ def test_cli_compile_audit_local(capsys):
     assert "all-" in out      # some collective inventoried
 
 
-def test_benchkit_stamp_fields():
-    """shardcheck_stamp feeds the bench artifacts the zero-tolerance
-    fields scripts/check_bench_regress.py gates."""
-    from nomad_tpu.benchkit import shardcheck_stamp
-
-    stamp = shardcheck_stamp()
-    assert stamp == {
-        "shardcheck_enabled": False, "shard_spec_drift": 0,
-        "shard_implicit_xfer": 0, "shard_collective_excess": 0}
+def test_state_count_fields():
+    """shardcheck.state() carries the zero-tolerance counts that
+    /v1/agent/self and ``operator shardcheck`` serve."""
+    counts = ("spec_drift_count", "implicit_xfer_count",
+              "collective_excess_count")
+    st = shardcheck.state()
+    assert st["enabled"] is False
+    assert [st[k] for k in counts] == [0] * 3
     shardcheck.enable()
     shardcheck.audit_hlo(("f",), "a = all-reduce(b)\n")
     shardcheck.audit_hlo(("f",), "a = all-reduce(b)\n"
                                  "c = all-reduce(d)\n")
-    stamp = shardcheck_stamp()
-    assert stamp["shardcheck_enabled"] is True
-    assert stamp["shard_collective_excess"] == 1
-
-
-def test_bench_regress_gates_shard_fields(tmp_path):
-    """A positive shard_* count against a zero previous round fails
-    the trend gate (zero-tolerance direction rows)."""
-    import importlib.util
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "cbr", os.path.join(root, "scripts", "check_bench_regress.py"))
-    cbr = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(cbr)
-    prev = {"schema": 1, "shard_spec_drift": 0,
-            "shard_implicit_xfer": 0, "shard_collective_excess": 0}
-    cur = dict(prev, shard_spec_drift=2)
-    regressions, _ = cbr.compare_artifacts(prev, cur)
-    assert any("shard_spec_drift" in r for r in regressions)
-    regressions, _ = cbr.compare_artifacts(prev, dict(prev))
-    assert not any("shard" in r for r in regressions)
+    st = shardcheck.state()
+    assert st["enabled"] is True
+    assert st["collective_excess_count"] == 1

@@ -510,18 +510,16 @@ def test_agent_self_and_operator_cli_surface(capsys):
         server.shutdown()
 
 
-def test_benchkit_stamp_fields():
-    """statecheck_stamp feeds the bench artifacts the zero-tolerance
-    fields scripts/check_bench_regress.py gates."""
-    from nomad_tpu.benchkit import statecheck_stamp
-
-    stamp = statecheck_stamp()
-    assert stamp == {
-        "statecheck_enabled": False, "state_torn_reads": 0,
-        "state_aliasing_writes": 0, "state_journal_gaps": 0,
-        "state_write_skews": 0, "state_stale_memos": 0}
+def test_state_count_fields():
+    """statecheck.state() carries the zero-tolerance counts that
+    /v1/agent/self and ``operator statecheck`` serve."""
+    counts = ("torn_read_count", "aliasing_write_count",
+              "journal_gap_count", "write_skew_count", "stale_memo_count")
+    st = statecheck.state()
+    assert st["enabled"] is False
+    assert [st[k] for k in counts] == [0] * 5
     statecheck.enable()
     statecheck.note_memo_served("usage_base", 1, 2)
-    stamp = statecheck_stamp()
-    assert stamp["statecheck_enabled"] is True
-    assert stamp["state_stale_memos"] == 1
+    st = statecheck.state()
+    assert st["enabled"] is True
+    assert st["stale_memo_count"] == 1

@@ -379,7 +379,9 @@ def test_pipelined_barrier_spans_reach_every_eval_trace(monkeypatch):
     from nomad_tpu.solver import batch as batch_mod
     from nomad_tpu.solver.batch import SolveBarrier
 
-    monkeypatch.setenv("NOMAD_TPU_BATCH_FIXPOINT", "0")
+    # fake lanes/results: nothing for the fixpoint to read
+    monkeypatch.setattr(batch_mod, "_cross_lane_fixpoint",
+                        lambda lanes, results, ledger: None)
 
     class Lane:
         def fuse_key(self):

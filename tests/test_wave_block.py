@@ -7,8 +7,7 @@ The fuzz constructs synthetic compact tables directly (capacities down
 to 1 force dense saturation/refill chains; huge prior collision counts
 with tiny job counts drive scores negative to engage the skip/fallback
 machinery and both threshold-crossing directions), then asserts the two
-kernels' (chosen, scores, n_yielded) are identical elementwise.
-scripts/wave_block_fuzz.py is the wider standalone version."""
+kernels' (chosen, scores, n_yielded) are identical elementwise."""
 from functools import partial
 
 import numpy as np
@@ -80,27 +79,88 @@ def test_block_matches_classic_fuzz(C, B, K, L, INNER, spread_alg):
                 f"{bad[:5]}: classic {a[bad[:5]]} block {b[bad[:5]]}")
 
 
-def test_dispatch_gate_routes_penalty_lanes_to_classic(monkeypatch):
+def _pack_real_lane(kind, count, penalties=None, n_nodes=12):
+    from nomad_tpu import mock
+    from nomad_tpu.scheduler import Harness
+    from nomad_tpu.scheduler.context import EvalContext
+    from nomad_tpu.scheduler.reconcile import AllocPlaceResult
+    from nomad_tpu.solver.service import TpuPlacementService
+    from nomad_tpu.structs import Plan, Spread
+
+    h = Harness()
+    nodes = []
+    for i in range(n_nodes):
+        n = mock.node()
+        n.id = f"wb-node-{i:04d}"
+        n.meta["rack"] = f"rack-{i % 3}"
+        n.compute_class()
+        nodes.append(n)
+        h.state.upsert_node(n)
+    job = mock.job(id=f"wb-job-{kind}")
+    tg = job.task_groups[0]
+    tg.count = count
+    if kind == "spread":
+        tg.spreads = [Spread(attribute="${meta.rack}", weight=50)]
+    plan = Plan(eval_id=f"wb-eval-{kind:>28}".replace(" ", "0"),
+                priority=50, job=job)
+    ctx = EvalContext(h.state.snapshot(), plan)
+    places = [AllocPlaceResult(name=f"{job.id}.{tg.name}[{k}]",
+                               task_group=tg) for k in range(count)]
+    svc = TpuPlacementService(ctx, job, batch_mode=False,
+                              spread_alg=False)
+    pens = None
+    if penalties:
+        pens = [{nodes[penalties[k]].id} if k in penalties else set()
+                for k in range(count)]
+    lane = svc.pack(tg, places, nodes, pens)
+    assert lane is not None
+    return lane
+
+
+@pytest.mark.parametrize("kind,count,want_wave", [
+    ("plain", 126, True),       # limit ceil(log2 N): fits any buffer
+    ("spread", 4, True),        # limit max(count, 100) + skips <= 128
+    ("spread", 126, False)])    # 126 + 3 skips outgrow the widest
+def test_wave_routing_is_decided_by_the_lane_window(kind, count,
+                                                    want_wave):
+    """Which kernel family a lane takes is read off the lane: a scan
+    window wider than the widest wave buffer (a spread job of 126 or
+    more placements, as `spread-drain`'s 1,200) takes the whole-axis
+    scan; no user-set switch has a say."""
+    lane = _pack_real_lane(kind, count, n_nodes=20)
+    lim = int(np.asarray(lane.batch.limit)[0])
+    assert (binpack.wavefront_buffer_size(lim) is not None) == want_wave
+    assert lane.wavefront_ok() == want_wave
+
+
+@pytest.mark.parametrize("kind,want_block", [
+    ("plain", True), ("penalty", False), ("spread", False)])
+def test_dispatch_gate_is_decided_by_the_lane(kind, want_block,
+                                              monkeypatch):
     """A lane with an active reschedule penalty must take the compact
     scan (penalties couple score to the absolute placement index, which
-    the run-block shortcut cannot model); penalty-free lanes take the
-    run-block kernel. Pinned via the compiled-fn cache key's use_block
-    flag."""
-    rng = np.random.default_rng(7)
-    C, B = 40, 8
-    P = C - B
-    compact, scal_f = _make_case(rng, C, B)
-    # solve_lane_wave needs struct inputs; drive the gate logic directly
-    pen_free = np.full(P, -1, dtype=np.int32)
-    pen_hot = pen_free.copy()
-    pen_hot[3] = 5
-    assert binpack._wave_block_enabled()
-    assert bool((pen_free < 0).all())
-    assert not bool((pen_hot < 0).all())
+    the run-block shortcut cannot model), and so must a lane with a
+    spread (its counts ride the compact scan's carry); penalty-free
+    lanes without one take the run-block kernel. Read off the
+    compiled-fn cache key's use_block flag on a real lane's dispatch."""
+    from nomad_tpu.solver.service import dispatch_lane
 
+    lane = _pack_real_lane(kind, 4,
+                           penalties={1: 5} if kind == "penalty" else None)
+    assert lane.wavefront_ok()
+    assert bool((np.asarray(lane.batch.penalty_idx) >= 0).any()) \
+        == (kind == "penalty")
 
-def test_block_kernel_env_kill_switch(monkeypatch):
-    monkeypatch.setenv("NOMAD_TPU_WAVE_BLOCK", "0")
-    assert not binpack._wave_block_enabled()
-    monkeypatch.delenv("NOMAD_TPU_WAVE_BLOCK")
-    assert binpack._wave_block_enabled()
+    seen = []
+    real = binpack._wave_compact_program
+
+    def spy(cm_shape, sp_shape, spread_alg, dtype_name, batched, B,
+            use_block):
+        seen.append(use_block)
+        return real(cm_shape, sp_shape, spread_alg, dtype_name, batched,
+                    B, use_block)
+
+    monkeypatch.setattr(binpack, "_wave_compact_program", spy)
+    chosen = dispatch_lane(lane)[0]
+    assert seen == [want_block]
+    assert (np.asarray(chosen) >= 0).all()

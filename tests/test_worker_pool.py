@@ -401,7 +401,6 @@ def test_cross_worker_conflict_serialized(monkeypatch):
         AllocatedResources, AllocatedSharedResources,
         AllocatedTaskResources, Allocation,
     )
-    monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH", "1")
     monkeypatch.setenv("NOMAD_TPU_PLAN_BATCH_WINDOW_MS", "500")
 
     store = StateStore()
@@ -472,7 +471,7 @@ def test_cross_worker_conflict_serialized(monkeypatch):
 
 
 # ----------------------------------------------------------------------
-# Bench path smoke (the full-scale run is bench.py time_worker_scaling)
+# benchkit.run_worker_scaling, shrunk
 
 
 def test_run_worker_scaling_smoke():
