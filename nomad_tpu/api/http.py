@@ -962,6 +962,13 @@ class ApiHandler(BaseHTTPRequestHandler):
                             self.nomad.supervisor.state()
                             if hasattr(self.nomad, "supervisor")
                             else {},
+                        # placements a batch worker's barrier has
+                        # handed out and no commit has settled
+                        # (server/inflight.py): unsettled_evals reads
+                        # 0 on an idle server
+                        "inflight_bookings":
+                            self.nomad.inflight.state()
+                            if hasattr(self.nomad, "inflight") else {},
                         # poison-eval dead letters (ISSUE 16): evals
                         # that exhausted their delivery limit
                         # NOMAD_TPU_POISON_AFTER times; released via

@@ -31,6 +31,7 @@ from ..structs import (
     TRIGGER_NODE_UPDATE, TRIGGER_PERIODIC_JOB,
 )
 from .broker import BlockedEvals, EvalBroker
+from .inflight import InflightBookings
 from .plan_apply import BadNodeTracker, Planner
 from .worker import BatchWorker, Worker
 
@@ -244,6 +245,7 @@ class WorkerSupervisor:
                     _log("error", "server",
                          f"worker {w.name} DIED (thread exit); "
                          f"restarting slot {i} with backoff")
+                    self._retire_bookings(w)
                     self._schedule_restart_locked(i, now)
                     continue
                 age = now - max(getattr(w, "last_progress", now),
@@ -261,6 +263,7 @@ class WorkerSupervisor:
                     # redeliver via nack-timeout, and any plan it wakes
                     # to submit dies at the stale-lease fence
                     w.stop()
+                    self._retire_bookings(w)
                     self._schedule_restart_locked(i, now)
                     continue
                 # healthy: once a replacement outlives the stall
@@ -269,6 +272,15 @@ class WorkerSupervisor:
                         and now - self._spawned_at.get(i, now)
                         > max(self.stall_s, 2 * self.base_s)):
                     self._consecutive.pop(i, None)
+
+    @staticmethod
+    def _retire_bookings(w) -> None:
+        """A batch worker that is gone leaves its batch's bookings
+        (inflight.py) behind: its evals redeliver, and nothing it booked
+        commits."""
+        barrier = getattr(w, "barrier", None)
+        if barrier is not None:
+            barrier.retire()
 
     def _schedule_restart_locked(self, slot: int, now: float) -> None:
         n = self._consecutive.get(slot, 0) + 1
@@ -380,6 +392,12 @@ class Server:
         self.broker = EvalBroker()
         self.blocked_evals = BlockedEvals(self.broker)
         self.planner = Planner(self.state)
+        # what the batch workers' barriers have handed to their evals
+        # and the alloc table does not hold yet (inflight.py): booked by
+        # one barrier's fixpoint, charged by the other's, settled by the
+        # commit under the store's lock
+        self.inflight = InflightBookings()
+        self.state.plan_commit_hook = self.inflight.settle
         # group commit: one blocked-evals unblock sweep per committed
         # plan BATCH (the per-plan sweep in on_plan_result is skipped
         # for batch-committed results)
@@ -555,6 +573,7 @@ class Server:
             for w in self.workers:
                 w.stop()
             self.workers = []
+            self.inflight.clear()
             self.broker.set_enabled(False)
             self.blocked_evals.set_enabled(False)
             with self._hb_lock:

@@ -41,6 +41,15 @@ class StaleEvalToken(Exception):
     its stale plan dies here instead of double-placing."""
 
 
+def _release_bookings(server, eval_id: str) -> None:
+    """Drop what ``eval_id``'s barrier booked for it and no commit has
+    settled (server/inflight.py); a server without bookings (the test
+    doubles) has none."""
+    bookings = getattr(server, "inflight", None)
+    if bookings is not None and eval_id:
+        bookings.release(eval_id)
+
+
 def _fire_crash_point() -> None:
     """``worker.crash`` chaos point: an armed error kills the worker
     thread mid-eval (contrast ``worker.invoke``, whose error takes the
@@ -81,13 +90,21 @@ class WorkerPlanner:
                 f"longer outstanding; plan rejected")
         # (reference: worker.go:656 `nomad.plan.submit` -- wall time of the
         # whole submission incl. queue wait at the serialized applier)
-        with metrics.measure("nomad.plan.submit"), \
-                tracer.span("plan.submit") as sp:
-            result = self.server.planner.apply(
-                plan, worker=self.worker_name)
-            sp.tag(allocs=sum(len(v)
-                              for v in result.node_allocation.values()),
-                   rejected=len(result.rejected_nodes))
+        try:
+            with metrics.measure("nomad.plan.submit"), \
+                    tracer.span("plan.submit") as sp:
+                result = self.server.planner.apply(
+                    plan, worker=self.worker_name)
+                sp.tag(allocs=sum(len(v)
+                                  for v in result.node_allocation.values()),
+                       rejected=len(result.rejected_nodes))
+        finally:
+            # the plan is back: what it committed took the commit's
+            # index inside the store's lock (server/inflight.py), and
+            # what is still booked under this eval -- a plan refused
+            # whole never reaches the store, nor does one whose
+            # submission raised -- will not commit
+            _release_bookings(self.server, self.eval_id)
         new_state = None
         if result.rejected_nodes or (result.is_no_op() and not plan.is_no_op()):
             # partial/failed commit: scheduler refreshes its snapshot
@@ -239,10 +256,19 @@ class BatchWorker(threading.Thread):
     This replaces the reference's one-eval-per-worker contract
     (nomad/worker.go:397 + scheduler/scheduler.go:59-68) with the
     TPU-native amortized form: per-eval semantics are unchanged (each eval
-    runs the stock GenericScheduler against its own snapshot; the
-    serialized plan applier resolves cross-eval conflicts), only the device
-    dispatch is shared. With zero or one dense-eligible eval per batch it
-    degrades to exactly the old behavior."""
+    runs the stock GenericScheduler against its own snapshot), only the
+    device dispatch is shared. Cross-eval conflicts the barrier settles
+    itself, before any plan is submitted, where it can see them: among
+    the evals of its batch, and against what the server's other batch
+    worker has delivered to its evals and the applier has not committed
+    yet (``server.inflight``, server/inflight.py; every way out of an
+    eval or a batch below releases what was booked for it). The
+    serialized plan applier still re-checks every plan against live
+    state and refuses what does not fit: conflicts with writers the
+    barrier cannot see, and those of lanes the fixpoint cannot re-solve
+    (solver/batch.py _cross_lane_fixpoint). With zero or one
+    dense-eligible eval per batch and nothing in flight it degrades to
+    exactly the old behavior."""
 
     def __init__(self, server, worker_id: int, width: int = 8,
                  schedulers: Optional[List[str]] = None,
@@ -257,6 +283,7 @@ class BatchWorker(threading.Thread):
         self._stop_ev = threading.Event()
         self.evals_processed = 0
         self.batches_processed = 0
+        self.barrier = None     # the batch in progress
         # supervisor progress heartbeat (see Worker.last_progress);
         # additionally touched per completed eval thread (_run_one), so
         # a long legitimate batch still shows progress
@@ -304,21 +331,29 @@ class BatchWorker(threading.Thread):
                                e_pad_hint=self.width,
                                plan_group_hint=getattr(
                                    self.server.planner, "expect_plans",
-                                   None))
-        hook = make_solve_hook(barrier)
-        threads = [
-            threading.Thread(
-                target=self._run_one, args=(ev, token, barrier, hook),
-                daemon=True, name=f"batch-eval-{ev.id[:8]}")
-            for ev, token in batch]
-        for t in threads:
-            t.start()
-        for t in threads:
-            # bounded join (nomadlint join-with-timeout): an eval
-            # thread wedged past the dispatch watchdog must surface as
-            # a live diagnosable thread, not an invisible infinite join
-            while t.is_alive():
-                t.join(timeout=5.0)
+                                   None),
+                               bookings=getattr(self.server, "inflight",
+                                                None))
+        # the supervisor retires it if this thread dies or is abandoned
+        self.barrier = barrier
+        try:
+            hook = make_solve_hook(barrier)
+            threads = [
+                threading.Thread(
+                    target=self._run_one, args=(ev, token, barrier, hook),
+                    daemon=True, name=f"batch-eval-{ev.id[:8]}")
+                for ev, token in batch]
+            for t in threads:
+                t.start()
+            for t in threads:
+                # bounded join (nomadlint join-with-timeout): an eval
+                # thread wedged past the dispatch watchdog must surface
+                # as a live diagnosable thread, not an invisible
+                # infinite join
+                while t.is_alive():
+                    t.join(timeout=5.0)
+        finally:
+            barrier.retire()
         self.evals_processed += len(batch)
         self.batches_processed += 1
 
@@ -382,5 +417,8 @@ class BatchWorker(threading.Thread):
                 import traceback
                 traceback.print_exc()
         finally:
+            # whichever way the eval ended (acked with no plan, stale
+            # lease, nack): nothing more of it will commit
+            _release_bookings(self.server, ev.id)
             self.last_progress = time.monotonic()
             barrier.done()

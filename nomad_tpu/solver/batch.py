@@ -638,8 +638,10 @@ def _dispatch(const, init, batch, spread_alg: bool, dtype_name: str,
 
 
 def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
-                         ledger: Dict[str, list]) -> None:
-    """Resolve intra-batch placement conflicts BEFORE plans are submitted.
+                         ledger: Dict[str, list], foreign=None):
+    """Resolve placement conflicts BEFORE plans are submitted: among the
+    lanes this barrier holds, and against what the server's other
+    barrier has handed to its evals and no lane can have packed yet.
 
     Every lane solved from the same snapshot, so concurrent evals pile
     onto the same best-scoring nodes; the serialized applier then
@@ -654,46 +656,89 @@ def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
     against the accumulated usage (one extra small cached-program
     dispatch per conflicted lane). The outcome matches what the
     applier+retry loop would have produced from this snapshot -- minus
-    the control-plane round trips. The applier's authoritative re-check
-    (plan_apply.py _evaluate_plan) still runs unchanged on every plan.
+    the control-plane round trips.
 
-    Lanes that the wave kernel can't re-solve (preemption tables, static
-    ports, devices/cores/distinct_property) only consume ledger capacity;
-    their conflicts keep the applier/retry path. The ledger is keyed by
-    node id and persists across a batch's barrier generations (multi-TG
-    evals rendezvous once per TG) so later generations see earlier ones'
-    usage. Results are edited in place.
+    What the barrier settles itself: conflicts among its own lanes (the
+    ledger), and, with ``foreign`` (the server's in-flight bookings as
+    this barrier reads them, server/inflight.py ``ForeignView``),
+    conflicts with the
+    placements another batch worker's barrier has delivered and the
+    applier has not committed, or committed after the usage was folded:
+    a node's free capacity is its ledger entry less every such booking
+    that the usage the entry was made from cannot contain -- none that
+    it does contain, and none of this barrier's own.
+
+    What stays the applier's: its authoritative re-check
+    (plan_apply.py _evaluate_plan) runs unchanged on every plan, and it
+    alone adjudicates the lanes that the wave kernel can't re-solve
+    (whole-axis scans, preemption tables, static ports,
+    devices/cores/distinct_property): those only consume ledger
+    capacity, and a placement of theirs over a node's capacity is left
+    in the plan, uncharged, for the applier to refuse. The ledger is
+    keyed by node id and persists across a batch's barrier generations
+    (multi-TG evals rendezvous once per TG) so later generations see
+    earlier ones' usage. Results are edited in place.
+
+    Returns, a lane, {node id: [cpu, mem, disk, dynamic ports]} of the
+    placements charged (what the barrier books for the lane's eval), or
+    None when there was nothing to walk.
     """
-    if len(lanes) < 2 and not ledger:
-        return
+    if len(lanes) < 2 and not ledger and foreign is None:
+        return None
 
     order_idx = sorted(
         range(len(lanes)),
         key=lambda i: (-lanes[i].service.ctx.plan.priority, i))
+    charged: List[Dict[str, list]] = [{} for _ in lanes]
+    # placements that fit the ledger and not the ledger less the other
+    # barrier's bookings
+    cross = 0
 
-    def charge(lane, free, pi):
-        """Try to charge placement pi to the ledger entry ``free``;
-        returns True and subtracts when it fits."""
-        b = lane.batch
-        need = (float(b.ask_cpu[pi]), float(b.ask_mem[pi]),
-                float(b.ask_disk[pi]), int(b.n_dyn_ports[pi]))
-        if (free[0] >= need[0] and free[1] >= need[1]
+    def free_of(nid, f):
+        """The ledger entry ``f`` of ``nid`` less the other barrier's
+        bookings that the usage it was made from cannot contain."""
+        if foreign is None:
+            return f
+        d = foreign.deduction(nid, f[4])
+        if d is None:
+            return f
+        return [f[0] - d[0], f[1] - d[1], f[2] - d[2], f[3] - d[3]]
+
+    def charge(into, lane, nid, f, pi):
+        """Try to charge placement pi to ``nid``'s ledger entry ``f``;
+        returns True and subtracts (and adds to the lane's charges,
+        ``into``) when it fits."""
+        nonlocal cross
+        need = _placement_need(lane.batch, pi)
+        if not (f[0] >= need[0] and f[1] >= need[1]
+                and f[2] >= need[2] and f[3] >= need[3]):
+            return False
+        free = free_of(nid, f)
+        if free is not f and not (
+                free[0] >= need[0] and free[1] >= need[1]
                 and free[2] >= need[2] and free[3] >= need[3]):
-            free[0] -= need[0]
-            free[1] -= need[1]
-            free[2] -= need[2]
-            free[3] -= need[3]
-            return True
-        return False
+            cross += 1
+            return False
+        got = into.get(nid)
+        if got is None:
+            got = into[nid] = [0.0, 0.0, 0.0, 0]
+        for k in range(4):
+            f[k] -= need[k]
+            got[k] += need[k]
+        return True
 
     def entry(lane, pos, nid):
         f = ledger.get(nid)
         if f is None:
             c, s = lane.const, lane.init
+            u = lane.usage_index
             f = [float(c.cpu_cap[pos]) - float(s.used_cpu[pos]),
                  float(c.mem_cap[pos]) - float(s.used_mem[pos]),
                  float(c.disk_cap[pos]) - float(s.used_disk[pos]),
-                 int(s.dyn_avail[pos])]
+                 int(s.dyn_avail[pos]),
+                 # what this entry's usage holds: the index the lane
+                 # folded it at
+                 float("inf") if u is None else u]
             ledger[nid] = f
         return f
 
@@ -717,6 +762,7 @@ def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
                       and not plan.node_update
                       and not plan.node_preemptions)
         order = np.asarray(lane.order)
+        charge_lane = functools.partial(charge, charged[i])
         conflicted: List[int] = []
         accepted_own: List[int] = []
         unresolvable = 0
@@ -725,7 +771,7 @@ def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
             if pos < 0 or pos >= order.shape[0] or not active[pi]:
                 continue
             nid = lane.nodes[order[pos]].id
-            if charge(lane, entry(lane, pos, nid), pi):
+            if charge_lane(lane, nid, entry(lane, pos, nid), pi):
                 accepted_own.append(pos)
             elif resolvable:
                 conflicted.append(pi)
@@ -740,15 +786,25 @@ def _cross_lane_fixpoint(lanes: List[PackedLane], results: List,
         metrics.incr("nomad.solver.fixpoint_conflicts", len(conflicted))
         metrics.incr("nomad.solver.fixpoint_dispatches")
         results[i] = _resolve_lane_conflicts(
-            lane, res, conflicted, accepted_own, ledger, entry, charge)
+            lane, res, conflicted, accepted_own, ledger, entry,
+            charge_lane, free_of, foreign)
+    if cross:
+        metrics.incr("nomad.solver.fixpoint_cross_batch_conflicts", cross)
+    return charged
 
 
 def _resolve_lane_conflicts(lane, res, conflicted, accepted_own,
-                            ledger, entry, charge):
+                            ledger, entry, charge, free_of, foreign=None):
     """Re-solve ``conflicted`` placements of one wave lane against the
-    ledger's accumulated usage; returns the merged result tuple (the
-    fused dispatch's arrays are read-only device-buffer views, so the
-    merge copies instead of mutating)."""
+    ledger's accumulated usage (each entry read through ``free_of``:
+    less the other barrier's bookings) and, on the nodes the ledger
+    does not hold, against this lane's own usage less those bookings
+    (``foreign``; no ledger entry is made for a node no lane of this
+    barrier was charged on: an entry is some lane's view of the node,
+    and only a placement there leaves the history that explains a later
+    lane's move off it); returns the merged result tuple (the fused
+    dispatch's arrays are read-only device-buffer views, so the merge
+    copies instead of mutating)."""
     from .binpack import solve_lane_fused
 
     import jax
@@ -758,24 +814,34 @@ def _resolve_lane_conflicts(lane, res, conflicted, accepted_own,
     n_yielded = np.array(res[2], copy=True)
     const, init = lane.const, lane.init
     order = np.asarray(lane.order)
-    n = order.shape[0]
-    pos_of = {lane.nodes[order[p]].id: p for p in range(n)}
+    pos_of = _scan_positions(lane, order)
 
     used_cpu = np.array(init.used_cpu, copy=True)
     used_mem = np.array(init.used_mem, copy=True)
     used_disk = np.array(init.used_disk, copy=True)
     dyn_avail = np.array(init.dyn_avail, copy=True)
     for nid, f in ledger.items():
-        p = pos_of.get(nid)
+        p = pos_of(nid)
         if p is None:
             continue
         # re-derive this lane's view of the node from the joint ledger
         # (caps are identical across lanes -- raw node resources minus
         # reserved -- so cap - free is the joint used)
+        f = free_of(nid, f)
         used_cpu[p] = float(const.cpu_cap[p]) - f[0]
         used_mem[p] = float(const.mem_cap[p]) - f[1]
         used_disk[p] = float(const.disk_cap[p]) - f[2]
         dyn_avail[p] = f[3]
+    if foreign is not None:
+        u = lane.usage_index
+        for nid, d in foreign.deductions(
+                float("inf") if u is None else u).items():
+            p = None if nid in ledger else pos_of(nid)
+            if p is not None:
+                used_cpu[p] += d[0]
+                used_mem[p] += d[1]
+                used_disk[p] += d[2]
+                dyn_avail[p] -= d[3]
     placed = np.array(init.placed, copy=True)
     placed_job = np.array(init.placed_job, copy=True)
     spread_counts = np.array(init.spread_counts, copy=True)
@@ -816,8 +882,58 @@ def _resolve_lane_conflicts(lane, res, conflicted, accepted_own,
         # charge the fresh choice (solved against the ledger's usage, so
         # it fits; charging records it for later lanes)
         nid = lane.nodes[order[pos]].id
-        charge(lane, entry(lane, pos, nid), pi)
+        charge(lane, nid, entry(lane, pos, nid), pi)
     return (chosen, scores, n_yielded)
+
+
+def _scan_positions(lane, order):
+    """node id -> the node's position in ``lane``'s scan order, or None.
+    By way of the lane's node matrix, whose id index every lane and
+    generation of one node table shares (a walk over 10,000 nodes a
+    conflicted lane otherwise)."""
+    matrix = lane.matrix
+    if matrix is None:
+        pos = {lane.nodes[i].id: p for p, i in enumerate(order.tolist())}
+        return pos.get
+    index = matrix.__dict__.get("_pos_index")
+    if index is None:
+        index = {nid: i for i, nid in enumerate(matrix.node_ids)}
+        matrix._pos_index = index
+    inv = np.full(len(matrix.node_ids), -1, dtype=np.int64)
+    inv[order] = np.arange(order.shape[0])
+
+    def pos_of(nid):
+        i = index.get(nid)
+        if i is None or inv[i] < 0:
+            return None
+        return int(inv[i])
+    return pos_of
+
+
+def _placement_need(batch, pi: int) -> tuple:
+    """(cpu, mem, disk, dynamic ports) placement ``pi`` takes of its
+    node: a ledger entry's and a booking's four numbers."""
+    return (float(batch.ask_cpu[pi]), float(batch.ask_mem[pi]),
+            float(batch.ask_disk[pi]), int(batch.n_dyn_ports[pi]))
+
+
+def _lane_charges(lane, res) -> Dict[str, list]:
+    """{node id: [cpu, mem, disk, dynamic ports]} of everything a lane's
+    result places: what a barrier books for a generation the fixpoint
+    had nothing to walk in (a lone lane, nothing in flight)."""
+    out: Dict[str, list] = {}
+    if res is None:
+        return out
+    active = np.asarray(lane.batch.active)
+    order = np.asarray(lane.order)
+    for pi, pos in enumerate(np.asarray(res[0]).tolist()):
+        if pos < 0 or pos >= order.shape[0] or not active[pi]:
+            continue
+        got = out.setdefault(lane.nodes[order[pos]].id,
+                             [0.0, 0.0, 0.0, 0])
+        for k, x in enumerate(_placement_need(lane.batch, pi)):
+            got[k] += x
+    return out
 
 
 class SolveBarrier:
@@ -832,11 +948,15 @@ class SolveBarrier:
     Completions apply in GENERATION ORDER: the cross-lane fixpoint
     ledger charges generation g before g+1 even when g+1's device work
     finishes first. ``depth`` is the pipeline's slot count, for tests
-    (1 = a serial order to compare with); production passes none."""
+    (1 = a serial order to compare with); production passes none.
+    ``bookings`` is the server's in-flight bookings
+    (server/inflight.py): what this barrier's fixpoint accepts is
+    booked there for the server's other barrier to charge, and theirs
+    is charged here; the batch's owner calls retire() when it ends."""
 
     def __init__(self, participants: int, use_mesh: bool = True,
                  e_pad_hint: int = 0, depth: Optional[int] = None,
-                 plan_group_hint=None):
+                 plan_group_hint=None, bookings=None):
         self._cv = threading.Condition()
         self._participants = participants
         self._finished = 0
@@ -859,6 +979,15 @@ class SolveBarrier:
         # shared per-node capacity ledger for the cross-lane conflict
         # fixpoint; persists across this batch's barrier generations
         self._ledger: Dict[str, list] = {}
+        self._bookings = bookings
+        if bookings is not None:
+            bookings.open_view(self)
+
+    def retire(self) -> None:
+        """The batch is over (or its worker is given up): nothing more
+        of this barrier's is in flight."""
+        if self._bookings is not None:
+            self._bookings.retire(self)
 
     def done(self) -> None:
         """Thread finished its eval (no more solves coming)."""
@@ -988,21 +1117,10 @@ class SolveBarrier:
                              "complete; proceeding out of order")
                         break
                     self._complete_cv.wait(remaining)
-        # only pay a second watchdog when the fixpoint can actually do
-        # work (its own early-return conditions); its re-solves are real
-        # device dispatches and deserve the same deadline as the fuse
-        fixpoint_needed = len(lanes) >= 2 or bool(self._ledger)
         try:
-            if err is None and fixpoint_needed:
+            if err is None:
                 try:
-                    from .guard import run_dispatch
-                    with tracer.activate(gctx), \
-                            tracer.span("solver.fixpoint", ctx=gctx,
-                                        generation=gen):
-                        run_dispatch(
-                            lambda: _cross_lane_fixpoint(lanes, results,
-                                                         self._ledger),
-                            label="solver.batch.fixpoint")
+                    self._settle_generation(gen, gctx, lanes, results)
                 except Exception as e:  # noqa: BLE001 -- same contract
                     err = e
         finally:
@@ -1018,6 +1136,42 @@ class SolveBarrier:
                 if self._next_complete == gen:
                     self._next_complete = gen + 1
                 self._complete_cv.notify_all()
+
+    def _settle_generation(self, gen: int, gctx, lanes, results) -> None:
+        """The fixpoint over one generation's results, then the booking
+        of what it accepted. With a server's bookings the two are one
+        section under its lock: the other barrier's fixpoint charges
+        either all of this generation or none of it."""
+        bookings = self._bookings
+        if bookings is None:
+            self._run_fixpoint(gen, gctx, lanes, results, None)
+            return
+        with bookings.fixpoint_lock:
+            charged = self._run_fixpoint(gen, gctx, lanes, results,
+                                         bookings.foreign(self))
+            for i, lane in enumerate(lanes):
+                bookings.book(
+                    self, lane.service.ctx.plan.eval_id,
+                    charged[i] if charged is not None
+                    else _lane_charges(lane, results[i]))
+
+    def _run_fixpoint(self, gen: int, gctx, lanes, results, foreign):
+        # only pay a second watchdog when the fixpoint can actually do
+        # work (its own early-return conditions); its re-solves are real
+        # device dispatches and deserve the same deadline as the fuse
+        if not (len(lanes) >= 2 or self._ledger or foreign is not None):
+            return None
+        from .guard import run_dispatch
+        # the fourth argument only where there is something to pass: the
+        # tests' stand-ins for the fixpoint take the three
+        args = (lanes, results, self._ledger)
+        if foreign is not None:
+            args += (foreign,)
+        with tracer.activate(gctx), \
+                tracer.span("solver.fixpoint", ctx=gctx, generation=gen):
+            return run_dispatch(
+                lambda: _cross_lane_fixpoint(*args),
+                label="solver.batch.fixpoint")
 
     def _hint_plan_group(self, n: int) -> None:
         """A generation's results are about to wake n eval threads, each

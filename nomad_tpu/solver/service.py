@@ -60,12 +60,12 @@ class PackedLane:
     __slots__ = ("service", "tg", "places", "nodes", "order", "const",
                  "init", "batch", "dtype_name", "spread_alg", "ptab",
                  "pinit", "cand_allocs", "table_version", "matrix",
-                 "delta_src", "_wave")
+                 "delta_src", "usage_index", "_wave")
 
     def __init__(self, service, tg, places, nodes, order, const, init,
                  batch, dtype_name, spread_alg, ptab=None, pinit=None,
                  cand_allocs=None, table_version=None, matrix=None,
-                 delta_src=None):
+                 delta_src=None, usage_index=None):
         self.service = service
         self.tg = tg
         self.places = places
@@ -93,6 +93,13 @@ class PackedLane:
         # tables were packed AT, so the device-resident chain can
         # advance v_old -> v_new by scatter instead of re-shipping
         self.delta_src = delta_src
+        # the state index this lane's usage holds every alloc up to:
+        # the live alloc table's last write when it was folded, else
+        # the snapshot's. Tells the cross-lane fixpoint which of the
+        # other barrier's bookings the usage cannot contain
+        # (server/inflight.py); None (a lane built by hand) = all that
+        # have committed
+        self.usage_index = usage_index
         self._wave = None
 
     def wavefront_ok(self) -> bool:
@@ -481,9 +488,11 @@ class TpuPlacementService:
             proposed_by_node = {
                 node.id: self.ctx.proposed_allocs(node.id) for node in nodes}
         table = getattr(self.ctx.state, "alloc_table", None)
+        usage_index = state_index
         if (table is not None and not table.has_port_overflow
                 and proposed_by_node is None):
-            usage = self._pack_usage_from_table(table, matrix, nodes, tg)
+            usage, usage_index = self._pack_usage_from_table(
+                table, matrix, nodes, tg)
         else:
             # incremental path: snapshot-scoped base fold + this eval's
             # own plan deltas -- O(plan) per eval instead of O(allocs)
@@ -636,7 +645,8 @@ class TpuPlacementService:
                               self.ctx.state, "node_table_index", None),
                           matrix=matrix,
                           delta_src=(delta_store, state_index)
-                          if delta_store is not None else None)
+                          if delta_store is not None else None,
+                          usage_index=usage_index)
 
     @staticmethod
     def _cands_hold_matching_devices(requests, cand_allocs, ptab) -> bool:
@@ -1031,11 +1041,13 @@ class TpuPlacementService:
         table via the native kernels (nomad_tpu/native.py), then overlay
         this eval's plan deltas (stops/preemptions/placements so far) --
         equivalent to folding ctx.proposed_allocs per node, without the
-        O(nodes x allocs) Python walk."""
+        O(nodes x allocs) Python walk. Returns the usage and the state
+        index it holds every alloc up to."""
         from ..tensor.pack import UsageState
         n, n_pad = len(nodes), matrix.n_pad
         store = getattr(self.ctx.state, "_store", None)
         lock = store._lock if store is not None else None
+        folded_at = self.ctx.state.latest_index()
 
         with_ports = bool(tg.networks)
         with (lock if lock is not None else contextlib.nullcontext()):
@@ -1044,9 +1056,9 @@ class TpuPlacementService:
                 # last alloc write lies past the snapshot whose index
                 # seeds this eval's shuffle (PERF.md open question 1)
                 from ..server.telemetry import metrics as _tm
+                folded_at = store._table_index.get("allocs", 0)
                 _tm.sample("nomad.solver.pack_usage_ahead", float(max(
-                    0, store._table_index.get("allocs", 0)
-                    - self.ctx.state.index)))
+                    0, folded_at - self.ctx.state.index)))
             # fold cache: all lanes of one barrier generation pack from
             # the same table version against the same (version-keyed)
             # matrix -- fold once, hand out copies (the overlay mutates
@@ -1100,7 +1112,7 @@ class TpuPlacementService:
             placed_job=placed_job, port_bitmap=packed["port_words"],
             dyn_used=packed["dyn_used"])
         self._overlay_plan_deltas(usage, nodes, tg)
-        return usage
+        return usage, folded_at
 
     def _pack_usage_incremental(self, matrix, nodes, tg):
         """Incremental usage packing (the pack-cache path when the alloc

@@ -416,6 +416,11 @@ class StateStore:
         # (the NOMAD_TPU_QUALITY=0 default for unattached stores) is
         # the prior path bit-for-bit.
         self._quality_hook = None
+        # called with (plan results, index) when verified plans have
+        # landed, lock still held: the server points it at its
+        # in-flight bookings (server/inflight.py), which must learn a
+        # commit's index before any lane can fold the table past it
+        self.plan_commit_hook = None
         # tensor-resident alloc table (fed to the TPU solver's native
         # packing kernels; maintained incrementally on every write)
         self.alloc_table = AllocTable()
@@ -1561,6 +1566,8 @@ class StateStore:
             idx = self._bump("allocs", "deployments", "evals",
                              delta=pairs, keys=keys)
             result.alloc_index = idx
+            if self.plan_commit_hook is not None:
+                self.plan_commit_hook((result,), idx)
             return idx
 
     def apply_plan_results_batch(
@@ -1615,6 +1622,8 @@ class StateStore:
                              delta=pairs_all, keys=keys_all)
             for result, _ in staged:
                 result.alloc_index = idx
+            if self.plan_commit_hook is not None:
+                self.plan_commit_hook([r for r, _ in staged], idx)
             return idx, outcomes
 
     def quality_usage_by_node(self) -> Dict[str, tuple]:
