@@ -34,10 +34,13 @@ on the chip, PERF.md section 2):
 - eviction_mismatches: over the placements that sit where the reference
   put them, those whose evicted allocs (the allocs on the node whose
   preempted_by_allocation names the placement) are not the reference's,
-  compared as multisets of `eviction_class`.
+  compared as multisets of `eviction_class`, and that no committed state
+  of that one node between the eval's snapshot and its plan explains
+  (`own_state_gap`: the device path reads each node's usage live).
 """
 from __future__ import annotations
 
+import bisect
 import importlib
 import json
 import os
@@ -52,6 +55,15 @@ INDEX_SLACK = 64
 # the reference reproduces a plan when this share of its placements sit
 # where the reference put them (a wrong index reproduces none)
 REPRODUCED_SHARE = 0.5
+# the most committed states one eval is replayed at, over all its
+# snapshot indexes: set from the most that sound runs walk (PERF.md
+# section 2); an eval that would walk further keeps its best comparison
+WALK_STATES = 128
+# the most node reads (one node a replay scans for one placement) that
+# the replays of all of a run's sampled evals make together: a check's
+# time grows with them. Set from the most that sound runs read (PERF.md
+# section 2); once they are spent, the evals left are not replayed
+WALK_READS = 12_000_000
 
 
 def evicts(config: dict) -> bool:
@@ -73,7 +85,9 @@ def reference_name(config: dict) -> str:
 def reference(name: str = "placement"):
     """The reference module perfbench/reference/<name>.py: `replay(job,
     group, order, usage_index, fetch, dtype)` -> [(node, score, window,
-    evicted alloc ids)], `scan_limit` and `shuffled`."""
+    evicted alloc ids)], `scan_limit` and `shuffled`; one that evicts
+    also `node_allocs`, `option` and `score`, one node's answer and its
+    score (`own_state_gap`)."""
     return importlib.import_module(f"reference.{name}")
 
 
@@ -115,7 +129,8 @@ def taken(on_node: list, cap: tuple, before: list, ask: tuple,
     it: what refuses a lane ahead its share of the node, whose charge
     the fixpoint's ledger still holds and no history shows. None: no
     other eval came near, and nothing of the program's moves a
-    placement off such a node."""
+    placement off such a node, full or not (a reference that evicts
+    picks full nodes)."""
     used, touched = list(before[:3]), False
     for b in on_node:
         if (b["eval_id"] != eval_id
@@ -123,9 +138,10 @@ def taken(on_node: list, cap: tuple, before: list, ask: tuple,
             touched = True
             for i, x in enumerate(cs.alloc_resources(b)):
                 used[i] += x
-    if any(used[i] + ask[i] > cap[i] for i in range(3)):
-        return "filled"
-    return "touched" if touched else None
+    if not touched:
+        return None
+    return "filled" if any(used[i] + ask[i] > cap[i] for i in range(3)) \
+        else "touched"
 
 
 def against(served: dict, seq: list, job: dict, group: str, eval_id: str,
@@ -216,18 +232,102 @@ def share_in_reach(served: dict, order: list, reach) -> float:
     return sum(node in head for node, _s in served.values()) / len(served)
 
 
-def compare_plan(served: dict, job: dict, group: str, eval_id: str,
-                 plan_index: int, indexes, base_order: list, fetch,
-                 reference=ref, evicted: dict = None) -> dict:
+def own_states(on_node: list, index: int, plan_index: int) -> list:
+    """The committed states of one node from an eval's snapshot `index`
+    up to its plan's commit: the snapshot's, then each at which one of
+    the node's allocs was created, stopped, released or reported
+    terminal by its client."""
+    return [index] + sorted(
+        {x for b in on_node
+         for x in (b["create_index"], cs.released_at(b, on_node),
+                   cs.stopped_at(b, on_node), cs.reported_at(b))
+         if x is not None and index < x < plan_index})
+
+
+def own_state_gap(k: int, served: dict, ids: dict, theirs: list, job: dict,
+                  group: str, index: int, plan_index: int, fetch, reference):
+    """Placement `k` sits on the reference's node and evicted the classes
+    `theirs` there, unlike the replay of the whole plan. Judged again at
+    each committed state of its own node (`own_states`), with the plan's
+    earlier placements on that node and what they evicted laid over it:
+    the answer of `reference.option` for that one node, where fitting
+    without eviction is the answer "nothing", and its score
+    (`reference.score`). The device path reads each node's usage live,
+    so a node it read may have had room at a state the replay of the
+    whole plan did not pick (PERF.md section 2). Returns the narrowest
+    distance between the program's score and the score of a state whose
+    answer is `theirs` (None where no state's is), and each state with
+    its answer ([state, the priorities evicted, [] for none; None where
+    the node is exhausted]). `ids`: placement number -> alloc id."""
+    node_id = served[k][0]
+    node, on_node = fetch(node_id)
+    by_id = {b["id"]: b for b in on_node}
+    count, ask = cs.plan_ask(job, group)
+    asks_devices = cs.device_asks(job, group)
+    attr = cs.spread_attribute(job)
+    earlier = {ids[j] for j in served if j < k}
+    evicted_of: dict = {}   # group -> its allocs the plan evicted before k
+    counts: dict = {}       # the spread's counts of the plan before k
+    for j in served:
+        if j < k:
+            other, on_other = fetch(served[j][0])
+            for b in on_other:
+                if b.get("preempted_by_allocation") == ids[j]:
+                    g = (b["job_id"], b["task_group"])
+                    evicted_of[g] = evicted_of.get(g, 0) + 1
+            if attr is not None:
+                value = other.get("meta", {}).get(attr)
+                counts[value] = counts.get(value, 0) + 1
+    placed = [b for b in on_node if b["id"] in earlier]
+    gone = {b["id"] for b in on_node
+            if b.get("preempted_by_allocation") in earlier}
+    best, answers = None, []
+    for state in own_states(on_node, index, plan_index):
+        proposed = [a for a in reference.node_allocs(on_node, state,
+                                                     job["id"])
+                    if a.id not in gone]
+        proposed += reference.node_allocs(placed, plan_index, job["id"])
+        got = reference.option(node, proposed, ask, asks_devices,
+                               int(job.get("priority", 50)), evicted_of,
+                               True)
+        answers.append([state, None if got is None else sorted(
+            a.priority for a in got[1])])
+        if got is None or sorted(
+                eviction_class(by_id[a.id]) for a in got[1]) != theirs:
+            continue
+        gap = float("inf") if served[k][1] is None else abs(
+            served[k][1] - reference.score(
+                node, proposed, got[0], got[1], (job["id"], group), count,
+                counts, attr))
+        best = gap if best is None else min(best, gap)
+    return best, answers
+
+
+def walk_budget() -> dict:
+    """What the replays of one run's sampled evals may still read."""
+    return {"reads": WALK_READS}
+
+
+def compare_plan(served: dict, ids: dict, job: dict, group: str,
+                 eval_id: str, plan_index: int, indexes, base_order: list,
+                 fetch, reference=ref, evicted: dict = None,
+                 budget: dict = None) -> dict:
     """One eval's first plan against the reference.
 
     `served`: placement number (the alloc name's index) -> (node id,
     score reported or None), for the allocs that plan committed at
-    `plan_index`; `indexes`: the state indexes the eval's snapshot can
-    have had, likeliest first; `base_order`: the ready nodes in
-    registration order; `reference`: the module that replays the eval;
-    `evicted`: placement number -> the sorted classes of what it evicted
-    (`evicted_classes`), for a deployment that evicts.
+    `plan_index`; `ids`: placement number -> alloc id; `indexes`: the
+    state indexes the eval's snapshot can have had, likeliest first;
+    `base_order`: the ready nodes in registration order; `reference`:
+    the module that replays the eval; `evicted`: placement number -> the
+    sorted classes of what it evicted (`evicted_classes`), for a
+    deployment that evicts. An evicted set unlike the reference's is
+    judged again at its own node's states (`own_state_gap`): where one
+    state gives it, and the program's score lies no farther from that
+    state's than from the replayed one's, the score gap is taken there;
+    else the mismatch stays, listed with every state's answer. `budget`:
+    the run's `walk_budget`, which every node a replay reads draws on (a
+    fresh one where None).
 
     The eval's scan order is the shuffle its snapshot's index seeds; the
     usage it packs is the live alloc table's when it packs, a committed
@@ -237,51 +337,82 @@ def compare_plan(served: dict, job: dict, group: str, eval_id: str,
     state at which a node the scan reached changed, the reference
     replays the eval and is compared with `served` (`against`); the pair
     with the fewest unexplained placements, then the fewest evicted sets
-    unlike the reference's, stands.
+    unlike the reference's, stands. No more than WALK_STATES states are
+    replayed in all, and none once the run's budget is spent; an eval
+    that would need more keeps the best so far, which does not reproduce
+    the plan, and says so (`walk_capped`).
     Returns that comparison with `reproduced` (REPRODUCED_SHARE of the
-    plan sits where the reference put it), `index`, `usage_index` and
-    `order`; `index` is None when no index was replayed at all."""
+    plan sits where the reference put it), `index`, `usage_index`,
+    `order`, `walked` (the states replayed) and `walk_capped`; `index`
+    is None when no index was replayed at all."""
+    budget = walk_budget() if budget is None else budget
     count, _ask = cs.plan_ask(job, group)
     reach, share = scan_reach(len(base_order), reference.scan_limit(
         len(base_order), count, cs.spread_attribute(job) is not None))
-    best = None
+    best, walked, capped = None, 0, False
+    events: dict = {}   # node id -> the indexes at which its allocs changed
     for index in indexes:
         order = reference.shuffled(base_order, eval_id, index)
         if share_in_reach(served, order, reach) < share:
             continue    # no node is read for a wrong index
         usage_index = index
         while usage_index is not None:
+            if walked == WALK_STATES or budget["reads"] <= 0:
+                capped = True
+                break
+            walked += 1
             seen: set = set()
 
             def fetch_seen(node_id, seen=seen):
                 seen.add(node_id)
+                budget["reads"] -= 1
                 return fetch(node_id)
             seq = reference.replay(job, group, order, usage_index,
                                    fetch_seen)
             got = against(served, seq, job, group, eval_id, usage_index,
                           plan_index, fetch_seen, evicted)
-            got.update(index=index, usage_index=usage_index, order=order)
+            kept, gaps = [], dict(zip(got["same"], got["gaps"]))
+            for m in got["eviction_mismatches"]:
+                gap, answers = own_state_gap(
+                    m[0], served, ids, m[3], job, group, index, plan_index,
+                    fetch, reference)
+                # the state that explains the evictions explains the score
+                # no worse than the replayed one
+                if gap is None or gap > gaps[m[0]]:
+                    kept.append(m + [answers])
+                else:
+                    gaps[m[0]] = gap
+            got.update(index=index, usage_index=usage_index, order=order,
+                       gaps=[gaps[k] for k in got["same"]],
+                       eviction_mismatches=kept, evictions_at_own_state=len(
+                           got["eviction_mismatches"]) - len(kept))
             if best is None or rank(got) < rank(best):
                 best = got
             if not got["mismatches"] and not got["eviction_mismatches"]:
                 break
             # the next state at which a node this replay read changed
-            events = {x for node_id in seen
-                      for on_node in [fetch(node_id)[1]] for b in on_node
-                      for x in (b["create_index"],
-                                cs.released_at(b, on_node))
-                      if x is not None and usage_index < x < plan_index}
-            usage_index = min(events, default=None)
-        if best is not None and not best["mismatches"] and \
+            for node_id in seen - events.keys():
+                on_node = fetch(node_id)[1]
+                events[node_id] = sorted(
+                    {x for b in on_node
+                     for x in (b["create_index"], cs.released_at(b, on_node))
+                     if x is not None})
+            usage_index = min(
+                (x for node_id in seen for x in events[node_id][bisect.bisect(
+                    events[node_id], usage_index):][:1] if x < plan_index),
+                default=None)
+        if capped or best is not None and not best["mismatches"] and \
                 not best["eviction_mismatches"] and \
                 len(best["same"]) >= REPRODUCED_SHARE * len(served):
             break
     if best is None:
         return {"reproduced": False, "index": None, "usage_index": None,
                 "same": [], "gaps": [], "mismatches": [],
-                "eviction_mismatches": [],
-                "moved": {"filled": 0, "touched": 0}}
+                "eviction_mismatches": [], "evictions_at_own_state": 0,
+                "moved": {"filled": 0, "touched": 0},
+                "walked": walked, "walk_capped": capped}
     best["reproduced"] = len(best["same"]) >= REPRODUCED_SHARE * len(served)
+    best.update(walked=walked, walk_capped=capped)
     return best
 
 

@@ -45,6 +45,12 @@ def released_at(a: dict, on_node: list):
     counts."""
     if a["desired_status"] == EVICT:
         return evicted_at(a, on_node)
+    return reported_at(a)
+
+
+def reported_at(a: dict):
+    """The index of its client's terminal report (its last write), None
+    while its client has reported none."""
     if a["client_status"] in ("complete", "failed", "lost"):
         return a["modify_index"]
     return None
@@ -117,6 +123,13 @@ def plan_ask(job: dict, group: str) -> tuple:
         float(sum(t["resources"]["cpu"] for t in tg["tasks"])),
         float(sum(t["resources"]["memory_mb"] for t in tg["tasks"])),
         float(tg["ephemeral_disk"]["size_mb"]))
+
+
+def device_asks(job: dict, group: str) -> list:
+    """[(device name, count)] one placement of a job's group asks for."""
+    return [(d["name"], int(d.get("count", 1)))
+            for t in task_group(job, group)["tasks"]
+            for d in t["resources"].get("devices") or ()]
 
 
 def alloc_priority(a: dict) -> int:

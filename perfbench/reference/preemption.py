@@ -285,6 +285,35 @@ def option(node: dict, proposed: list, ask: tuple, asks_devices: list,
     return used, evicted, taken
 
 
+def score(node: dict, proposed: list, used: list, evicted: list,
+          group: tuple, count: int, counts: dict, attr,
+          dtype: str = "float64") -> float:
+    """The mean of one option's scores, in rank.go's order: binpack from
+    `used`, job anti-affinity over the allocs of `group` among
+    `proposed`, the even-spread boost where it is not zero (`counts`:
+    this plan's placements per value of the spread attribute `attr`),
+    the preemption score of `evicted`. `dtype` other than float64
+    rounds each score and the mean to that precision."""
+    cap = cs.node_capacity(node)
+    scores = [float(binpack_score(cap[0], cap[1], used[0], used[1], dtype))]
+    anti = anti_affinity(sum(1 for a in proposed if a.group == group), count,
+                         dtype)
+    if anti is not None:
+        scores.append(float(anti))
+    if attr is not None:
+        boost = float(even_spread_boost(
+            counts, node.get("meta", {}).get(attr), dtype))
+        if boost != 0.0:
+            scores.append(boost)
+    if evicted:
+        scores.append(preemption_score(net_priority(evicted)))
+    if dtype == "float64":
+        return sum(scores) / len(scores)
+    rounding = _f(dtype)
+    scores = [float(rounding(x)) for x in scores]
+    return float(rounding(sum(scores) / len(scores)))
+
+
 def group_constraints(job: dict, group: str) -> list:
     tg = cs.task_group(job, group)
     return list(job.get("constraints") or ()) + list(
@@ -327,10 +356,7 @@ def replay(job: dict, group: str, order: list, usage_index: int, fetch,
     float64 (the control) rounds every score it compares to that
     precision."""
     count, ask = cs.plan_ask(job, group)
-    tg = cs.task_group(job, group)
-    asks_devices = [(d["name"], int(d.get("count", 1)))
-                    for t in tg["tasks"]
-                    for d in t["resources"].get("devices") or ()]
+    asks_devices = cs.device_asks(job, group)
     constraints = group_constraints(job, group)
     attr = cs.spread_attribute(job)
     priority = int(job.get("priority", 50))
@@ -339,7 +365,6 @@ def replay(job: dict, group: str, order: list, usage_index: int, fetch,
     counts: dict = {}       # the spread's counts of this job's allocs
     evicted_of: dict = {}   # group -> its allocs this plan has evicted
     infeasible: set = set()
-    rounding = None if dtype == "float64" else _f(dtype)
 
     def select(evict: bool):
         """stack.go Select: the best of the first `limit` options."""
@@ -358,30 +383,12 @@ def replay(job: dict, group: str, order: list, usage_index: int, fetch,
             if got is None:
                 continue
             used, evicted, taken = got
-            cap = cs.node_capacity(node)
-            scores = [float(binpack_score(cap[0], cap[1], used[0], used[1],
-                                          dtype))]
-            collisions = sum(1 for a in state[node_id]
-                             if a.group == (job["id"], group))
-            anti = anti_affinity(collisions, count, dtype)
-            if anti is not None:
-                scores.append(float(anti))
-            if attr is not None:
-                boost = float(even_spread_boost(
-                    counts, node.get("meta", {}).get(attr), dtype))
-                if boost != 0.0:
-                    scores.append(boost)
-            if evicted:
-                scores.append(preemption_score(net_priority(evicted)))
-            if rounding is not None:
-                scores = [float(rounding(x)) for x in scores]
-            score = sum(scores) / len(scores)
-            if rounding is not None:
-                score = float(rounding(score))
-            if score <= SKIP_SCORE and len(aside) < MAX_SKIP:
-                aside.append((node_id, score, evicted, taken))
+            mean = score(node, state[node_id], used, evicted,
+                         (job["id"], group), count, counts, attr, dtype)
+            if mean <= SKIP_SCORE and len(aside) < MAX_SKIP:
+                aside.append((node_id, mean, evicted, taken))
                 continue
-            seen.append((node_id, score, evicted, taken))
+            seen.append((node_id, mean, evicted, taken))
             if len(seen) == limit:
                 break
         seen += aside[:limit - len(seen)]
@@ -395,7 +402,7 @@ def replay(job: dict, group: str, order: list, usage_index: int, fetch,
         if got is None:
             out.append((None, None, [], []))
             continue
-        (node_id, score, evicted, taken), window = got
+        (node_id, mean, evicted, taken), window = got
         allocs = [a for a in state[node_id] if a not in evicted]
         allocs.append(Alloc(None, (job["id"], group), priority, 0, ask,
                             dict(taken), True))
@@ -405,5 +412,5 @@ def replay(job: dict, group: str, order: list, usage_index: int, fetch,
         if attr is not None:
             value = fetch(node_id)[0].get("meta", {}).get(attr)
             counts[value] = counts.get(value, 0) + 1
-        out.append((node_id, score, window, [a.id for a in evicted]))
+        out.append((node_id, mean, window, [a.id for a in evicted]))
     return out

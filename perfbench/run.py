@@ -362,12 +362,10 @@ def read_back(api: Api, server: Server, cell: dict, window_jobs: list,
                 if b["id"] in stops:
                     b["stop_index"] = stops[b["id"]]
         return nodes_seen[node_id]
-    def fresh():
-        return {"evals": 0, "unreproduced": 0, "unreplayed": 0,
-                "mismatches": [], "filled": 0, "touched": 0, "retried": 0,
-                "gaps": [], "back": [], "ahead": [], "evictions": [],
-                "evicting": 0}
-    tally, ctl = fresh(), fresh() if control else None
+    tally, ctl = new_tally(), new_tally() if control else None
+    # what the replays of all the sampled evals may read, one for the
+    # program's answers and one for the control's (check.WALK_READS)
+    budget, ctl_budget = check.walk_budget(), check.walk_budget()
     for rec in order[:int(mix["check_jobs"])]:
         job = api.get(f"/v1/job/{rec['id']}")
         live = [a for a in api.get(f"/v1/job/{rec['id']}/allocations")
@@ -383,9 +381,9 @@ def read_back(api: Api, server: Server, cell: dict, window_jobs: list,
         # solved against a state no older than the eval and older than
         # its plan, likeliest the newest
         indexes = range(plan_index - 1, ev["create_index"] - 1, -1)
-        got = check.compare_plan(served, job, group, rec["eval_id"],
+        got = check.compare_plan(served, ids, job, group, rec["eval_id"],
                                  plan_index, indexes, base_order, fetch,
-                                 reference, evicted)
+                                 reference, evicted, budget)
         add(tally, got, len(live) - len(served), plan_index)
         if control and got["reproduced"]:
             low = reference.replay(job, group, got["order"],
@@ -399,9 +397,9 @@ def read_back(api: Api, server: Server, cell: dict, window_jobs: list,
                     low_evicted[k] = sorted(check.eviction_class(by_id[i])
                                             for i in low[k][3])
             add(ctl, check.compare_plan(
-                {k: low[k][:2] for k in served}, job, group, rec["eval_id"],
-                plan_index, [got["index"]], base_order, fetch, reference,
-                low_evicted), 0, plan_index)
+                {k: low[k][:2] for k in served}, ids, job, group,
+                rec["eval_id"], plan_index, [got["index"]], base_order, fetch,
+                reference, low_evicted, ctl_budget), 0, plan_index)
     c = snap_end["counters"]
     not_device = (c.get("nomad.solver.dispatch_timeout", 0)
                   + c.get("nomad.solver.dispatch_error", 0)
@@ -436,6 +434,15 @@ def read_back(api: Api, server: Server, cell: dict, window_jobs: list,
             "mismatches_first": tally["mismatches"][:5],
             "evicting_placements": tally["evicting"],
             "eviction_mismatches_first": tally["evictions"][:5],
+            # evicted sets unlike the replayed state's that their own
+            # node's state explains (check.own_state_gap)
+            "evictions_at_own_state": tally["at_own_state"],
+            # [states replayed, whether the reference matched the plan]
+            # an eval, the evals that met a bound (check.WALK_STATES, or
+            # check.WALK_READS spent), and the node reads of all replays
+            "walk_states": tally["walked"],
+            "walk_capped_evals": tally["capped"],
+            "walk_reads": check.WALK_READS - budget["reads"],
             "retried_not_compared": tally["retried"],
             "plan_minus_snapshot_index": tally["back"],
             "usage_minus_snapshot_index": tally["ahead"],
@@ -446,12 +453,23 @@ def read_back(api: Api, server: Server, cell: dict, window_jobs: list,
     return numbers(tally), numbers(ctl) if control else None, info
 
 
+def new_tally() -> dict:
+    return {"evals": 0, "unreproduced": 0, "unreplayed": 0,
+            "mismatches": [], "filled": 0, "touched": 0, "retried": 0,
+            "gaps": [], "back": [], "ahead": [], "evictions": [],
+            "evicting": 0, "at_own_state": 0, "walked": [], "capped": 0}
+
+
 def add(tally: dict, got: dict, retried: int, plan_index: int) -> None:
     tally["evals"] += 1
     tally["unreproduced"] += 0 if got["reproduced"] else 1
     tally["unreplayed"] += 1 if got["index"] is None else 0
     tally["mismatches"] += got["mismatches"]
     tally["evictions"] += got["eviction_mismatches"]
+    tally["at_own_state"] += got["evictions_at_own_state"]
+    tally["walked"].append([got["walked"], got["reproduced"] and not (
+        got["mismatches"] or got["eviction_mismatches"])])
+    tally["capped"] += 1 if got["walk_capped"] else 0
     tally["filled"] += got["moved"]["filled"]
     tally["touched"] += got["moved"]["touched"]
     tally["retried"] += retried

@@ -142,15 +142,17 @@ def standin_tree(root, manifest):
     return grown
 
 
-def tier5_world(n_nodes, count, seed, cpu_only, alg="binpack", util=0.95):
+def tier5_world(n_nodes, count, seed, cpu_only, alg="binpack", util=0.95,
+                gpu_holders=False):
     """nomad_tpu.benchkit.run_tier_placements(5, ...) with its state
     kept: a fleet of three machine classes filled to `util` of its cpu
     (BASELINE's 95%) by priority 10-40 fillers and one priority-70 job
-    (without its GPU ask where `cpu_only`), preemption on. Returns (the
-    world as the API's JSON: (node id -> (node, its allocs), ready node
-    ids, job, eval id, state index), the host's plan: (placement number
-    -> node, -> sorted evicted alloc ids, -> score), the host's state
-    store). Placing nothing, with `alg` None."""
+    (without its GPU ask where `cpu_only`), preemption on. With
+    `gpu_holders`, the fillers hold every GPU instance (`hold_gpus`).
+    Returns (the world as the API's JSON: (node id -> (node, its
+    allocs), ready node ids, job, eval id, state index), the host's plan:
+    (placement number -> node, -> sorted evicted alloc ids, -> score),
+    the host's state store). Placing nothing, with `alg` None."""
     import itertools
     import random
     from nomad_tpu import mock
@@ -158,12 +160,43 @@ def tier5_world(n_nodes, count, seed, cpu_only, alg="binpack", util=0.95):
     # ids from a counter of their own, and the process's put back after
     saved, mock._counter = mock._counter, itertools.count()
     try:
-        return _tier5_world(rng, n_nodes, count, seed, cpu_only, alg, util)
+        return _tier5_world(rng, n_nodes, count, seed, cpu_only, alg, util,
+                            gpu_holders)
     finally:
         mock._counter = saved
 
 
-def _tier5_world(rng, n_nodes, count, seed, cpu_only, alg, util):
+def hold_gpus(rng, h, nodes):
+    """Every GPU instance of the fleet held by the fillers on its node, 1
+    or 2 instances a holder, holders drawn from the seed; each holder's
+    job asks for what it holds. So a GPU ask finds no free instance, and
+    device preemption decides where it goes."""
+    from nomad_tpu.structs.resources import (AllocatedDeviceResource,
+                                             DeviceRequest)
+    for node in nodes:
+        for group in node.node_resources.devices:
+            free = list(group.instance_ids)
+            allocs = list(h.state.allocs_by_node(node.id))
+            rng.shuffle(allocs)
+            held = []
+            for a in allocs:
+                if not free:
+                    break
+                k = min(len(free), rng.choice([1, 2]))
+                a = a.copy_skip_job()
+                take, free = free[:k], free[k:]
+                task = next(iter(a.allocated_resources.tasks.values()))
+                task.devices = [AllocatedDeviceResource(
+                    vendor=group.vendor, type=group.type, name=group.name,
+                    device_ids=take)]
+                a.job.task_groups[0].tasks[0].resources.devices = [
+                    DeviceRequest(name="nvidia/gpu", count=k)]
+                held.append(a)
+            h.state.upsert_allocs(held)
+
+
+def _tier5_world(rng, n_nodes, count, seed, cpu_only, alg, util,
+                 gpu_holders):
     from nomad_tpu import benchkit, mock
     from nomad_tpu.scheduler import Harness
     from nomad_tpu.structs import codec
@@ -176,6 +209,8 @@ def _tier5_world(rng, n_nodes, count, seed, cpu_only, alg, util):
     h.state.set_scheduler_config(cfg)
     nodes = benchkit.make_fleet(rng, h, n_nodes, gpus=True)
     benchkit.seed_utilization(rng, h, nodes, util, priorities=(10, 20, 30, 40))
+    if gpu_holders:
+        hold_gpus(rng, h, nodes)
     job = benchkit.tier_job(5, rng, count)
     if cpu_only:
         job.task_groups[0].tasks[0].resources.devices = []
@@ -252,6 +287,50 @@ def preemptor_pairs(world, state):
             sorted(check.eviction_class(by_id[a.id]) for a in mine),
             preemption_score(net_priority(theirs)) if theirs else None,
             ref.preemption_score(ref.net_priority(mine)) if mine else None))
+    return out
+
+
+def device_pairs(world):
+    """On every node of a tier-5 world whose matching device instances
+    are all held (`hold_gpus`), the classes of what each evicts for the
+    job's device ask: the host's Preemptor.preempt_for_device (on the
+    node and allocs decoded from the world's JSON), reference/
+    preemption.py's PreemptForDevice, and the host's as
+    data/preempt/host_departures.py writes it; [] where one finds
+    nothing. [(node id, host's, reference's, departure's)]."""
+    import check
+    from nomad_tpu.scheduler.preemption import Preemptor
+    from nomad_tpu.scheduler.rank import DeviceAllocator
+    from nomad_tpu.structs import Allocation, DeviceRequest, Node, codec
+    from reference import preemption as ref
+    fleet, _base, job, _eval_id, index = world
+    [ask] = [d for t in job["task_groups"][0]["tasks"]
+             for d in t["resources"].get("devices") or ()]
+    count, priority = int(ask.get("count", 1)), int(job["priority"])
+    host_of = host_reference().host_preempt_for_device
+    out = []
+    for node_id, (node, on_node) in sorted(fleet.items()):
+        groups = ref.device_groups(node, ask["name"])
+        proposed = ref.node_allocs(on_node, index, job["id"])
+        if not groups or ref.assign_devices(groups, proposed, {}, count):
+            continue
+        by_id = {b["id"]: b for b in on_node}
+        host_node = codec.decode(Node, node)
+        live = [a for a in (codec.decode(Allocation, b) for b in on_node)
+                if not a.terminal_status()]
+        host = Preemptor(priority, None, (job["namespace"], job["id"]))
+        host.set_node(host_node)
+        host.set_preemptions([])
+        host.set_candidates(live)
+        allocator = DeviceAllocator(None, host_node)
+        allocator.add_allocs(live)
+        theirs = host.preempt_for_device(
+            DeviceRequest(name=ask["name"], count=count), allocator) or []
+        out.append((node_id, *(
+            sorted(check.eviction_class(by_id[a.id]) for a in got)
+            for got in (theirs, ref.preempt_for_device(
+                groups, proposed, {}, count, priority), host_of(
+                groups, proposed, {}, count, priority)))))
     return out
 
 

@@ -97,8 +97,9 @@ def compare(n, spread, count, cannot, indexes, served=None):
     def fetch(node_id):
         read.add(node_id)
         return fleet[node_id]
-    got = check.compare_plan(served or plan, job, "web", eval_id, PLAN,
-                             indexes, ids, fetch)
+    served = served or plan
+    got = check.compare_plan(served, {k: f"alloc-{k}" for k in served}, job,
+                             "web", eval_id, PLAN, indexes, ids, fetch)
     return got, read
 
 

@@ -1,9 +1,9 @@
 """reference/preemption.py, written to Nomad 1.7, beside the program's
 host iterator stack on seeded worlds of `nomad_tpu.benchkit` (BASELINE
 tier 5: a fleet of three machine classes filled by priority 10-40
-fillers, one priority-70 job; and a cpu-only variant without its GPU
-ask), each read the way check.py reads the served one, as the API's
-JSON (`structs.codec.encode`):
+fillers, one priority-70 job; a cpu-only variant without its GPU ask;
+and a variant whose fillers hold every GPU), each read the way check.py
+reads the served one, as the API's JSON (`structs.codec.encode`):
 
 - where both follow the same source function (PreemptForTaskGroup with
   filterSuperset, netPriority, preemptionScore), exactly;
@@ -16,7 +16,7 @@ import itertools
 import random
 
 import pytest
-from conftest import against_host, preemptor_pairs, tier5_world
+from conftest import against_host, device_pairs, preemptor_pairs, tier5_world
 
 from reference import preemption as ref
 
@@ -51,6 +51,50 @@ def test_the_host_stack_departs_only_where_perf_md_says(n, count, seed,
     plain = against_host(world, plan)
     departed = against_host(world, plan, departures=True)
     assert plain[3] == count
+    assert any(got[:2] == (count, count) and got[2] < 1e-9
+               for got in (plain, departed)), (plain, departed)
+
+
+# (nodes, count, seed): the fillers hold every GPU instance (`hold_gpus`)
+# and the job asks for one, so every placement evicts for a device
+DEVICE_WORLDS = [(200, 12, 21), (300, 16, 22), (400, 20, 23),
+                 (600, 24, 24), (1000, 30, 25)]
+
+
+@pytest.mark.parametrize("n,count,seed", DEVICE_WORLDS)
+def test_device_preemption_beside_the_host_preemptor(n, count, seed):
+    """Every node whose GPUs are all held: the host's
+    Preemptor.preempt_for_device evicts what host_departures'
+    PreemptForDevice does on every node, or what the reference's does on
+    every node once the program follows the source; where the two
+    differ, the host takes a holder of a higher priority or of more
+    instances (PERF.md section 7, item 0 (j))."""
+    world, _plan, _state = tier5_world(n, count, seed, False, alg=None,
+                                       gpu_holders=True)
+    pairs = device_pairs(world)
+    assert len(pairs) == n // 2
+    assert all(host and mine for _node, host, mine, _dep in pairs)
+    assert all(host == dep for _node, host, _mine, dep in pairs) or all(
+        host == mine for _node, host, mine, _dep in pairs)
+    for _node, host, mine, _dep in pairs:
+        if host != mine:
+            assert min(c[0] for c in host) > min(c[0] for c in mine) or \
+                sum(dict(c[2]).get("nvidia/gpu/v100", 0) for c in host) > \
+                sum(dict(c[2]).get("nvidia/gpu/v100", 0) for c in mine)
+
+
+@pytest.mark.parametrize("n,count,seed", DEVICE_WORLDS)
+def test_a_device_ask_evicts_as_the_reference_with_the_departures(n, count,
+                                                                   seed):
+    """The host stack's whole plan: every placement evicts for its GPU,
+    and sits where the reference with the host's departures laid over it
+    puts it, evicting the same classes with the same score (or where the
+    reference does)."""
+    world, plan, _state = tier5_world(n, count, seed, False,
+                                      gpu_holders=True)
+    assert len(plan[1]) == count and all(plan[1].values())
+    plain = against_host(world, plan)
+    departed = against_host(world, plan, departures=True)
     assert any(got[:2] == (count, count) and got[2] < 1e-9
                for got in (plain, departed)), (plain, departed)
 
