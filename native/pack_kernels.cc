@@ -13,6 +13,7 @@
 #include <cstdint>
 #include <cmath>
 #include <cstring>
+#include <algorithm>
 
 extern "C" {
 
@@ -338,6 +339,174 @@ void nt_verify_plan(const double* tbl_cpu, const double* tbl_mem,
   }
 }
 
-int32_t nt_abi_version() { return 3; }
+}  // extern "C"
+
+namespace {
+
+const int64_t kWaveBig = int64_t(1) << 30;
+
+// used0 + m * ask <= cap, in T, operation by operation as numpy
+// evaluates binpack.wavefront_compact_host's fits()
+template <typename T>
+inline bool wave_fits(T used0, T cap, T ask, int64_t m) {
+  const T prod = static_cast<T>(m) * ask;
+  const T total = used0 + prod;
+  return total <= cap;
+}
+
+// Placements of one ask that fit a node in one dimension: the float
+// quotient, then two corrections down and two up by the same float
+// predicate the kernels score with (binpack.py cap_dim).
+template <typename T>
+inline int64_t wave_cap_dim(T used0, T cap, T ask) {
+  if (!(ask > T(0))) return kWaveBig;
+  const T div = ask > T(1e-9) ? ask : T(1e-9);
+  const T f = std::floor((cap - used0) / div);
+  int64_t q;
+  if (!std::isfinite(f)) {
+    q = 0;
+  } else if (f >= T(9.223372036854775808e18) ||
+             f < T(-9.223372036854775808e18)) {
+    q = INT64_MIN;  // what numpy's float -> int64 cast gives on x86
+  } else {
+    q = static_cast<int64_t>(f);
+  }
+  if (!wave_fits(used0, cap, ask, q)) q -= 1;
+  if (!wave_fits(used0, cap, ask, q)) q -= 1;
+  if (q < 0) q = 0;
+  if (wave_fits(used0, cap, ask, q + 1)) q += 1;
+  if (wave_fits(used0, cap, ask, q + 1)) q += 1;
+  if (!wave_fits(used0, cap, ask, q)) q = 0;
+  return q;
+}
+
+inline int64_t floor_div(int64_t a, int64_t b) {
+  int64_t q = a / b;
+  if ((a % b != 0) && ((a < 0) != (b < 0))) q -= 1;
+  return q;
+}
+
+template <typename T>
+int64_t wave_compact(
+    int64_t n, int64_t p, int64_t rows, int32_t s, double ask_cpu_d,
+    double ask_mem_d, double ask_disk_d, int64_t n_dyn, int32_t has_static,
+    int32_t distinct, int32_t distinct_job_level, const void* cpu_cap_v,
+    const void* mem_cap_v, const void* disk_cap_v, const void* used_cpu_v,
+    const void* used_mem_v, const void* used_disk_v,
+    const int32_t* dyn_avail, const uint8_t* static_free,
+    const int32_t* placed, const int32_t* placed_job,
+    const uint8_t* feasible, const void* affinity_v,
+    const int64_t* cap_extra, const int32_t* spread_vidx, int64_t n_patch,
+    const int64_t* patch_pos, const void* patch_cpu_v,
+    const void* patch_mem_v, const void* patch_disk_v,
+    const int32_t* patch_dyn, const int32_t* patch_placed,
+    const int32_t* patch_placed_job, void* compact_v) {
+  const T* cpu_cap = static_cast<const T*>(cpu_cap_v);
+  const T* mem_cap = static_cast<const T*>(mem_cap_v);
+  const T* disk_cap = static_cast<const T*>(disk_cap_v);
+  const T* used_cpu = static_cast<const T*>(used_cpu_v);
+  const T* used_mem = static_cast<const T*>(used_mem_v);
+  const T* used_disk = static_cast<const T*>(used_disk_v);
+  const T* affinity = static_cast<const T*>(affinity_v);
+  const T* patch_cpu = static_cast<const T*>(patch_cpu_v);
+  const T* patch_mem = static_cast<const T*>(patch_mem_v);
+  const T* patch_disk = static_cast<const T*>(patch_disk_v);
+  T* compact = static_cast<T*>(compact_v);
+  const T ask_cpu = static_cast<T>(ask_cpu_d);
+  const T ask_mem = static_cast<T>(ask_mem_d);
+  const T ask_disk = static_cast<T>(ask_disk_d);
+  const int64_t width = 8 + s;
+  int64_t k = 0, scanned = 0, next = 0;
+  for (int64_t i = 0; i < n && k < rows; ++i) {
+    scanned = i + 1;
+    T u_cpu = used_cpu[i], u_mem = used_mem[i], u_disk = used_disk[i];
+    int32_t dyn = dyn_avail[i], pl = placed[i], pl_job = placed_job[i];
+    while (next < n_patch && patch_pos[next] < i) ++next;
+    if (next < n_patch && patch_pos[next] == i) {
+      u_cpu = patch_cpu[next];
+      u_mem = patch_mem[next];
+      u_disk = patch_disk[next];
+      dyn = patch_dyn[next];
+      pl = patch_placed[next];
+      pl_job = patch_placed_job[next];
+    }
+    int64_t c = wave_cap_dim(u_cpu, cpu_cap[i], ask_cpu);
+    c = std::min(c, wave_cap_dim(u_mem, mem_cap[i], ask_mem));
+    c = std::min(c, wave_cap_dim(u_disk, disk_cap[i], ask_disk));
+    if (n_dyn > 0) c = std::min(c, floor_div(dyn, n_dyn));
+    if (has_static) c = std::min<int64_t>(c, static_free[i] ? 1 : 0);
+    if (distinct) {
+      const int32_t d0 = distinct_job_level ? pl_job : pl;
+      c = std::min<int64_t>(c, d0 > 0 ? 0 : 1);
+    }
+    if (cap_extra != nullptr) c = std::min(c, cap_extra[i]);
+    if (!feasible[i]) c = 0;
+    c = std::max<int64_t>(0, std::min(c, p));
+    if (c <= 0) continue;
+    T* row = compact + k * width;
+    row[0] = static_cast<T>(c);
+    row[1] = u_cpu;
+    row[2] = u_mem;
+    row[3] = cpu_cap[i];
+    row[4] = mem_cap[i];
+    row[5] = static_cast<T>(pl);
+    row[6] = affinity != nullptr ? affinity[i] : T(0);
+    row[7] = static_cast<T>(i);
+    for (int32_t j = 0; j < s; ++j)
+      row[8 + j] = static_cast<T>(spread_vidx[j * n + i]);
+    ++k;
+  }
+  for (; k < rows; ++k) {
+    T* row = compact + k * width;
+    for (int64_t j = 0; j < width; ++j) row[j] = T(0);
+    row[7] = T(-1);
+    for (int32_t j = 0; j < s; ++j) row[8 + j] = T(-1);
+  }
+  return scanned;
+}
+
+}  // namespace
+
+extern "C" {
+
+// One wave lane's compact candidate table (binpack.wavefront_compact_host,
+// bit for bit), in one call. Nodes are walked in scan order; each node's
+// placement capacity c is computed as the numpy precompute computes it,
+// and every node with c > 0 becomes a row [c, used_cpu, used_mem,
+// cpu_cap, mem_cap, placed, affinity, position, spread value indexes...].
+// The walk stops at the rows-th such node (only the first rows are ever
+// read) and the remaining rows get the sentinel (position -1, spread
+// values -1, zeros elsewhere).
+//
+// elem_bytes 4 or 8 selects float or double for every T array (caps,
+// usage, affinity, the patch's usage, the output). affinity and
+// cap_extra (an extra per-node capacity, the device dimension) may be
+// null. The patch overrides the lane's usage at n_patch ascending
+// positions: a fixpoint re-solve's view of the lane without a copy of it.
+// Returns how many nodes the walk read.
+int64_t nt_wave_compact(
+    int32_t elem_bytes, int64_t n, int64_t p, int64_t rows, int32_t s,
+    double ask_cpu, double ask_mem, double ask_disk, int64_t n_dyn,
+    int32_t has_static, int32_t distinct, int32_t distinct_job_level,
+    const void* cpu_cap, const void* mem_cap, const void* disk_cap,
+    const void* used_cpu, const void* used_mem, const void* used_disk,
+    const int32_t* dyn_avail, const uint8_t* static_free,
+    const int32_t* placed, const int32_t* placed_job,
+    const uint8_t* feasible, const void* affinity,
+    const int64_t* cap_extra, const int32_t* spread_vidx, int64_t n_patch,
+    const int64_t* patch_pos, const void* patch_cpu, const void* patch_mem,
+    const void* patch_disk, const int32_t* patch_dyn,
+    const int32_t* patch_placed, const int32_t* patch_placed_job,
+    void* compact) {
+  auto run = elem_bytes == 8 ? wave_compact<double> : wave_compact<float>;
+  return run(n, p, rows, s, ask_cpu, ask_mem, ask_disk, n_dyn, has_static,
+             distinct, distinct_job_level, cpu_cap, mem_cap, disk_cap,
+             used_cpu, used_mem, used_disk, dyn_avail, static_free, placed,
+             placed_job, feasible, affinity, cap_extra, spread_vidx, n_patch,
+             patch_pos, patch_cpu, patch_mem, patch_disk, patch_dyn,
+             patch_placed, patch_placed_job, compact);
+}
+
+int32_t nt_abi_version() { return 4; }
 
 }  // extern "C"

@@ -26,15 +26,16 @@ MAX_PORTS_PER_ALLOC = 8
 
 # Bumped whenever the C ABI changes shape; load() refuses a library that
 # answers otherwise instead of corrupting memory.
-ABI_VERSION = 3
+ABI_VERSION = 4
 
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 SOURCE = os.path.join(_NATIVE_DIR, "pack_kernels.cc")
 # no -march=native: the tree is copied between machines as it stands on
 # disk, and a library tuned to the CPU it was built on may not run on
-# the next one
-CXXFLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC")
+# the next one. No contraction into fused multiply-adds either: the wave
+# precompute must round as numpy does, operation by operation.
+CXXFLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC", "-ffp-contract=off")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
@@ -143,6 +144,15 @@ def _load_locked() -> ctypes.CDLL:
         ctypes.c_int32, i32, i32]
     lib.nt_shuffled_order.argtypes = [ctypes.c_uint64, ctypes.c_int32,
                                       i32]
+    v = ctypes.c_void_p
+    lib.nt_wave_compact.argtypes = [
+        ctypes.c_int32, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int32, ctypes.c_double, ctypes.c_double, ctypes.c_double,
+        ctypes.c_int64, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+        *[v] * 14,                            # node arrays
+        ctypes.c_int64, *[v] * 7,             # the patch
+        v]
+    lib.nt_wave_compact.restype = ctypes.c_int64
     return lib
 
 
@@ -197,6 +207,65 @@ def solve_eval(cpu_cap: np.ndarray, mem_cap: np.ndarray, disk_cap: np.ndarray,
         1 if spread_alg else 0, _ptr(order, ctypes.c_int32),
         _ptr(out_choice, ctypes.c_int32))
     return out_choice
+
+
+def wave_compact(dtype, p: int, rows: int, ask, n_dyn: int,
+                 has_static: bool, distinct: bool, distinct_job_level: bool,
+                 caps, used, dyn_avail, static_free, placed, placed_job,
+                 feasible, affinity, cap_extra, spread_vidx, patch=None
+                 ) -> Optional[Tuple[np.ndarray, int]]:
+    """One wave lane's compact candidate table, (rows, 8 + S) of
+    ``dtype`` (float32 or float64), exactly as
+    solver/binpack.py wavefront_compact_host builds it, in one call that
+    stops at the rows-th node with room; returns it with the number of
+    nodes walked, or None when the library is absent. ``caps`` and
+    ``used`` are (cpu, mem, disk) node arrays, ``ask`` the lane's three
+    asks; ``affinity`` and ``cap_extra`` (a further per-node capacity)
+    may be None. ``patch`` (ascending positions, then used cpu, mem,
+    disk, dynamic ports, placed, placed_job there) overrides the node
+    arrays at those positions."""
+    lib = load()
+    if lib is None:
+        return None
+    dt = np.dtype(dtype)
+    if dt not in (np.float32, np.float64):
+        raise ValueError(f"wave_compact: no kernel for {dt}")
+    f = [np.ascontiguousarray(a, dtype=dt) for a in (*caps, *used)]
+    i32 = [np.ascontiguousarray(a, dtype=np.int32)
+           for a in (dyn_avail, placed, placed_job)]
+    b8 = [np.ascontiguousarray(a, dtype=np.bool_).view(np.uint8)
+          for a in (static_free, feasible)]
+    aff = (None if affinity is None
+           else np.ascontiguousarray(affinity, dtype=dt))
+    extra = (None if cap_extra is None
+             else np.ascontiguousarray(cap_extra, dtype=np.int64))
+    vidx = np.ascontiguousarray(spread_vidx, dtype=np.int32)
+    n, s = f[0].shape[0], vidx.shape[0]
+    if patch is None:
+        n_patch, pt = 0, [None] * 7
+    else:
+        n_patch = len(patch[0])
+        pt = [np.ascontiguousarray(patch[0], dtype=np.int64),
+              *[np.ascontiguousarray(a, dtype=dt) for a in patch[1:4]],
+              *[np.ascontiguousarray(a, dtype=np.int32)
+                for a in patch[4:7]]]
+    node_arrays = [*f, *i32, *b8] + [a for a in (aff, extra) if a is not None]
+    if (any(a.shape != (n,) for a in node_arrays)
+            or vidx.shape != (s, n)
+            or any(a.shape != (n_patch,) for a in pt if a is not None)):
+        raise ValueError("wave_compact: arrays disagree on their lengths")
+    compact = np.empty((rows, 8 + s), dtype=dt)
+
+    def addr(a):
+        return None if a is None else a.ctypes.data
+    scanned = lib.nt_wave_compact(
+        dt.itemsize, n, p, rows, s, float(ask[0]),
+        float(ask[1]), float(ask[2]), n_dyn, int(has_static),
+        int(distinct), int(distinct_job_level),
+        *[addr(a) for a in f], addr(i32[0]), addr(b8[0]), addr(i32[1]),
+        addr(i32[2]), addr(b8[1]), addr(aff), addr(extra), addr(vidx),
+        n_patch, *[addr(a) for a in pt], addr(compact))
+    return compact, int(scanned)
 
 
 def _ptr(arr, ctype):

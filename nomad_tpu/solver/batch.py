@@ -812,60 +812,19 @@ def _resolve_lane_conflicts(lane, res, conflicted, accepted_own,
     chosen = np.array(res[0], copy=True)
     scores = np.array(res[1], copy=True)
     n_yielded = np.array(res[2], copy=True)
-    const, init = lane.const, lane.init
     order = np.asarray(lane.order)
-    pos_of = _scan_positions(lane, order)
-
-    used_cpu = np.array(init.used_cpu, copy=True)
-    used_mem = np.array(init.used_mem, copy=True)
-    used_disk = np.array(init.used_disk, copy=True)
-    dyn_avail = np.array(init.dyn_avail, copy=True)
-    for nid, f in ledger.items():
-        p = pos_of(nid)
-        if p is None:
-            continue
-        # re-derive this lane's view of the node from the joint ledger
-        # (caps are identical across lanes -- raw node resources minus
-        # reserved -- so cap - free is the joint used)
-        f = free_of(nid, f)
-        used_cpu[p] = float(const.cpu_cap[p]) - f[0]
-        used_mem[p] = float(const.mem_cap[p]) - f[1]
-        used_disk[p] = float(const.disk_cap[p]) - f[2]
-        dyn_avail[p] = f[3]
-    if foreign is not None:
-        u = lane.usage_index
-        for nid, d in foreign.deductions(
-                float("inf") if u is None else u).items():
-            p = None if nid in ledger else pos_of(nid)
-            if p is not None:
-                used_cpu[p] += d[0]
-                used_mem[p] += d[1]
-                used_disk[p] += d[2]
-                dyn_avail[p] -= d[3]
-    placed = np.array(init.placed, copy=True)
-    placed_job = np.array(init.placed_job, copy=True)
-    spread_counts = np.array(init.spread_counts, copy=True)
-    S = spread_counts.shape[0] if spread_counts.ndim else 0
-    for pos in accepted_own:
-        placed[pos] += 1
-        placed_job[pos] += 1
-        for s in range(S):
-            v = int(const.spread_vidx[s, pos])
-            if v >= 0:
-                spread_counts[s, v] += 1
-    new_init = init._replace(
-        used_cpu=used_cpu, used_mem=used_mem, used_disk=used_disk,
-        dyn_avail=dyn_avail, placed=placed, placed_job=placed_job,
-        spread_counts=spread_counts)
-
+    patch, spread_counts = _ledger_patch(
+        lane, _scan_positions(lane, order), ledger, free_of, foreign,
+        accepted_own)
     idx = np.asarray(conflicted, dtype=np.int64)
     sub_batch = jax.tree_util.tree_map(
         lambda a: np.asarray(a)[idx]
         if np.asarray(a).shape[:1] == (chosen.shape[0],) else a,
         lane.batch)
     c2, s2, y2 = solve_lane_fused(
-        const, new_init, sub_batch, spread_alg=lane.spread_alg,
-        dtype_name=lane.dtype_name, wave=True)
+        lane.const, lane.init._replace(spread_counts=spread_counts),
+        sub_batch, spread_alg=lane.spread_alg, dtype_name=lane.dtype_name,
+        wave=True, patch=patch)
     # Merge ONLY successful re-solves. A -1 re-solve means the ledger saw
     # no capacity -- but the ledger can be pessimistic (a consumer-only
     # lane's charge whose plan later gets rejected is never refunded), so
@@ -886,25 +845,86 @@ def _resolve_lane_conflicts(lane, res, conflicted, accepted_own,
     return (chosen, scores, n_yielded)
 
 
+def _ledger_patch(lane, pos_of, ledger, free_of, foreign, accepted_own):
+    """A re-solve's view of ``lane`` where it differs from the lane's
+    own init, as a WavePatch, and the spread counts: the ledger's
+    entries (each read through ``free_of``), the other barrier's
+    bookings on the nodes the ledger does not hold, and the lane's own
+    accepted placements (``accepted_own``, scan positions). Each value
+    is what writing it into a copy of the init's arrays would leave
+    there."""
+    from .binpack import WavePatch
+
+    const, init = lane.const, lane.init
+    used_cpu, used_mem = np.asarray(init.used_cpu), np.asarray(init.used_mem)
+    used_disk, dyn_avail = (np.asarray(init.used_disk),
+                            np.asarray(init.dyn_avail))
+    usage: Dict[int, tuple] = {}
+    for nid, f in ledger.items():
+        p = pos_of(nid)
+        if p is None:
+            continue
+        # re-derive this lane's view of the node from the joint ledger
+        # (caps are identical across lanes -- raw node resources minus
+        # reserved -- so cap - free is the joint used)
+        f = free_of(nid, f)
+        usage[p] = (float(const.cpu_cap[p]) - f[0],
+                    float(const.mem_cap[p]) - f[1],
+                    float(const.disk_cap[p]) - f[2], f[3])
+    if foreign is not None:
+        u = lane.usage_index
+        for nid, d in foreign.deductions(
+                float("inf") if u is None else u).items():
+            p = None if nid in ledger else pos_of(nid)
+            if p is not None:
+                usage[p] = (used_cpu[p] + d[0], used_mem[p] + d[1],
+                            used_disk[p] + d[2], dyn_avail[p] - d[3])
+    own: Dict[int, int] = {}
+    spread_counts = np.array(init.spread_counts, copy=True)
+    S = spread_counts.shape[0] if spread_counts.ndim else 0
+    for pos in accepted_own:
+        own[pos] = own.get(pos, 0) + 1
+        for s in range(S):
+            v = int(const.spread_vidx[s, pos])
+            if v >= 0:
+                spread_counts[s, v] += 1
+    pos = sorted(usage.keys() | own.keys())
+    rows = [usage[p] if p in usage
+            else (used_cpu[p], used_mem[p], used_disk[p], dyn_avail[p])
+            for p in pos]
+    placed, placed_job = np.asarray(init.placed), np.asarray(init.placed_job)
+    patch = WavePatch(
+        pos=np.array(pos, dtype=np.int64),
+        used_cpu=np.array([r[0] for r in rows], dtype=used_cpu.dtype),
+        used_mem=np.array([r[1] for r in rows], dtype=used_mem.dtype),
+        used_disk=np.array([r[2] for r in rows], dtype=used_disk.dtype),
+        dyn_avail=np.array([r[3] for r in rows], dtype=dyn_avail.dtype),
+        placed=np.array([placed[p] + own.get(p, 0) for p in pos],
+                        dtype=placed.dtype),
+        placed_job=np.array([placed_job[p] + own.get(p, 0) for p in pos],
+                            dtype=placed_job.dtype))
+    return patch, spread_counts
+
+
 def _scan_positions(lane, order):
     """node id -> the node's position in ``lane``'s scan order, or None.
     By way of the lane's node matrix, whose id index every lane and
-    generation of one node table shares (a walk over 10,000 nodes a
-    conflicted lane otherwise)."""
+    generation of one node table shares, and the inverse of the order
+    its pack made (a walk over 10,000 nodes a conflicted lane
+    otherwise)."""
     matrix = lane.matrix
-    if matrix is None:
+    if matrix is None or lane.scan_pos is None:
         pos = {lane.nodes[i].id: p for p, i in enumerate(order.tolist())}
         return pos.get
     index = matrix.__dict__.get("_pos_index")
     if index is None:
         index = {nid: i for i, nid in enumerate(matrix.node_ids)}
         matrix._pos_index = index
-    inv = np.full(len(matrix.node_ids), -1, dtype=np.int64)
-    inv[order] = np.arange(order.shape[0])
+    inv, n = lane.scan_pos, order.shape[0]
 
     def pos_of(nid):
         i = index.get(nid)
-        if i is None or inv[i] < 0:
+        if i is None or i >= n:
             return None
         return int(inv[i])
     return pos_of
@@ -1146,7 +1166,13 @@ class SolveBarrier:
         if bookings is None:
             self._run_fixpoint(gen, gctx, lanes, results, None)
             return
+        t_wait = time.time()
         with bookings.fixpoint_lock:
+            # the wait for the other barrier's section: the lock's hold
+            # time as this barrier meets it
+            tracer.record("solver.fixpoint_lock_wait", t_wait,
+                          (time.time() - t_wait) * 1e3, ctx=gctx,
+                          generation=gen)
             charged = self._run_fixpoint(gen, gctx, lanes, results,
                                          bookings.foreign(self))
             for i, lane in enumerate(lanes):

@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import jitcheck
+from .. import jitcheck, native
+from ..server.telemetry import metrics
 from . import stages
 
 
@@ -1080,12 +1081,12 @@ def _make_fused_fn(metas, treedef, group_keys, spread_alg: bool,
 def solve_lane_fused(const, init, batch, ptab=None, pinit=None, *,
                      spread_alg: bool, dtype_name: str,
                      batched: bool = False, wave: bool = False,
-                     cache_version=None, delta_src=None):
+                     cache_version=None, delta_src=None, patch=None):
     """Solve with minimal transfers: returns host-side numpy
     (chosen int64, scores, n_yielded int64[, evict_rows]). When ``batched``
     every leaf carries a leading eval axis and outputs do too. ``wave``
     routes through the wavefront path (caller must have checked
-    eligibility): host-side O(N) precompute + compact-table device scan
+    eligibility): host-side precompute + compact-table device scan
     (solve_lane_wave). Stacking chosen/n_yielded through the score dtype
     is exact: node indexes and yield counts are < 2^24. ``cache_version``
     tags const-tree buffers in the device-resident cache with the
@@ -1093,7 +1094,14 @@ def solve_lane_fused(const, init, batch, ptab=None, pinit=None, *,
     ``delta_src`` is that snapshot's (store, index) pair for the
     ISSUE-20 version chain -- journal-covered generations ship only
     their diff and scatter it into the resident buffers on device. The
-    wave transports ride it; the whole-axis branch below ships whole."""
+    wave transports ride it; the whole-axis branch below ships whole.
+    ``patch`` (a WavePatch, one wave lane) is a fixpoint re-solve's
+    usage where it differs from ``init``."""
+    if patch is not None:
+        return _solve_lane_wave(const, init, batch, patch,
+                                spread_alg=spread_alg, dtype_name=dtype_name,
+                                batched=False, cache_version=cache_version,
+                                delta_src=delta_src)
     if wave and ptab is None:
         return solve_lane_wave(const, init, batch, spread_alg=spread_alg,
                                dtype_name=dtype_name, batched=batched,
@@ -1485,12 +1493,14 @@ solve_system = functools.partial(
         _solve_system_impl)
 
 
-# -- compact wavefront: host-side O(N) precompute, device-side scan --------
+# -- compact wavefront: host-side precompute, device-side scan ------------
 #
 # The wavefront scan only ever reads the first C = P + B fit-order rows, so
-# the O(N) precompute (capacity fold + fit-order compress + row gather) runs
-# on the HOST in numpy and only the compact (C, 8) table crosses the
-# host->device boundary: ~65KB/lane instead of ~0.5MB of N-sized tables.
+# the precompute (capacity fold + fit-order compress + row gather) runs on
+# the HOST, in one native call that stops at the C-th node with room
+# (wave_lane_compact; numpy's wavefront_compact_host is its oracle), and
+# only the compact (C, 8) table crosses the host->device boundary:
+# ~65KB/lane instead of ~0.5MB of N-sized tables.
 # It cuts per-dispatch link bytes and HBM traffic E-fold in fused batches.
 # The float predicates here MUST mirror _solve_wavefront_impl / the dense
 # kernel op-for-op (IEEE ops agree between numpy and XLA) so placements
@@ -1509,7 +1519,7 @@ def wavefront_buffer_size(limit: int) -> Optional[int]:
 
 # shared with service._wave_devices_ok's eligibility bound: a lane passes
 # the wave gate ONLY if the capacity replay provably terminates within
-# this many steps, so wavefront_compact_host can assert the replay
+# this many steps, so the wave precompute can assert the replay
 # succeeded rather than silently skipping the device clamp
 WAVE_DEVICE_CAP_STEPS = 1024
 
@@ -1570,7 +1580,9 @@ def wavefront_compact_host(const, init, batch, dtype_name: str,
     pos(sentinel -1), then one spread value-index column per spread.
     ``p_pad`` grows the output axis (C = p_pad + B) so many lane sizes
     share one compiled variant; the padded steps are inert (beyond
-    n_active) and callers slice outputs."""
+    n_active) and callers slice outputs. The oracle of the native
+    precompute (wave_lane_compact), and its stand-in where the library
+    cannot load."""
     dt = np.dtype(dtype_name)
     P = int(np.asarray(batch.ask_cpu).shape[0])
     P_out = max(P, p_pad or 0)
@@ -1580,9 +1592,6 @@ def wavefront_compact_host(const, init, batch, dtype_name: str,
     ask_disk = np.asarray(batch.ask_disk, dtype=dt)[0]
     n_dyn = int(np.asarray(batch.n_dyn_ports)[0])
     has_static = bool(np.asarray(batch.has_static)[0])
-    count = np.asarray(batch.count, dtype=dt)[0]
-    L = int(np.asarray(batch.limit)[0])
-    n_active = int(np.asarray(batch.active).sum())
 
     BIG = np.int64(2 ** 30)
     cpu_cap = np.asarray(const.cpu_cap, dtype=dt)
@@ -1639,6 +1648,8 @@ def wavefront_compact_host(const, init, batch, dtype_name: str,
            else np.zeros(N, dtype=dt))
 
     S = int(np.asarray(const.spread_vidx).shape[0])
+    # only the first C nodes with room are ever read: nt_wave_compact
+    # stops its walk there
     fit_pos = np.nonzero(c > 0)[0][:P_out + B]
     C = P_out + B
     compact = np.zeros((C, 8 + S), dtype=dt)
@@ -1657,17 +1668,98 @@ def wavefront_compact_host(const, init, batch, dtype_name: str,
     if S:
         compact[:k, 8:] = np.asarray(
             const.spread_vidx)[:, fit_pos].T.astype(dt)
-    scal_f = np.array([ask_cpu, ask_mem, count], dtype=dt)
-    scal_i = np.array([L, n_active], dtype=np.int32)
+    return (compact, *_wave_lane_tables(const, init, batch, dt, P_out))
+
+
+def _wave_lane_tables(const, init, batch, dt, P_out: int):
+    """The small tables of a wave lane beside its compact table: scal_f
+    (ask_cpu, ask_mem, count), scal_i (limit, active placements), the
+    penalty rows padded to ``P_out``, and the spread tables."""
+    scal_f = np.array([np.asarray(batch.ask_cpu, dtype=dt)[0],
+                       np.asarray(batch.ask_mem, dtype=dt)[0],
+                       np.asarray(batch.count, dtype=dt)[0]], dtype=dt)
+    scal_i = np.array([int(np.asarray(batch.limit)[0]),
+                       int(np.asarray(batch.active).sum())], dtype=np.int32)
     pen = np.full(P_out, -1, dtype=np.int32)
-    pen[:P] = np.asarray(batch.penalty_idx, dtype=np.int32)
+    pen[:np.asarray(batch.ask_cpu).shape[0]] = np.asarray(
+        batch.penalty_idx, dtype=np.int32)
     sp = _WaveSpread(
         counts=np.asarray(init.spread_counts, dtype=np.int32),
         desired=np.asarray(const.spread_desired, dtype=dt),
         has_targets=np.asarray(const.spread_has_targets, dtype=bool),
         weights=np.asarray(const.spread_weights, dtype=dt),
         sum_weights=np.asarray(const.spread_sum_weights, dtype=dt))
-    return compact, scal_f, scal_i, pen, sp
+    return scal_f, scal_i, pen, sp
+
+
+class WavePatch(NamedTuple):
+    """A lane's usage as a fixpoint re-solve sees it, where that differs
+    from the lane's own init: ascending scan positions and, at each, the
+    six per-node usage fields (in init's dtypes). The native precompute
+    reads it beside the init; nothing copies the node axis."""
+
+    pos: np.ndarray          # (K,) int64, ascending
+    used_cpu: np.ndarray
+    used_mem: np.ndarray
+    used_disk: np.ndarray
+    dyn_avail: np.ndarray
+    placed: np.ndarray
+    placed_job: np.ndarray
+
+    def applied_to(self, init):
+        """``init`` with the patch written over copies of its arrays."""
+        fields = {}
+        for name in WavePatch._fields[1:]:
+            a = np.array(getattr(init, name), copy=True)
+            a[self.pos] = getattr(self, name)
+            fields[name] = a
+        return init._replace(**fields)
+
+
+def wave_lane_compact(const, init, batch, dtype_name: str,
+                      p_pad: Optional[int] = None, B: int = WAVE_B,
+                      patch: Optional[WavePatch] = None):
+    """wavefront_compact_host's five outputs, the compact table built by
+    one native call (native.wave_compact: the interpreter released once,
+    the walk stopped at the last node with room that the table holds)
+    over ``init`` with ``patch`` laid over it. Where the library cannot
+    load, wavefront_compact_host builds them, and
+    ``nomad.solver.wave_precompute_fallbacks`` counts it."""
+    if native.load() is None:
+        metrics.incr("nomad.solver.wave_precompute_fallbacks")
+        if patch is not None:
+            init = patch.applied_to(init)
+        return wavefront_compact_host(const, init, batch, dtype_name,
+                                      p_pad=p_pad, B=B)
+    dt = np.dtype(dtype_name)
+    P = int(np.asarray(batch.ask_cpu).shape[0])
+    P_out = max(P, p_pad or 0)
+    c_dev = None
+    if np.asarray(const.dev_aff).shape[0]:
+        c_dev = _wave_device_capacity(const, init)
+        # as in wavefront_compact_host: wavefront_ok admits device lanes
+        # only when the replay bound holds
+        assert c_dev is not None, "unbounded device capacity replay"
+    distinct_job_level = bool(np.asarray(const.distinct_job_level))
+    compact, scanned = native.wave_compact(
+        dt, P, P_out + B,
+        (np.asarray(batch.ask_cpu, dtype=dt)[0],
+         np.asarray(batch.ask_mem, dtype=dt)[0],
+         np.asarray(batch.ask_disk, dtype=dt)[0]),
+        int(np.asarray(batch.n_dyn_ports)[0]),
+        bool(np.asarray(batch.has_static)[0]),
+        bool(np.asarray(const.distinct_hosts)), distinct_job_level,
+        (const.cpu_cap, const.mem_cap, const.disk_cap),
+        (init.used_cpu, init.used_mem, init.used_disk),
+        init.dyn_avail, init.static_free, init.placed, init.placed_job,
+        const.feasible,
+        const.affinity if bool(np.asarray(const.has_affinity)) else None,
+        c_dev, const.spread_vidx, patch=patch)
+    n_nodes = int(np.asarray(const.cpu_cap).shape[0])
+    metrics.incr("nomad.solver.wave_precomputes")
+    metrics.incr("nomad.solver.wave_precompute_nodes_scanned", scanned)
+    metrics.incr("nomad.solver.wave_precompute_nodes_total", n_nodes)
+    return (compact, *_wave_lane_tables(const, init, batch, dt, P_out))
 
 
 def _solve_wave_compact_impl(compact, scal_f, scal_i, pen, sp=None,
@@ -2785,6 +2877,17 @@ def solve_lane_wave(const, init, batch, *, spread_alg: bool,
     solve_lane_fused's non-preempt outputs. The slot-buffer width B is
     picked from the lane's limit (WAVE_B for log2 windows, WAVE_B_WIDE
     for spread/affinity windows); callers guarantee it fits."""
+    return _solve_lane_wave(const, init, batch, None, spread_alg=spread_alg,
+                            dtype_name=dtype_name, batched=batched,
+                            cache_version=cache_version,
+                            delta_src=delta_src)
+
+
+def _solve_lane_wave(const, init, batch, patch: Optional[WavePatch], *,
+                     spread_alg: bool, dtype_name: str, batched: bool,
+                     cache_version, delta_src):
+    """solve_lane_wave over ``init`` with a fixpoint re-solve's
+    ``patch`` laid over it (one lane, not batched)."""
     if batched:
         E = np.asarray(batch.ask_cpu).shape[0]
         P = int(np.asarray(batch.ask_cpu).shape[1])
@@ -2808,7 +2911,7 @@ def solve_lane_wave(const, init, batch, *, spread_alg: bool,
         active_rows = np.asarray(batch.active).any(axis=1)
 
         def pack_one(e):
-            return wavefront_compact_host(
+            return wave_lane_compact(
                 jax.tree_util.tree_map(lambda a: a[e], const),
                 jax.tree_util.tree_map(lambda a: a[e], init),
                 jax.tree_util.tree_map(lambda a: a[e], batch),
@@ -2838,8 +2941,8 @@ def solve_lane_wave(const, init, batch, *, spread_alg: bool,
                              "buffer width (caller must gate on "
                              "wavefront_ok)")
         p_pad = _wave_p_bucket(P)
-        compact, scal_f, scal_i, pen, sp = wavefront_compact_host(
-            const, init, batch, dtype_name, p_pad=p_pad, B=B)
+        compact, scal_f, scal_i, pen, sp = wave_lane_compact(
+            const, init, batch, dtype_name, p_pad=p_pad, B=B, patch=patch)
 
     # zero-size spread tables flow through uniformly: the kernel skips
     # spread work statically when S == 0. Lanes with no spreads and no

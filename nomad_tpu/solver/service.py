@@ -60,12 +60,12 @@ class PackedLane:
     __slots__ = ("service", "tg", "places", "nodes", "order", "const",
                  "init", "batch", "dtype_name", "spread_alg", "ptab",
                  "pinit", "cand_allocs", "table_version", "matrix",
-                 "delta_src", "usage_index", "_wave")
+                 "delta_src", "usage_index", "scan_pos", "_wave")
 
     def __init__(self, service, tg, places, nodes, order, const, init,
                  batch, dtype_name, spread_alg, ptab=None, pinit=None,
                  cand_allocs=None, table_version=None, matrix=None,
-                 delta_src=None, usage_index=None):
+                 delta_src=None, usage_index=None, scan_pos=None):
         self.service = service
         self.tg = tg
         self.places = places
@@ -100,6 +100,10 @@ class PackedLane:
         # (server/inflight.py); None (a lane built by hand) = all that
         # have committed
         self.usage_index = usage_index
+        # scan_pos[i]: the scan position of node i (of ``matrix``'s node
+        # axis), the inverse of ``order`` that pack makes anyway; the
+        # fixpoint's re-solves read their ledger's nodes through it
+        self.scan_pos = scan_pos
         self._wave = None
 
     def wavefront_ok(self) -> bool:
@@ -646,7 +650,7 @@ class TpuPlacementService:
                           matrix=matrix,
                           delta_src=(delta_store, state_index)
                           if delta_store is not None else None,
-                          usage_index=usage_index)
+                          usage_index=usage_index, scan_pos=inv)
 
     @staticmethod
     def _cands_hold_matching_devices(requests, cand_allocs, ptab) -> bool:

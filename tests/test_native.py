@@ -28,6 +28,8 @@ KERNEL_PARITY_TESTS = {
         "tests/test_native_oracle.py::test_fresh_heterogeneous_fleet",
     "nt_verify_plan":
         "tests/test_native.py::test_verify_plan_matches_numpy",
+    "nt_wave_compact":
+        "tests/test_wave_precompute.py::test_native_precompute_matches_numpy",
     "nt_abi_version":
         "tests/test_native.py::test_native_abi_version_matches",
 }
