@@ -364,7 +364,7 @@ def phase_parity(seed: int, n_nodes: int = FLEET_NODES,
 
 def _world(seed: int, n_nodes: int, utilization: float, preemption: bool):
     from nomad_tpu import mock
-    from nomad_tpu.benchkit import make_fleet, seed_utilization
+    from nomad_tpu.benchkit import hold_gpus, make_fleet, seed_utilization
     from nomad_tpu.scheduler import Harness
     from nomad_tpu.structs import PreemptionConfig, SchedulerConfiguration
 
@@ -380,6 +380,10 @@ def _world(seed: int, n_nodes: int, utilization: float, preemption: bool):
     nodes = make_fleet(rng, h, n_nodes, gpus=True)
     seed_utilization(rng, h, nodes, utilization,
                      priorities=(10, 20, 30, 40) if preemption else (50,))
+    if preemption:
+        # every GPU held by the fillers: a GPU ask fits no node without
+        # eviction, so it takes the windowed preemption search
+        hold_gpus(rng, h, nodes)
     return h, rng
 
 
@@ -417,8 +421,8 @@ def phase_programs(seed: int, n_nodes: int = FLEET_NODES,
     once at fleet width through the scheduler: spread + affinity (the
     wide-window compact wave: the window is max(count, 100) and the
     wide buffer holds 128), distinct_property + scored device asks
-    (dense), a system job, and on a 95%-utilised fleet with device
-    reservations the windowed and the dense preemption search."""
+    (dense), a system job, and on a 95%-utilised fleet whose fillers
+    hold every GPU the windowed and the dense preemption search."""
     from nomad_tpu import mock
     from nomad_tpu.benchkit import tier_job
     from nomad_tpu.structs import (

@@ -19,6 +19,9 @@ from ..structs import (
     SpreadTarget, Task, TaskGroup, UpdateStrategy, Affinity,
     ParameterizedJobConfig, PeriodicConfig,
 )
+from ..structs.config import PreemptionConfig
+from ..structs.job import MigrateStrategy
+from ..structs.resources import DeviceRequest
 
 
 def _thread_stacks():
@@ -86,14 +89,26 @@ def to_jsonable(obj):
     return obj
 
 
+def build(cls, src, **overrides):
+    """A dataclass from the JSON object's keys it has fields for."""
+    fields = {f.name for f in dataclasses.fields(cls)}
+    kwargs = {k: v for k, v in (src or {}).items() if k in fields}
+    kwargs.update(overrides)
+    return cls(**kwargs)
+
+
 def job_from_json(data: dict) -> Job:
     """Parse the JSON jobspec (the reference's api.Job JSON shape,
     snake_cased; jobspec2 HCL parsing maps to the same structure)."""
-    def build(cls, src, **overrides):
-        fields = {f.name for f in dataclasses.fields(cls)}
-        kwargs = {k: v for k, v in (src or {}).items() if k in fields}
-        kwargs.update(overrides)
-        return cls(**kwargs)
+
+    def migrate_of(tg_src):
+        """The group's migrate stanza over the job's; a service job's
+        group without either takes the default strategy (max_parallel
+        1), as Nomad's api.TaskGroup.Canonicalize gives it."""
+        src = {**(data.get("migrate") or {}), **(tg_src.get("migrate") or {})}
+        if not src and data.get("type", "service") != "service":
+            return None
+        return build(MigrateStrategy, src)
 
     tgs = []
     for tg_src in data.get("task_groups", []):
@@ -107,8 +122,15 @@ def job_from_json(data: dict) -> Job:
                       dynamic_ports=[build(Port, p) for p in
                                      n.get("dynamic_ports", [])])
                 for n in res_src.get("networks", [])]
+            devices = [
+                build(DeviceRequest, d,
+                      constraints=[build(Constraint, c)
+                                   for c in d.get("constraints", [])],
+                      affinities=[build(Affinity, a)
+                                  for a in d.get("affinities", [])])
+                for d in res_src.get("devices", [])]
             resources = build(Resources, res_src, networks=networks,
-                              devices=[])
+                              devices=devices)
             tasks.append(build(
                 Task, t_src, resources=resources,
                 constraints=[build(Constraint, c)
@@ -145,7 +167,8 @@ def job_from_json(data: dict) -> Job:
                                if tg_src.get("reschedule_policy") else None),
             ephemeral_disk=build(EphemeralDisk,
                                  tg_src.get("ephemeral_disk", {})),
-            volumes={}, scaling=tg_src.get("scaling"), migrate=None)
+            volumes={}, scaling=tg_src.get("scaling"),
+            migrate=migrate_of(tg_src))
         tgs.append(tg)
     job = Job(
         id=data.get("id", ""),
@@ -186,6 +209,10 @@ def job_from_json(data: dict) -> Job:
 class ApiHandler(BaseHTTPRequestHandler):
     server_version = "nomad-tpu/0.1"
     protocol_version = "HTTP/1.1"
+    # a response goes out as two writes, its headers then its body: with
+    # Nagle's algorithm on a kept-alive connection the body waits for the
+    # client's delayed ACK of the headers, 40 ms a request on Linux
+    disable_nagle_algorithm = True
 
     # quiet logs
     def log_message(self, fmt, *args):
@@ -1743,6 +1770,10 @@ class ApiHandler(BaseHTTPRequestHandler):
                 cfg = SchedulerConfiguration(
                     scheduler_algorithm=body.get("scheduler_algorithm",
                                                  "binpack"),
+                    preemption_config=(
+                        build(PreemptionConfig, body["preemption_config"])
+                        if body.get("preemption_config") is not None
+                        else PreemptionConfig()),
                     memory_oversubscription_enabled=body.get(
                         "memory_oversubscription_enabled", False),
                     pause_eval_broker=bool(body.get("pause_eval_broker",

@@ -631,6 +631,34 @@ def seed_utilization(rng: random.Random, h, nodes, frac: float,
             used += 500
 
 
+def hold_gpus(rng: random.Random, h, nodes) -> None:
+    """Every GPU instance of the fleet held by the fillers on its node,
+    1 or 2 instances a holder drawn from the seed, each holder's job
+    asking for what it holds: a GPU ask then finds no free instance, and
+    device preemption decides where it goes."""
+    from .structs.resources import AllocatedDeviceResource, DeviceRequest
+    for node in nodes:
+        for group in node.node_resources.devices:
+            free = list(group.instance_ids)
+            allocs = list(h.state.allocs_by_node(node.id))
+            rng.shuffle(allocs)
+            held = []
+            for a in allocs:
+                if not free:
+                    break
+                k = min(len(free), rng.choice([1, 2]))
+                a = a.copy_skip_job()
+                take, free = free[:k], free[k:]
+                task = next(iter(a.allocated_resources.tasks.values()))
+                task.devices = [AllocatedDeviceResource(
+                    vendor=group.vendor, type=group.type, name=group.name,
+                    device_ids=take)]
+                a.job.task_groups[0].tasks[0].resources.devices = [
+                    DeviceRequest(name="nvidia/gpu", count=k)]
+                held.append(a)
+            h.state.upsert_allocs(held)
+
+
 def tier_job(tier: int, rng: random.Random, count: int):
     """The job each tier schedules."""
     job = mock.job(type="batch" if tier == 2 else "service")
@@ -687,10 +715,12 @@ def tier_job(tier: int, rng: random.Random, count: int):
 
 def run_tier_placements(tier: int, n_nodes: int, count: int, seed: int,
                         alg: str, spread_variant: bool = False,
-                        with_evictions: bool = False):
+                        with_evictions: bool = False,
+                        gpu_holders: bool = False):
     """Build one world, schedule one tier-shaped eval with the given
     algorithm, return {alloc name -> node id} (plus, with_evictions,
-    {alloc name -> sorted evicted alloc names})."""
+    {alloc name -> sorted evicted alloc names}). With ``gpu_holders``
+    the tier-5 fillers hold every GPU (hold_gpus)."""
     from .scheduler import Harness
 
     rng = random.Random(seed)
@@ -704,6 +734,8 @@ def run_tier_placements(tier: int, n_nodes: int, count: int, seed: int,
     nodes = make_fleet(rng, h, n_nodes, gpus=(tier == 5))
     if tier == 5:
         seed_utilization(rng, h, nodes, 0.95, priorities=(10, 20, 30, 40))
+        if gpu_holders:
+            hold_gpus(rng, h, nodes)
     elif tier in (3, 4):
         seed_utilization(rng, h, nodes, 0.25)
 

@@ -129,8 +129,8 @@ class EvalContext:
         self.events.append(event)
 
     def proposed_allocs(self, node_id: str) -> List[Allocation]:
-        """Existing non-client-terminal allocs on the node, minus plan stops
-        and preemptions, plus plan placements (reference: context.go:176
+        """Existing allocs on the node that are neither client-terminal
+        nor evicted, minus plan stops and preemptions, plus plan placements (reference: context.go:176
         EvalContext.ProposedAllocs). Preserves insertion order so the scan
         is deterministic (the reference materializes from a map; our
         deterministic order is a superset contract the TPU path shares)."""
@@ -146,7 +146,7 @@ class EvalContext:
         for alloc in existing:
             if alloc.id in removed:
                 continue
-            if alloc.client_terminal_status():
+            if alloc.released_from_node():
                 continue
             by_id[alloc.id] = alloc
         for alloc in self.plan.node_allocation.get(node_id, ()):

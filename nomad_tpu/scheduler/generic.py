@@ -314,11 +314,10 @@ class GenericScheduler:
                     if node is not None and node.ready():
                         preferred = [node]
 
-            option = self.stack.select(tg, SelectOptions(
+            option = self._select_next_option(tg, SelectOptions(
                 penalty_node_ids=penalty,
                 preferred_nodes=preferred,
-                alloc_name=place.name,
-                preempt=self._preemption_enabled()))
+                alloc_name=place.name))
 
             if option is None:
                 # Failed placement: record metrics, coalesce
@@ -373,11 +372,29 @@ class GenericScheduler:
                         prev_node_id=prev.node_id))
                     alloc.reschedule_tracker = tracker
 
-            if option.preempted_allocs:
-                for p in option.preempted_allocs:
-                    self.plan.append_preempted_alloc(p, alloc.id)
-
+            self._append_preemptions(option.preempted_allocs, alloc.id)
             self.plan.append_alloc(alloc)
+
+    def _select_next_option(self, tg, options: SelectOptions):
+        """A placement selected with eviction off, and selected again
+        with it on only where no node is an option and the job's
+        scheduler may preempt (reference: generic_sched.go
+        selectNextOption)."""
+        option = self.stack.select(tg, options)
+        if option is None and self._preemption_enabled():
+            options.preempt = True
+            option = self.stack.select(tg, options)
+        return option
+
+    def _append_preemptions(self, preempted, alloc_id: str) -> None:
+        """The allocs one placement evicts, into the plan, counted."""
+        if not preempted:
+            return
+        from ..server.telemetry import metrics as _tm
+        _tm.incr("nomad.solver.evicting_placements")
+        _tm.incr("nomad.solver.evicted_allocs", len(preempted))
+        for p in preempted:
+            self.plan.append_preempted_alloc(p, alloc_id)
 
     def _deployment_id(self) -> str:
         """Placements attach to the active deployment of the CURRENT job
@@ -579,9 +596,7 @@ class GenericScheduler:
                     prev_alloc_id=prev.id,
                     prev_node_id=prev.node_id))
                 alloc.reschedule_tracker = tracker
-        if sp.preempted_allocs:
-            for p in sp.preempted_allocs:
-                self.plan.append_preempted_alloc(p, alloc.id)
+        self._append_preemptions(sp.preempted_allocs, alloc.id)
         self.plan.append_alloc(alloc)
 
     def _preemption_enabled(self) -> bool:

@@ -216,49 +216,53 @@ class Preemptor:
     # -- device path (reference: PreemptForDevice) --------------------------
     def preempt_for_device(self, req, dev_allocator
                            ) -> Optional[List[Allocation]]:
-        """Free device instances by preempting holders; chooses the option
-        with minimal net priority (reference: preemption.go:475-558)."""
-        # Map device group -> allocs holding instances of it
-        holders: Dict[str, List[Tuple[Allocation, int]]] = {}
+        """Free device instances by preempting holders (reference:
+        preemption.go PreemptForDevice + selectBestAllocs): per matching
+        device group, its eligible holders priority group by priority
+        group, lowest first and in candidate order within one, until the
+        instances they hold plus the group's free ones meet the ask;
+        then, per such option, the holders by instances held, most
+        first, until they hold the ask's count. The option whose
+        distinct priorities sum least wins, the first among equals."""
+        held_by: Dict[str, Dict[str, int]] = {}
         for alloc in self.current_allocs:
-            if alloc.job is None:
-                continue
-            if self.job_priority - alloc.job.priority < 10:
-                continue
             for tr in alloc.allocated_resources.tasks.values():
                 for dev in tr.devices:
-                    holders.setdefault(dev.id_string(), []).append(
-                        (alloc, len(dev.device_ids)))
+                    per = held_by.setdefault(dev.id_string(), {})
+                    per[alloc.id] = per.get(alloc.id, 0) + len(
+                        dev.device_ids)
 
         best_option: Optional[List[Allocation]] = None
         best_net_priority = math.inf
         for group in self.node.node_resources.devices:
             if not group.matches_request(req.name):
                 continue
-            entries = holders.get(group.id_string(), [])
-            if not entries:
-                continue
-            free = len(group.instance_ids) - sum(
-                n for _, n in entries)
-            needed = req.count - max(free, 0)
-            if needed <= 0:
-                continue
-            # Sort holders by instance count descending, take until covered
-            entries = sorted(entries, key=lambda e: -e[1])
+            key = group.id_string()
+            held = held_by.get(key, {})
+            holders = [a for a in self.current_allocs if a.id in held]
+            used = dev_allocator.used.get(key, set())
+            free = sum(1 for i in group.instance_ids if i not in used)
+            chosen: List[Allocation] = []
             covered = 0
-            option: List[Allocation] = []
+            for _prio, allocs in filter_and_group_preemptible(
+                    self.job_priority, holders):
+                for alloc in allocs:
+                    if covered + free >= req.count:
+                        break
+                    chosen.append(alloc)
+                    covered += held[alloc.id]
+            if not chosen or covered + free < req.count:
+                continue
+            taken: List[Allocation] = []
             priorities = set()
-            net_prio = 0
-            for alloc, n in entries:
-                if covered >= needed:
+            count = 0
+            for alloc in sorted(chosen, key=lambda a: -held[a.id]):
+                if count >= req.count:
                     break
-                covered += n
-                option.append(alloc)
-                p = alloc.job.priority
-                if p not in priorities:
-                    priorities.add(p)
-                    net_prio += p
-            if covered >= needed and net_prio < best_net_priority:
-                best_net_priority = net_prio
-                best_option = option
+                taken.append(alloc)
+                count += held[alloc.id]
+                priorities.add(alloc.job.priority)
+            if sum(priorities) < best_net_priority:
+                best_net_priority = sum(priorities)
+                best_option = taken
         return best_option

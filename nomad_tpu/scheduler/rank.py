@@ -89,6 +89,14 @@ class DeviceAllocator:
                     self.used.setdefault(dev.id_string(), set()).update(
                         dev.device_ids)
 
+    def release_allocs(self, allocs: List[Allocation]) -> None:
+        """Give back the instances evicted allocs hold."""
+        for alloc in allocs:
+            for tr in alloc.allocated_resources.tasks.values():
+                for dev in tr.devices:
+                    self.used.get(dev.id_string(), set()).difference_update(
+                        dev.device_ids)
+
     def add_reserved(self, offer: AllocatedDeviceResource) -> None:
         self.used.setdefault(offer.id_string(), set()).update(offer.device_ids)
 
@@ -383,15 +391,9 @@ class BinPackIterator(RankIterator):
                     self.ctx.metrics.exhausted_node(
                         option.node.id, option.node.computed_class, dim)
                     continue
-                # util after preemption: recompute from remaining + ghost
-                removed = {a.id for a in allocs_to_preempt}
-                remaining = [a for a in current if a.id not in removed] + [ghost]
-                fit2, _, util = allocs_fit(option.node, remaining, None,
-                                           check_devices=False)
-                if not fit2:
-                    self.ctx.metrics.exhausted_node(
-                        option.node.id, option.node.computed_class, dim)
-                    continue
+                # the option stands, scored on AllocsFit's utilisation
+                # before the evictions (rank.go: `util` is not taken
+                # again), whether or not the usage after them fits
             if allocs_to_preempt:
                 option.preempted_allocs = allocs_to_preempt
 

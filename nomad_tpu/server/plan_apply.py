@@ -635,8 +635,7 @@ class Planner:
         result = PlanResult(
             node_update={k: list(v) for k, v in plan.node_update.items()},
             node_allocation={},
-            node_preemptions={k: list(v)
-                              for k, v in plan.node_preemptions.items()},
+            node_preemptions={},
             deployment=plan.deployment,
             deployment_updates=list(plan.deployment_updates),
         )
@@ -682,14 +681,45 @@ class Planner:
                     plan.node_allocation[node_id])
             else:
                 rejected.append(node_id)
+        if plan.node_preemptions:
+            with tracer.span("plan.verify_preemptions",
+                             nodes=len(plan.node_preemptions)):
+                result.node_preemptions = self._verified_preemptions(
+                    snapshot, plan, set(rejected))
 
         if rejected and plan.all_at_once:
             # all-or-nothing (reference: evaluatePlan AllAtOnce handling)
             result.node_allocation = {}
+            result.node_preemptions = {}
             result.deployment = None
             result.deployment_updates = []
         result.rejected_nodes = rejected
         return result
+
+    @staticmethod
+    def _verified_preemptions(snapshot, plan: Plan, rejected: set
+                              ) -> Dict[str, List[Allocation]]:
+        """The plan's evictions that commit (reference: plan_apply.go
+        evaluatePlanPlacements): none on a refused node, whose
+        placements asked for them and do not commit, and none of an
+        alloc already terminal in the verified view (an earlier plan's
+        eviction or stop, committed or in flight), whose record the
+        first one keeps."""
+        base = getattr(snapshot, "_snap", snapshot)
+        gone = getattr(snapshot, "_removed", frozenset())
+        out: Dict[str, List[Allocation]] = {}
+        for node_id, allocs in plan.node_preemptions.items():
+            if node_id in rejected:
+                continue
+            keep = []
+            for a in allocs:
+                stored = base.alloc_by_id(a.id)
+                if (stored is not None and a.id not in gone
+                        and not stored.terminal_status()):
+                    keep.append(a)
+            if keep:
+                out[node_id] = keep
+        return out
 
     @staticmethod
     def _alloc_special(a) -> bool:

@@ -376,10 +376,11 @@ def _fuse_group(lanes: List[PackedLane], idxs: List[int], key: tuple,
     # axis up to the widest lane's steps: a padded lane is inert work
     # on a device that the tight bucket would have lent to the node
     # axis (PERF.md section 6, PR 30, has both on four chips). A
-    # preempting group still scans every padded step over (N, A) tables
-    # and keeps the tight buckets
-    whole_axis = not wave and A == 0
-    if e_pad_hint and (wave or whole_axis):
+    # preempting group runs its lanes in turn on one device too, so
+    # every lane count meets the one program its windowed twin warmed
+    # (_warm_whole_axis_twin)
+    whole_axis = not wave
+    if e_pad_hint:
         e_pad = max(e_pad, _e_bucket(min(e_pad_hint, E_BUCKETS[-1])))
     # floor of 32: many lane sizes share one compiled variant (an
     # inert padded step costs ~us; a fresh XLA compile costs seconds)
@@ -557,8 +558,7 @@ def fuse_and_solve(lanes: List[PackedLane], use_mesh: bool = True,
     an inert wave lane costs only O(B*P) padded compute. Whole-axis
     groups are pinned alike: no padded step is run, and a padded lane
     is skipped on one device and inert beside the real ones on a mesh
-    (_fuse_group). Only preempting whole-axis groups keep the tight
-    bucket -- their padding costs O(N*A*P) per lane.
+    (_fuse_group), preempting ones included, which run on one device.
 
     ``staged`` carries groups pre-filled by the pipeline's prepare stage
     (fuse_lanes run while the previous generation was in flight) so the
@@ -567,6 +567,33 @@ def fuse_and_solve(lanes: List[PackedLane], use_mesh: bool = True,
     if groups is None:
         groups = fuse_lanes(lanes, e_pad_hint)
     return solve_groups(lanes, groups, use_mesh=use_mesh)
+
+
+# the shapes whose whole-axis preemption program is warm
+_TWINS_WARM: set = set()
+_TWINS_LOCK = threading.Lock()
+
+
+def _warm_whole_axis_twin(const, init, batch, ptab, pinit,
+                          spread_alg: bool, dtype_name: str) -> None:
+    """A preempting group that takes the windowed kernel (no node fits
+    without eviction) has a twin of its shapes that takes the whole-axis
+    one: the same fleet once an eviction has left a free device beside
+    room for a later job. The first dispatch of a windowed group's
+    shapes compiles the twin's program too, by a launch that places
+    nothing, so that it does not compile (~20 s on a v5e) in front of a
+    window's queue."""
+    from .binpack import solve_lane_fused
+    key = (tuple((np.shape(x), np.asarray(x).dtype.str)
+                 for tree in (const, init, batch, ptab, pinit)
+                 for x in tree), spread_alg, dtype_name)
+    with _TWINS_LOCK:
+        if key in _TWINS_WARM:
+            return
+        _TWINS_WARM.add(key)
+    idle = batch._replace(active=np.zeros_like(np.asarray(batch.active)))
+    solve_lane_fused(const, init, idle, ptab, pinit, spread_alg=spread_alg,
+                     dtype_name=dtype_name, batched=True)
 
 
 def _dispatch(const, init, batch, spread_alg: bool, dtype_name: str,
@@ -586,13 +613,17 @@ def _dispatch(const, init, batch, spread_alg: bool, dtype_name: str,
     from .binpack import active_steps, solve_lane_fused
 
     if ptab is not None:
+        metrics.incr("nomad.solver.wavefront_preempt_dispatches" if wave
+                     else "nomad.solver.dense_dispatches")
+        got = solve_lane_fused(const, init, batch, ptab, pinit,
+                               spread_alg=spread_alg,
+                               dtype_name=dtype_name, batched=True,
+                               wave=wave, cache_version=cache_version,
+                               delta_src=delta_src)
         if wave:
-            metrics.incr("nomad.solver.wavefront_preempt_dispatches")
-        return solve_lane_fused(const, init, batch, ptab, pinit,
-                                spread_alg=spread_alg,
-                                dtype_name=dtype_name, batched=True,
-                                wave=wave, cache_version=cache_version,
-                                delta_src=delta_src)
+            _warm_whole_axis_twin(const, init, batch, ptab, pinit,
+                                  spread_alg, dtype_name)
+        return got
     if wave:
         metrics.incr("nomad.solver.wavefront_dispatches")
         return solve_lane_fused(const, init, batch, spread_alg=spread_alg,

@@ -242,14 +242,16 @@ def _spread_score(state: NodeState, const: NodeConst, dtype):
     return jnp.sum(jax.vmap(over_nodes)(const.spread_vidx, rows), axis=0)
 
 
-def _select_window(score, fit, limit, dtype):
+def _select_window(score, fit, limit, dtype, tie=None):
     """Dense emulation of LimitIterator + MaxScoreIterator over nodes laid
     out in shuffled order (reference: select.go:38-77, stack.go:82).
 
     Yield set = first min(L, C) counted options (C = feasible minus the
     first <=3 low-score skips) plus skipped options as fallback when the
-    source ran dry; winner = max score, earliest yield wins ties.
-    Returns (chosen_index, chosen_score, n_yielded); chosen = -1 if none.
+    source ran dry; winner = max score, then max `tie` (the low part of
+    a score carried as score + tie, _evicting_mean), earliest yield wins
+    ties. Returns (chosen_index, chosen_score, n_yielded); chosen = -1 if
+    none.
     """
     n = score.shape[0]
     low = fit & (score <= SKIP_THRESHOLD)
@@ -270,6 +272,8 @@ def _select_window(score, fit, limit, dtype):
     eff_score = jnp.where(yielded, score, neg_inf)
     best_score = jnp.max(eff_score)
     is_best = yielded & (eff_score == best_score)
+    if tie is not None:
+        is_best = _best_tie(is_best, tie, neg_inf)
     big = jnp.iinfo(jnp.int32).max
     best_order = jnp.min(jnp.where(is_best, order, big))
     chosen = jnp.argmax(is_best & (order == best_order))
@@ -277,6 +281,12 @@ def _select_window(score, fit, limit, dtype):
     chosen = jnp.where(any_yield, chosen, -1)
     return chosen, jnp.where(any_yield, best_score, neg_inf), \
         jnp.sum(yielded.astype(jnp.int32))
+
+
+def _best_tie(is_best, tie, neg_inf):
+    """Of the options whose score is the best, those whose low part is."""
+    best_tie = jnp.max(jnp.where(is_best, tie, neg_inf))
+    return is_best & (tie == best_tie)
 
 
 class PreemptTables(NamedTuple):
@@ -297,6 +307,10 @@ class PreemptTables(NamedTuple):
     static_rel: jnp.ndarray  # (N, A) bool holds an asked static port
     valid: jnp.ndarray       # (N, A) bool eligible candidate
     job_prio: jnp.ndarray    # () int32 scheduling job's priority
+    # (Gd, N, A) int32 instances of node device group g each candidate
+    # holds (the groups of NodeState.dev_free); 0-size when the lane
+    # asks for no device
+    dev_held: jnp.ndarray = _EMPTY_I3
 
 
 class PreemptState(NamedTuple):
@@ -325,27 +339,108 @@ def _distance(need_c, need_m, need_d, used_c, used_m, used_d):
     return jnp.sqrt(dc * dc + dm * dm + dd * dd)
 
 
+def _evicting_mean(base_sum, nscores, net_prio):
+    """The mean of an evicting option's scores, (base_sum + the
+    preemption score of net_prio) / (nscores + 1), as the unevaluated
+    sum hi + lo (rank.go preemptionScore, ScoreNormalizationIterator).
+
+    At the priorities a scheduler sees the preemption score lies within
+    1e-4 of 1, and evicted sets a quarter of a net priority apart differ
+    by ~4e-8 in the mean: below float32's resolution of a number near 1,
+    so a mean rounded to float32 ties or flips options that float64
+    ranks apart. The score is taken as 1 - q, q = 1 / (1 + e^-x) at
+    full relative precision, and the mean's rounding error is kept
+    (TwoSum): options that tie in hi rank by lo as their exact means
+    rank, and options of equal inputs tie exactly."""
+    x = PREEMPT_SCORE_RATE * (net_prio - PREEMPT_SCORE_ORIGIN)
+    q = 1.0 / (1.0 + jnp.exp(-x))
+    d = nscores + 1.0
+    t = (base_sum + 1.0) / d
+    u = -(q / d)
+    hi = t + u
+    b = hi - t
+    lo = (t - (hi - b)) + (u - b)
+    return hi, lo
+
+
 def _preempt_search(state: NodeState, pstate: PreemptState,
                     ptab: PreemptTables, const: NodeConst,
                     ask_cpu, ask_mem, ask_disk, dtype,
-                    lo: int, hi: Optional[int]):
-    """Vectorized PreemptForTaskGroup over node positions [lo:hi).
-
-    Per node: greedily pick eligible candidates (ascending priority group,
-    then minimal distance+penalty) until the freed+free resources superset
-    the ask, then filterSuperset. Returns per-node (met, evict_mask (n,A),
-    freed_cpu/mem/disk, net_prio) for the slice."""
+                    lo: int, hi: Optional[int], gone=None):
+    """Vectorized PreemptForTaskGroup over node positions [lo:hi), with
+    the candidates in ``gone`` (n, A) already taken out (a device
+    eviction of the same option). Returns per-node (met, evict_mask
+    (n,A), freed_cpu/mem/disk, net_prio) for the slice."""
     sl = slice(lo, hi)
     used_c = ptab.cpu[sl].astype(dtype)
     used_m = ptab.mem[sl].astype(dtype)
     used_d = ptab.disk[sl].astype(dtype)
     valid_now = ptab.valid[sl] & ~pstate.evicted[sl]
+    if gone is not None:
+        valid_now = valid_now & ~gone
     eligible = valid_now & (ptab.job_prio - ptab.prio[sl] >= 10)
     return _preempt_search_core(
         used_c, used_m, used_d, ptab.prio[sl], ptab.maxp[sl], ptab.grp[sl],
         valid_now, eligible, const.cpu_cap[sl], const.mem_cap[sl],
         const.disk_cap[sl], pstate.counts, ask_cpu, ask_mem, ask_disk,
         dtype)
+
+
+def _device_preempt_group(held, eligible, prio, free, count):
+    """PreemptForDevice for one device group over (n, A) candidates
+    (preemption.go PreemptForDevice + selectBestAllocs): the eligible
+    holders priority group by priority group, lowest first and in row
+    order within one, until the instances they hold plus the group's
+    ``free`` ones meet ``count``; of those, by instances held, most
+    first, until they hold ``count``. Returns (met (n,), taken (n, A),
+    sum of the taken's distinct priorities (n,))."""
+    n, A = held.shape
+    rows = jnp.arange(A, dtype=jnp.int32)
+    holders = eligible & (held > 0)
+    key = prio * A + rows[None, :]
+    before = (holders[:, None, :]
+              & (key[:, None, :] < key[:, :, None]))     # [i, j]: j ahead
+    cum = jnp.sum(jnp.where(before, held[:, None, :], 0), axis=2)
+    chosen = holders & (cum + free[:, None] < count)
+    met = (jnp.any(chosen, axis=1)
+           & (jnp.sum(jnp.where(chosen, held, 0), axis=1) + free >= count))
+    key2 = (jnp.max(held) - held) * (jnp.max(key) + 1) + key
+    ahead = chosen[:, None, :] & (key2[:, None, :] < key2[:, :, None])
+    cum2 = jnp.sum(jnp.where(ahead, held[:, None, :], 0), axis=2)
+    taken = chosen & (cum2 < count)
+    same = (taken[:, None, :] & (prio[:, None, :] == prio[:, :, None])
+            & (key2[:, None, :] < key2[:, :, None]))
+    first = taken & ~jnp.any(same, axis=2)
+    net = jnp.sum(jnp.where(first, prio, 0), axis=1).astype(jnp.int32)
+    return met, taken, net
+
+
+def _device_preempt(state: NodeState, pstate: PreemptState,
+                    ptab: PreemptTables, const: NodeConst,
+                    lo: int, hi: Optional[int]):
+    """Where the lane's one device ask finds no free instance on a node:
+    the option of the matching group whose evictions free enough
+    instances at the least sum of distinct priorities, the first group
+    among equals. Returns per-node (needed (n,): no group had enough
+    free, met, taken (n, A))."""
+    sl = slice(lo, hi)
+    free = state.dev_free[0, :, sl]                       # (Gd, n)
+    count = const.dev_count[0]
+    valid_now = ptab.valid[sl] & ~pstate.evicted[sl]
+    eligible = valid_now & (ptab.job_prio - ptab.prio[sl] >= 10)
+    n, A = eligible.shape
+    needed = ~jnp.any(free >= count, axis=0)
+    big = jnp.iinfo(jnp.int32).max
+    best_net = jnp.full(n, big, dtype=jnp.int32)
+    taken = jnp.zeros((n, A), dtype=bool)
+    for g in range(free.shape[0]):
+        met_g, taken_g, net_g = _device_preempt_group(
+            ptab.dev_held[g, sl], eligible, ptab.prio[sl],
+            jnp.maximum(free[g], 0), count)
+        better = met_g & (free[g] >= 0) & (net_g < best_net)
+        best_net = jnp.where(better, net_g, best_net)
+        taken = jnp.where(better[:, None], taken_g, taken)
+    return needed, best_net < big, taken
 
 
 def _preempt_search_core(used_c, used_m, used_d, prio, maxp, grp,
@@ -365,8 +460,8 @@ def _preempt_search_core(used_c, used_m, used_d, prio, maxp, grp,
     # allocs (own-job and terminal allocs are filtered before the
     # subtraction, preemption.go setCandidates) -- NOT the full carried
     # usage. An eviction set that "covers" the ask by this accounting can
-    # still fail the authoritative AllocsFit re-check (rank.go:541), which
-    # the caller models as the fit2 clamp.
+    # leave the node over capacity; the option stands all the same
+    # (rank.go), and the plan verifier refuses the node.
     avail_c0 = cpu_cap - jnp.sum(jnp.where(valid_now, used_c, 0.0), axis=1)
     avail_m0 = mem_cap - jnp.sum(jnp.where(valid_now, used_m, 0.0), axis=1)
     avail_d0 = disk_cap - jnp.sum(jnp.where(valid_now, used_d, 0.0), axis=1)
@@ -381,7 +476,7 @@ def _preempt_search_core(used_c, used_m, used_d, prio, maxp, grp,
     inf = jnp.array(jnp.inf, dtype=dtype)
 
     def cond(carry):
-        picked, av_c, av_m, av_d, _, _, _ = carry
+        picked, av_c, av_m, av_d, _, _, _, _ = carry
         # allMet starts False in the host loop: the first pick is
         # unconditional even when available already covers the ask
         met = ((av_c >= ask_cpu) & (av_m >= ask_mem) & (av_d >= ask_disk)
@@ -390,7 +485,7 @@ def _preempt_search_core(used_c, used_m, used_d, prio, maxp, grp,
         return jnp.any(~met & jnp.any(cand, axis=1))
 
     def body(carry):
-        picked, av_c, av_m, av_d, ne_c, ne_m, ne_d = carry
+        picked, av_c, av_m, av_d, ne_c, ne_m, ne_d, step = carry
         met = ((av_c >= ask_cpu) & (av_m >= ask_mem) & (av_d >= ask_disk)
                & jnp.any(picked, axis=1))
         cand = eligible & ~picked
@@ -407,30 +502,38 @@ def _preempt_search_core(used_c, used_m, used_d, prio, maxp, grp,
         pc = jnp.sum(jnp.where(onehot, used_c, 0.0), axis=1)
         pm = jnp.sum(jnp.where(onehot, used_m, 0.0), axis=1)
         pd = jnp.sum(jnp.where(onehot, used_d, 0.0), axis=1)
+        # the pick's place in the greedy's order (filterSuperset's
+        # stable sort keeps it among equal distances)
         return (picked | onehot, av_c + pc, av_m + pm, av_d + pd,
-                ne_c - pc, ne_m - pm, ne_d - pd)
+                ne_c - pc, ne_m - pm, ne_d - pd,
+                (jnp.where(onehot, step[1], step[0]), step[1] + 1))
 
     init = (jnp.zeros((n, A), dtype=bool), avail_c0, avail_m0, avail_d0,
             jnp.full(n, ask_cpu, dtype=dtype),
             jnp.full(n, ask_mem, dtype=dtype),
-            jnp.full(n, ask_disk, dtype=dtype))
+            jnp.full(n, ask_disk, dtype=dtype),
+            (jnp.full((n, A), A, dtype=jnp.int32), jnp.int32(0)))
     if static_iters:
         def scan_body(carry, _):
             return body(carry), None
         out_carry, _ = jax.lax.scan(scan_body, init, None, length=A,
                                     unroll=min(A, 8))
-        picked, av_c, av_m, av_d, _, _, _ = out_carry
+        picked, av_c, av_m, av_d, _, _, _, (rank, _) = out_carry
     else:
-        picked, av_c, av_m, av_d, _, _, _ = jax.lax.while_loop(
+        picked, av_c, av_m, av_d, _, _, _, (rank, _) = jax.lax.while_loop(
             cond, body, init)
     met = ((av_c >= ask_cpu) & (av_m >= ask_mem) & (av_d >= ask_disk)
            & jnp.any(picked, axis=1))
 
     # filterSuperset (preemption.go:705): re-add picked in DESCENDING
-    # distance-to-original-ask order until the ask is covered again.
+    # distance-to-original-ask order, the greedy's order among equal
+    # distances, until the ask is covered again.
     d0 = _distance(ask_cpu, ask_mem, ask_disk, used_c, used_m, used_d)
     sort_key = jnp.where(picked, -d0, inf)       # ascending(-d) = desc(d)
-    order = jnp.argsort(sort_key, axis=1, stable=True)
+    by_pick = jnp.argsort(rank, axis=1, stable=True)
+    order = jnp.take_along_axis(by_pick, jnp.argsort(
+        jnp.take_along_axis(sort_key, by_pick, axis=1), axis=1,
+        stable=True), axis=1)
     oc = jnp.take_along_axis(jnp.where(picked, used_c, 0.0), order, axis=1)
     om = jnp.take_along_axis(jnp.where(picked, used_m, 0.0), order, axis=1)
     od = jnp.take_along_axis(jnp.where(picked, used_d, 0.0), order, axis=1)
@@ -472,7 +575,9 @@ FAST_T = 1024
 def _scoring_parts(state: NodeState, const: NodeConst, b, dtype,
                    spread_alg: bool, lo: int, hi: Optional[int]):
     """Shared per-node fit + scoring over positions [lo:hi): returns
-    (fit, final, feas_nonres, other_sum, nscores, new_cpu, new_mem)."""
+    (fit, final, feas_nonres, other_sum, nscores, new_cpu, new_mem,
+    new_disk, dev_ok): feas_nonres leaves out the device asks, which
+    dev_ok answers (a free matching group for each)."""
     (ask_cpu, ask_mem, ask_disk, n_dyn, has_static, limit, count,
      penalty_idx, active, ask_cores) = b
     sl = slice(lo, hi)
@@ -514,10 +619,11 @@ def _scoring_parts(state: NodeState, const: NodeConst, b, dtype,
     # best group per request contributes one normalized score component
     R = const.dev_aff.shape[0]
     dev_score = None
+    dev_ok = jnp.ones(n, dtype=bool)
     if R > 0:
         free_g = state.dev_free[:, :, sl]
         ok_g = free_g >= const.dev_count[:, None, None]
-        feas_nonres &= jnp.all(jnp.any(ok_g, axis=1), axis=0)
+        dev_ok = jnp.all(jnp.any(ok_g, axis=1), axis=0)
         neg_inf = jnp.array(-jnp.inf, dtype=dtype)
         aff_g = jnp.where(ok_g, const.dev_aff[:, :, sl].astype(dtype),
                           neg_inf)
@@ -530,7 +636,7 @@ def _scoring_parts(state: NodeState, const: NodeConst, b, dtype,
             sum_aff / jnp.maximum(const.dev_sum_weight, 1e-9), 0.0)
     if has_cores:
         feas_nonres &= state.cores_free[sl] >= ask_cores
-    fit = (feas_nonres
+    fit = (feas_nonres & dev_ok
            & (new_cpu <= cpu_cap)
            & (new_mem <= mem_cap)
            & (new_disk <= const.disk_cap[sl]))
@@ -566,11 +672,11 @@ def _scoring_parts(state: NodeState, const: NodeConst, b, dtype,
         other_sum = other_sum + dev_score
     final = (binpack + other_sum) / nscores
     return (fit, final, feas_nonres, other_sum, nscores, new_cpu, new_mem,
-            new_disk)
+            new_disk, dev_ok)
 
 
-def _window_outputs(final, fit, limit, dtype, lo):
-    chosen, cscore, n_yield = _select_window(final, fit, limit, dtype)
+def _window_outputs(final, fit, limit, dtype, lo, tie=None):
+    chosen, cscore, n_yield = _select_window(final, fit, limit, dtype, tie)
     low = fit & (final <= SKIP_THRESHOLD)
     skip_rank = jnp.cumsum(low.astype(jnp.int32))
     skipped = low & (skip_rank <= MAX_SKIP)
@@ -589,58 +695,77 @@ def _score_and_select(state: NodeState, const: NodeConst, b, dtype,
     return _window_outputs(final, fit, limit, dtype, lo)
 
 
-def _score_and_select_preempt(state: NodeState, pstate: PreemptState,
-                              ptab: PreemptTables, const: NodeConst, b,
-                              dtype, spread_alg: bool,
-                              lo: int, hi: Optional[int]):
-    """Stack.Select with eviction enabled (BinPackIterator evict=True,
-    rank.go:545-565): nodes that fail the resource fit but have a
-    successful preemption search are yielded with the post-eviction
-    binpack score plus the preemption penalty (rank.go:851 logistic on
-    netPriority), exactly like the host chain. Returns the plain window
-    outputs plus the chosen node's eviction row and freed resources."""
+def _score_and_select_evict(state: NodeState, pstate: PreemptState,
+                            ptab: PreemptTables, const: NodeConst, b,
+                            dtype, spread_alg: bool,
+                            lo: int, hi: Optional[int]):
+    """Stack.Select with eviction on, where no node is an option without
+    it (generic_sched.go selectNextOption's second select; rank.go
+    BinPackIterator with evict): a device ask no free instance meets
+    asks PreemptForDevice, and where the usage left and the ask then do
+    not fit, PreemptForTaskGroup over the rest. An option is scored on
+    its usage before the task group's evictions (AllocsFit's
+    utilisation, over capacity, so the binpack score clamps) plus the
+    preemption score of all it evicts (rank.go:851 logistic on
+    netPriority), and stands whether or not the usage after the
+    evictions fits. Returns the window outputs plus the chosen node's
+    eviction row, the resources it frees and the device instances it
+    frees per group."""
     (ask_cpu, ask_mem, ask_disk, n_dyn, has_static, limit, count,
      penalty_idx, active, ask_cores) = b
     sl = slice(lo, hi)
     (fit, final, feas_nonres, other_sum, nscores, new_cpu, new_mem,
-     new_disk) = _scoring_parts(state, const, b, dtype, spread_alg, lo, hi)
-
-    met, evict, freed_c, freed_m, freed_d, net_prio = _preempt_search(
+     new_disk, dev_ok) = _scoring_parts(state, const, b, dtype, spread_alg,
+                                        lo, hi)
+    cpu_cap, mem_cap = const.cpu_cap[sl], const.mem_cap[sl]
+    n = cpu_cap.shape[0]
+    A = ptab.cpu.shape[1]
+    prio_f = ptab.prio[sl].astype(dtype)
+    if ptab.dev_held.shape[0]:
+        needed, dev_met, dev_taken = _device_preempt(
+            state, pstate, ptab, const, lo, hi)
+        dev_taken = dev_taken & needed[:, None]
+        dev_part = ~needed | dev_met
+    else:
+        dev_taken = jnp.zeros((n, A), dtype=bool)
+        dev_part = dev_ok
+    freed_dev = [jnp.sum(jnp.where(dev_taken, t[sl].astype(dtype), 0.0),
+                         axis=1) for t in (ptab.cpu, ptab.mem, ptab.disk)]
+    # AllocsFit's utilisation with the placement, after the device
+    # evictions: what the binpack score and the task group's need read
+    use_c = new_cpu - freed_dev[0]
+    use_m = new_mem - freed_dev[1]
+    use_d = new_disk - freed_dev[2]
+    fits = ((use_c <= cpu_cap) & (use_m <= mem_cap)
+            & (use_d <= const.disk_cap[sl]))
+    met, tg_evict, _fc, _fm, _fd, _net = _preempt_search(
         state, pstate, ptab, const, ask_cpu, ask_mem, ask_disk, dtype,
-        lo, hi)
-
-    # fit2: the authoritative re-check after eviction (rank.go:541 ->
-    # preemption insufficient under FULL usage -> node exhausted). The
-    # search's candidates-only accounting can overstate availability when
-    # this eval already placed on the node.
-    fit2 = ((new_cpu - freed_c <= const.cpu_cap[sl])
-            & (new_mem - freed_m <= const.mem_cap[sl])
-            & (new_disk - freed_d <= const.disk_cap[sl]))
-    fit_p = feas_nonres & ~fit & met & fit2
-    free_cpu_p = 1.0 - (new_cpu - freed_c) / jnp.maximum(
-        const.cpu_cap[sl], 1e-9)
-    free_mem_p = 1.0 - (new_mem - freed_m) / jnp.maximum(
-        const.mem_cap[sl], 1e-9)
-    binpack_p = _binpack_score(free_cpu_p, free_mem_p, spread_alg)
-    pscore = 1.0 / (1.0 + jnp.exp(
-        PREEMPT_SCORE_RATE * (net_prio - PREEMPT_SCORE_ORIGIN)))
-    final_p = (binpack_p + other_sum + pscore) / (nscores + 1.0)
-
-    fit_c = fit | fit_p
-    final_c = jnp.where(fit_p, final_p, final)
+        lo, hi, gone=dev_taken)
+    evict = dev_taken | (tg_evict & ~fits[:, None])
+    fit_p = (feas_nonres & dev_part & ~fit & (fits | met)
+             & jnp.any(evict, axis=1))
+    free_cpu = 1.0 - use_c / jnp.maximum(cpu_cap, 1e-9)
+    free_mem = 1.0 - use_m / jnp.maximum(mem_cap, 1e-9)
+    binpack = _binpack_score(free_cpu, free_mem, spread_alg)
+    mx = jnp.max(jnp.where(evict, prio_f, 0.0), axis=1)
+    sm = jnp.sum(jnp.where(evict, prio_f, 0.0), axis=1)
+    net_prio = jnp.where(mx > 0, mx + sm / jnp.maximum(mx, 1e-9), 0.0)
+    final_p, tie = _evicting_mean(binpack + other_sum, nscores, net_prio)
     chosen, cscore, n_yield, counted = _window_outputs(
-        final_c, fit_c, limit, dtype, lo)
+        final_p, fit_p, limit, dtype, lo, tie)
 
-    # Gather the chosen node's eviction info (slice-local index)
-    local = jnp.clip(chosen - lo, 0, evict.shape[0] - 1)
-    was_preempt = (chosen >= 0) & fit_p[local]
-    evict_row = jnp.where(was_preempt, evict[local],
-                          jnp.zeros_like(evict[0]))
-    freed = jnp.where(
-        was_preempt,
-        jnp.stack([freed_c[local], freed_m[local], freed_d[local]]),
-        jnp.zeros(3, dtype=dtype))
-    return chosen, cscore, n_yield, counted, evict_row, freed
+    # the chosen node's evictions (slice-local index)
+    local = jnp.clip(chosen - lo, 0, n - 1)
+    hit = chosen >= 0
+    evict_row = evict[local] & hit
+    freed = jnp.stack([jnp.sum(jnp.where(evict_row, t[lo + local]
+                                         .astype(dtype), 0.0))
+                       for t in (ptab.cpu, ptab.mem, ptab.disk)])
+    dev_back = (jnp.sum(jnp.where(evict_row[None, :],
+                                  ptab.dev_held[:, lo + local], 0),
+                        axis=1).astype(jnp.int32)
+                if ptab.dev_held.shape[0] else jnp.zeros(0, jnp.int32))
+    return chosen, cscore, n_yield, counted, evict_row, freed, dev_back
 
 
 def _commit_tables(state: NodeState, new_state: NodeState,
@@ -807,13 +932,18 @@ def _solve_placements_preempt_impl(const: NodeConst, init: NodeState,
                                    batch: PlacementBatch,
                                    ptab: PreemptTables,
                                    pinit: PreemptState,
+                                   n_steps=None,
                                    spread_alg: bool = False,
                                    dtype_name: str = "float32"):
-    """solve_placements with dense preemption: each scan step runs the
-    eviction-enabled select; committing a preempting winner releases the
-    evicted candidates' resources and ports into the carry and bumps the
-    per-(job,tg) eviction counts (the reference's plan.NodePreemptions +
-    currentPreemptions bookkeeping, generic_sched.go:924 + preemption.go).
+    """solve_placements for a scheduler that may preempt: each scan step
+    is generic_sched.go selectNextOption -- the select without eviction
+    over the whole node axis, and only where it finds no option the
+    select with eviction on (_score_and_select_evict). Committing an
+    evicting winner releases the evicted candidates' resources, ports
+    and device instances into the carry and bumps the per-(job,tg)
+    eviction counts (the reference's plan.NodePreemptions +
+    currentPreemptions bookkeeping). The trip count is an operand, as
+    in _solve_placements_impl.
 
     Extra outputs: evict_rows (P, A) bool -- candidate rows evicted by each
     placement on its chosen node."""
@@ -822,38 +952,44 @@ def _solve_placements_preempt_impl(const: NodeConst, init: NodeState,
     use_fast = n_total > 2 * FAST_T
     G = pinit.counts.shape[0]
     A = ptab.cpu.shape[1]
+    Gd = ptab.dev_held.shape[0]
+
+    def windowed(select, limit):
+        """select(lo, hi) over the first FAST_T positions where they
+        hold `limit` counted options (the window is then the full
+        pass's), else over the whole axis."""
+        if not use_fast:
+            return select(0, None)
+        f = select(0, FAST_T)
+        return jax.lax.cond(f[3] >= limit, lambda _: f,
+                            lambda _: select(0, None), operand=None)
 
     def step(carry, b):
         state, pstate = carry
         (ask_cpu, ask_mem, ask_disk, n_dyn, has_static, limit, count,
          penalty_idx, active, ask_cores) = b
+        plain = windowed(lambda lo, hi: _score_and_select(
+            state, const, b, dtype, spread_alg, lo, hi), limit)[:3]
 
-        if use_fast:
-            f = _score_and_select_preempt(
-                state, pstate, ptab, const, b, dtype, spread_alg,
-                0, FAST_T)
+        def without(_):
+            return (*plain, jnp.zeros(A, dtype=bool),
+                    jnp.zeros(3, dtype=dtype), jnp.zeros(Gd, jnp.int32))
 
-            def full(_):
-                return _score_and_select_preempt(
-                    state, pstate, ptab, const, b, dtype, spread_alg,
-                    0, None)
+        def evicting(_):
+            got = windowed(lambda lo, hi: _score_and_select_evict(
+                state, pstate, ptab, const, b, dtype, spread_alg, lo, hi),
+                limit)
+            return got[:3] + got[4:]
 
-            def fast(_):
-                return f
-
-            chosen, cscore, n_yield, _cnt, evict_row, freed = jax.lax.cond(
-                f[3] >= limit, fast, full, operand=None)
-        else:
-            chosen, cscore, n_yield, _cnt, evict_row, freed = \
-                _score_and_select_preempt(
-                    state, pstate, ptab, const, b, dtype, spread_alg,
-                    0, None)
+        chosen, cscore, n_yield, evict_row, freed, dev_back = jax.lax.cond(
+            plain[0] >= 0, without, evicting, operand=None)
 
         do = active & (chosen >= 0)
         safe = jnp.maximum(chosen, 0)
         add_f = do.astype(dtype)
         add_i = do.astype(jnp.int32)
         evict_row = evict_row & do
+        freed = freed * add_f
 
         # release evicted usage + ports, then charge the placement
         dyn_back = jnp.sum(
@@ -874,6 +1010,13 @@ def _solve_placements_preempt_impl(const: NodeConst, init: NodeState,
             dyn_avail=state.dyn_avail.at[safe].add(
                 dyn_back - add_i * n_dyn),
         )
+        if Gd:
+            # the evicted holders' instances come free in every group
+            # that matches an ask, before the placement takes its own
+            here = state.dev_free[:, :, safe]
+            state = state._replace(dev_free=state.dev_free.at[
+                :, :, safe].add(jnp.where(here >= 0,
+                                          dev_back[None, :] * add_i, 0)))
         new_state = _commit_tables(state, new_state, const, do, safe)
 
         grp_row = ptab.grp[safe]                      # (A,)
@@ -892,13 +1035,23 @@ def _solve_placements_preempt_impl(const: NodeConst, init: NodeState,
 
     ask_cores_xs = (batch.ask_cores if batch.ask_cores.shape[0]
                     else jnp.zeros_like(batch.count))
-    (final_state, final_pstate), (chosen, scores, n_yielded, evict_rows) = \
-        jax.lax.scan(
-            step, (init, pinit),
-            (batch.ask_cpu, batch.ask_mem, batch.ask_disk,
-             batch.n_dyn_ports, batch.has_static, batch.limit, batch.count,
-             batch.penalty_idx, batch.active, ask_cores_xs),
-            unroll=_dense_unroll())
+    xs = (batch.ask_cpu, batch.ask_mem, batch.ask_disk, batch.n_dyn_ports,
+          batch.has_static, batch.limit, batch.count, batch.penalty_idx,
+          batch.active, ask_cores_xs)
+    if n_steps is None:
+        n_steps = active_steps(batch.active)
+    p = batch.ask_cpu.shape[0]
+
+    def one(k, carry):
+        state_p, outs = carry
+        state_p, ys = step(state_p, tuple(x[k] for x in xs))
+        return state_p, tuple(o.at[k].set(y) for o, y in zip(outs, ys))
+
+    _, ys = jax.eval_shape(step, (init, pinit), tuple(x[0] for x in xs))
+    (final_state, _), (chosen, scores, n_yielded, evict_rows) = \
+        jax.lax.fori_loop(0, n_steps, one, ((init, pinit), (
+            *_unplaced(ys[:3], (p,)),
+            jnp.zeros((p, A), dtype=bool))))
     return chosen, scores, n_yielded, evict_rows, final_state
 
 
@@ -914,7 +1067,9 @@ def solve_eval_batch_preempt(const, init, batch, ptab, pinit,
     axis), mirroring solve_eval_batch."""
     inner = functools.partial(solve_placements_preempt,
                               spread_alg=spread_alg, dtype_name=dtype_name)
-    return jax.vmap(inner)(const, init, batch, ptab, pinit)
+    return jax.vmap(inner, in_axes=(0, 0, 0, 0, 0, None))(
+        const, init, batch, ptab, pinit,
+        jnp.max(active_steps(batch.active)))
 
 
 def solve_eval_batch(const: NodeConst, init: NodeState, batch: PlacementBatch,
@@ -1012,17 +1167,19 @@ def _make_fused_fn(metas, treedef, group_keys, spread_alg: bool,
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     if preempt:
-        inner = functools.partial(_solve_placements_preempt_impl,
-                                  spread_alg=spread_alg,
-                                  dtype_name=dtype_name)
-        if batched:
-            inner = jax.vmap(inner)
+        def one_p(const, init, batch, ptab, pinit, n_steps=None):
+            return _solve_placements_preempt_impl(
+                const, init, batch, ptab, pinit, n_steps,
+                spread_alg=spread_alg, dtype_name=dtype_name)[:4]
 
         @jax.jit
         def fn(*buffers):
-            const, init, batch, ptab, pinit = rebuild(buffers)
-            chosen, scores, n_yielded, evict_rows, _ = inner(
-                const, init, batch, ptab, pinit)
+            trees = rebuild(buffers)
+            if batched:
+                chosen, scores, n_yielded, evict_rows = _lanes_in_turn(
+                    one_p, trees)
+            else:
+                chosen, scores, n_yielded, evict_rows = one_p(*trees)
             out = jnp.stack([chosen.astype(scores.dtype), scores,
                              n_yielded.astype(scores.dtype)])
             return out, evict_rows
@@ -1033,49 +1190,52 @@ def _make_fused_fn(metas, treedef, group_keys, spread_alg: bool,
             const, init, batch, spread_alg=spread_alg,
             dtype_name=dtype_name, n_steps=n_steps)[:3]
 
-    def _solve_lanes_in_turn(const, init, batch):
-        """The lanes of a dispatch one after the other, each over its
-        own active steps, up to the last lane that has any: a padded
-        lane and a padded step cost nothing, and a retry of 40
-        placements beside a first attempt of 1,200 pays for 40. One
-        program serves every lane count and width up to its buffers'
-        (E, P). A step is a chain of some hundred small scans and
-        reductions over the node axis, bound by their latency (v5e,
-        N 16,384, S 1, V 75, PR 31): 64 us alone, as a batch of one or
-        as plain (N,) vectors; 64 us each of 8 here; 24 us each of 8 as
-        a vector axis under vmap (193 us for the eight). So the loop
-        loses 2.7x where eight lanes are equally wide and wins where
-        they differ, which is what a drained queue sends (`spread-drain`:
-        a first attempt beside retries of tens, 1,330 steps a launch, 85
-        ms in turn against 232 ms for the vector's 1,200 steps). A
-        gather in the step is linear in the lanes and levels the three
-        (SPREAD_SELECT_V). Lanes of like width as one vector: ROADMAP
-        A4."""
-        steps = active_steps(batch.active)
-        trees = (const, init, batch)
-        row_of_one = jax.vmap(one, in_axes=(0, 0, 0, None))
-
-        def lane(e, outs):
-            ys = row_of_one(*jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_slice_in_dim(a, e, 1, 0), trees),
-                steps[e])
-            return tuple(jax.lax.dynamic_update_slice_in_dim(o, y, e, 0)
-                         for o, y in zip(outs, ys))
-        return jax.lax.fori_loop(
-            0, active_steps(steps > 0), lane,
-            _unplaced(jax.eval_shape(row_of_one, *trees, steps[0])))
-
     @jax.jit
     def fn(*buffers):
         const, init, batch = rebuild(buffers)
         if batched:
-            chosen, scores, n_yielded = _solve_lanes_in_turn(
-                const, init, batch)
+            chosen, scores, n_yielded = _lanes_in_turn(
+                one, (const, init, batch))
         else:
             chosen, scores, n_yielded = one(const, init, batch)
         return jnp.stack([chosen.astype(scores.dtype), scores,
                           n_yielded.astype(scores.dtype)])
     return fn
+
+
+def _lanes_in_turn(one, trees):
+    """The lanes of a dispatch one after the other, each over its own
+    active steps, up to the last lane that has any: a padded lane and a
+    padded step cost nothing, and a retry of 40 placements beside a
+    first attempt of 1,200 pays for 40. One program serves every lane
+    count and width up to its buffers' (E, P). `one(*lane_trees,
+    n_steps)` solves one lane (chosen, scores, n_yielded[, evict_rows]);
+    `trees` are (const, init, batch[, ptab, pinit]) with the eval axis
+    leading. A step is a chain of some hundred small scans and
+    reductions over the node axis, bound by their latency (v5e,
+    N 16,384, S 1, V 75): 64 us alone, as a batch of one or as
+    plain (N,) vectors; 64 us each of 8 here; 24 us each of 8 as a
+    vector axis under vmap (193 us for the eight). So the loop loses
+    2.7x where eight lanes are equally wide and wins where they differ,
+    which is what a drained queue sends (`spread-drain`: a first attempt
+    beside retries of tens, 1,330 steps a launch, 85 ms in turn against
+    232 ms for the vector's 1,200 steps). A gather in the step is linear
+    in the lanes and levels the three (SPREAD_SELECT_V). Lanes of like
+    width as one vector: ROADMAP A4."""
+    steps = active_steps(trees[2].active)
+    row_of_one = jax.vmap(one, in_axes=(0,) * len(trees) + (None,))
+
+    def lane(e, outs):
+        ys = row_of_one(*jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, e, 1, 0), trees),
+            steps[e])
+        return tuple(jax.lax.dynamic_update_slice_in_dim(o, y, e, 0)
+                     for o, y in zip(outs, ys))
+    shapes = jax.eval_shape(row_of_one, *trees, steps[0])
+    # chosen -1, score -inf, nothing yielded; an evict row evicts nothing
+    outs = _unplaced(shapes[:3]) + tuple(
+        jnp.zeros(y.shape, y.dtype) for y in shapes[3:])
+    return jax.lax.fori_loop(0, active_steps(steps > 0), lane, outs)
 
 
 def solve_lane_fused(const, init, batch, ptab=None, pinit=None, *,
@@ -1111,7 +1271,7 @@ def solve_lane_fused(const, init, batch, ptab=None, pinit=None, *,
         return solve_lane_wave_preempt(
             const, init, batch, ptab, pinit, spread_alg=spread_alg,
             dtype_name=dtype_name, batched=batched,
-            cache_version=cache_version, delta_src=delta_src)
+            cache_version=cache_version)
     trees = ((const, init, batch) if ptab is None
              else (const, init, batch, ptab, pinit))
     stacked, metas, treedef, group_keys = _fuse_trees(trees)
@@ -2222,32 +2382,34 @@ def _solve_wave_block_impl(compact, scal_f, scal_i, pen,
 
 # ---------------------------------------------------------------------------
 # Wavefront preemption: the windowed kernel family extended to the
-# eviction-enabled select (VERDICT r3 next-step 3).
+# eviction-enabled select.
 #
-# The dense preempt path re-runs the greedy eviction search over ALL N
-# nodes' (N, A) candidate tables per placement step -- the tier-5 lanes
-# where the dense scan was slowest. But the selection window only ever
-# examines the first limit+MAX_SKIP OPTION nodes in shuffled order, where
-# an option is plain-fit OR eviction-met (rank.go:545-565); so the scan
-# can carry a B-slot buffer of front option nodes -- each slot holding its
-# (A,) candidate columns and accumulated eviction mask -- and run the
-# search over (B, A) instead of (N, A): ~N/B (=300x at 10K nodes) less
-# per-step work, sharing _preempt_search_core with the dense kernel.
+# The dense preempt path re-runs the eviction search over ALL N nodes'
+# (N, A) candidate tables per placement step. But where no node fits a
+# lane's ask without eviction at its start, selectNextOption's first
+# select can only ever find nodes this lane evicted on, and its second
+# examines only the first limit+MAX_SKIP evicting options in shuffled
+# order; so the scan carries a B-slot buffer of front option nodes --
+# each slot holding its (A,) candidate columns, the instances they hold
+# of its device groups and its accumulated eviction mask -- and runs the
+# searches over (B, A) instead of (N, A), sharing _preempt_search_core
+# and _device_preempt_group with the dense kernel.
 #
 # Window-membership correctness: a node OUTSIDE the window has never been
 # chosen, so its state is pristine and its option-status is static ->
 # precomputable on the host (the refill list). Option-status is monotone
-# non-increasing (picks and evictions only consume), so a shifted-out
-# slot can never become an option again; eviction-met is coverage-based
-# and therefore independent of the max_parallel penalty ordering, so
-# global count changes can't resurrect a node either. Slots shift out
-# when the chosen node exhausts BOTH plain fit and eviction potential;
-# refills enter pristine from the precomputed list.
+# non-increasing outside the slots (picks and evictions only consume),
+# so a shifted-out slot can never become an option again; whether an
+# eviction meets the ask is coverage-based and therefore independent of
+# the max_parallel penalty ordering, so global count changes can't
+# resurrect a node either. Slots shift out when the chosen node has
+# neither a fit nor an evicting option left; refills enter pristine from
+# the precomputed list.
 #
-# Eligibility (wavefront_preempt_ok): preempt lanes already exclude
-# networks/devices/cores (service.tg_solver_eligible preempt=True), so
-# the kernel models cpu/mem/disk + distinct_hosts + affinity + penalties;
-# spreads stay dense.
+# Eligibility (service.PackedLane.wavefront_ok): no spread columns, one
+# device ask without affinities, no node that fits at the lane's start;
+# preempt lanes already exclude networks and cores
+# (service.tg_solver_eligible preempt=True).
 
 # slot columns for the preempt wavefront (compactP, (C, _WPC_NCOLS))
 _WPC_FEAS = 0
@@ -2255,222 +2417,180 @@ _WPC_UC, _WPC_UM, _WPC_UD = 1, 2, 3
 _WPC_CC, _WPC_CM, _WPC_CD = 4, 5, 6
 _WPC_PLACED, _WPC_PLACED_JOB = 7, 8
 _WPC_AFF, _WPC_POS = 9, 10
-_WPC_CDEV = 11          # device-dimension placement capacity (2^24 =
-_WPC_NCOLS = 12         # unbounded; exact in float32)
-_WPC_DEV_UNBOUNDED = float(2 ** 24)
+_WPC_NCOLS = 11         # then one column a device group: its free
+                        # instances for the ask, -1 where it matches none
 
 
-def _numpy_preempt_pristine(ccpu, cmem, cdisk, cprio, cmaxp, cgrp, cvalid,
-                            counts, cpu_cap, mem_cap, disk_cap, job_prio,
-                            ask_cpu, ask_mem, ask_disk):
-    """Exact host-side transcription of _preempt_search_core at pristine
-    state (no prior evictions), vectorized over all N nodes in numpy.
-    Returns (met (N,), freed (3, N)) using the greedy + filterSuperset
-    eviction set -- the same values the device search would produce.
-    All arithmetic runs in the candidate arrays' dtype: a float64 host
-    pass against a float32 device search could flip near-tie argmins and
-    admit nodes the in-step search can't yield (window-starving zombies)
-    or drop real options."""
-    dt = ccpu.dtype
-    ask_cpu = dt.type(ask_cpu)
-    ask_mem = dt.type(ask_mem)
-    ask_disk = dt.type(ask_disk)
-    N, A = ccpu.shape
-    elig = cvalid & (job_prio - cprio >= 10)
-    avail_c0 = (cpu_cap - np.sum(np.where(cvalid, ccpu, 0.0), axis=1,
-                                 dtype=dt)).astype(dt)
-    avail_m0 = (mem_cap - np.sum(np.where(cvalid, cmem, 0.0), axis=1,
-                                 dtype=dt)).astype(dt)
-    avail_d0 = (disk_cap - np.sum(np.where(cvalid, cdisk, 0.0), axis=1,
-                                  dtype=dt)).astype(dt)
-    n_pre = np.where(cgrp >= 0, counts[np.maximum(cgrp, 0)], 0)
-    penalty = np.where((cmaxp > 0) & (n_pre >= cmaxp),
-                       (n_pre + 1 - cmaxp) * dt.type(MAX_PARALLEL_PENALTY),
-                       dt.type(0.0)).astype(dt)
+def _numpy_device_option(held, eligible, prio, free, count):
+    """_device_preempt_group, in numpy over all N nodes of one group."""
+    n, A = held.shape
+    holders = eligible & (held > 0)
+    key = prio.astype(np.int64) * A + np.arange(A)[None, :]
+    before = holders[:, None, :] & (key[:, None, :] < key[:, :, None])
+    cum = np.sum(np.where(before, held[:, None, :], 0), axis=2)
+    chosen = holders & (cum + free[:, None] < count)
+    met = (chosen.any(axis=1)
+           & (np.sum(np.where(chosen, held, 0), axis=1) + free >= count))
+    key2 = (held.max(initial=0) - held) * (key.max(initial=0) + 1) + key
+    ahead = chosen[:, None, :] & (key2[:, None, :] < key2[:, :, None])
+    cum2 = np.sum(np.where(ahead, held[:, None, :], 0), axis=2)
+    taken = chosen & (cum2 < count)
+    same = (taken[:, None, :] & (prio[:, None, :] == prio[:, :, None])
+            & (key2[:, None, :] < key2[:, :, None]))
+    first = taken & ~same.any(axis=2)
+    return met, taken, np.sum(np.where(first, prio, 0), axis=1)
 
-    def dist(ne_c, ne_m, ne_d):
-        eps = dt.type(1e-9)
-        zero = dt.type(0.0)
-        dc = np.where(ne_c > 0, (ne_c - ccpu) / np.maximum(ne_c, eps), zero)
-        dm = np.where(ne_m > 0, (ne_m - cmem) / np.maximum(ne_m, eps), zero)
-        dd = np.where(ne_d > 0, (ne_d - cdisk) / np.maximum(ne_d, eps),
-                      zero)
-        return np.sqrt(dc * dc + dm * dm + dd * dd).astype(dt)
 
-    picked = np.zeros((N, A), dtype=bool)
-    av_c, av_m, av_d = avail_c0.copy(), avail_m0.copy(), avail_d0.copy()
-    ne_c = np.full(N, ask_cpu, dtype=dt)
-    ne_m = np.full(N, ask_mem, dtype=dt)
-    ne_d = np.full(N, ask_disk, dtype=dt)
-    # must fit cprio's dtype: a wider sentinel silently WRAPS under
-    # NEP-50 value-based casting (int64 max as int32 == -1, which then
-    # wins every np.min and empties the pick group)
-    big_i = np.iinfo(np.int32).max
-    for _ in range(A):
-        met = ((av_c >= ask_cpu) & (av_m >= ask_mem) & (av_d >= ask_disk)
-               & picked.any(axis=1))
-        cand = elig & ~picked
-        if not np.any(~met & cand.any(axis=1)):
-            break
-        cur_prio = np.min(np.where(cand, cprio, big_i), axis=1)
-        in_group = cand & (cprio == cur_prio[:, None])
-        key = np.where(in_group,
-                       dist(ne_c[:, None], ne_m[:, None], ne_d[:, None])
-                       + penalty, np.inf)
-        pick = np.argmin(key, axis=1)
-        do = ~met & in_group.any(axis=1)
-        onehot = (np.arange(A)[None, :] == pick[:, None]) & do[:, None]
-        pc = np.sum(np.where(onehot, ccpu, 0.0), axis=1)
-        pm = np.sum(np.where(onehot, cmem, 0.0), axis=1)
-        pd = np.sum(np.where(onehot, cdisk, 0.0), axis=1)
-        picked |= onehot
-        av_c += pc; av_m += pm; av_d += pd            # noqa: E702
-        ne_c -= pc; ne_m -= pm; ne_d -= pd            # noqa: E702
-    met = ((av_c >= ask_cpu) & (av_m >= ask_mem) & (av_d >= ask_disk)
-           & picked.any(axis=1))
+def wavefront_preempt_pristine(const, init, batch, ptab, dtype_name: str):
+    """Per node at the lane's pristine state, exactly as the kernels'
+    first step reads it: (fits without eviction, is an option with
+    eviction on). The windowed kernel takes a lane only where no node
+    fits (the select with eviction off finds nothing anywhere, so every
+    later option without eviction is a node this lane evicted on: one of
+    its slots); the evicting options, in scan order, are its compact
+    table. Option status needs no greedy. A device eviction exists where
+    a matching group's eligible holders and its free instances meet the
+    ask. The task group's search meets the ask iff an eligible candidate
+    is left and the room beside the candidates plus all the eligible
+    ones covers it; where that cover fails no device eviction makes the
+    ask fit either, and where the device eviction takes every eligible
+    candidate (only there) the option is whether what is left fits,
+    read from the eviction itself (_numpy_device_option). Integer-valued
+    resources keep every sum exact in float32."""
+    dt = np.dtype(dtype_name)
+    ask = [dt.type(np.asarray(x, dtype=dt).reshape(-1)[0])
+           for x in (batch.ask_cpu, batch.ask_mem, batch.ask_disk)]
+    caps = [np.asarray(x, dtype=dt) for x in
+            (const.cpu_cap, const.mem_cap, const.disk_cap)]
+    used = [np.asarray(x, dtype=dt) for x in
+            (init.used_cpu, init.used_mem, init.used_disk)]
+    feas = np.asarray(const.feasible, dtype=bool)
+    if bool(np.asarray(const.distinct_hosts)):
+        placed = np.asarray(init.placed_job if bool(np.asarray(
+            const.distinct_job_level)) else init.placed)
+        feas = feas & (placed == 0)
+    dev_free = np.asarray(init.dev_free)
+    count = int(np.asarray(const.dev_count).reshape(-1)[0]) \
+        if dev_free.shape[0] else 0
+    n = feas.shape[0]
+    dev_ok = ((dev_free[0] >= count).any(axis=0) if dev_free.shape[0]
+              else np.ones(n, dtype=bool))
+    fits0 = np.all([u + a <= c for u, a, c in zip(used, ask, caps)], axis=0)
+    fit0 = feas & dev_ok & fits0
 
-    # filterSuperset: re-add picked in descending distance-to-ask order
-    d0 = dist(np.full(N, ask_cpu)[:, None], np.full(N, ask_mem)[:, None],
-              np.full(N, ask_disk)[:, None])
-    sort_key = np.where(picked, -d0, np.inf)
-    order = np.argsort(sort_key, axis=1, kind="stable")
-    oc = np.take_along_axis(np.where(picked, ccpu, 0.0), order, axis=1)
-    om = np.take_along_axis(np.where(picked, cmem, 0.0), order, axis=1)
-    od = np.take_along_axis(np.where(picked, cdisk, 0.0), order, axis=1)
-    cum_c = avail_c0[:, None] + np.cumsum(oc, axis=1)
-    cum_m = avail_m0[:, None] + np.cumsum(om, axis=1)
-    cum_d = avail_d0[:, None] + np.cumsum(od, axis=1)
-    met_at = ((cum_c >= ask_cpu) & (cum_m >= ask_mem)
-              & (cum_d >= ask_disk))
-    first_met = np.argmax(met_at, axis=1)
-    keep_sorted = (np.arange(A)[None, :] <= first_met[:, None])
-    keep_sorted &= np.take_along_axis(picked, order, axis=1)
-    evict = np.zeros_like(picked)
-    np.put_along_axis(evict, order, keep_sorted, axis=1)
-    freed = np.stack([np.sum(np.where(evict, t, 0.0), axis=1)
-                      for t in (ccpu, cmem, cdisk)])
-    return met, freed
+    cand = [np.asarray(x, dtype=dt) for x in (ptab.cpu, ptab.mem,
+                                              ptab.disk)]
+    prio = np.asarray(ptab.prio)
+    valid = np.asarray(ptab.valid, dtype=bool)
+    eligible = valid & (int(np.asarray(ptab.job_prio)) - prio >= 10)
+
+    def total(rows, c_):
+        return np.sum(np.where(rows, c_, dt.type(0)), axis=1, dtype=dt)
+    cover = eligible.any(axis=1) & np.all(
+        [c - total(valid, c_) + total(eligible, c_) >= a
+         for c_, c, a in zip(cand, caps, ask)], axis=0)
+    option = fits0 | cover
+    if dev_free.shape[0]:
+        held = np.asarray(ptab.dev_held)
+        met = np.zeros(n, dtype=bool)
+        holder = np.zeros_like(valid)
+        for g in range(dev_free.shape[1]):
+            h_g = eligible & (held[g] > 0) & (dev_free[0, g] >= 0)[:, None]
+            met |= h_g.any(axis=1) & (
+                np.maximum(dev_free[0, g], 0)
+                + np.sum(np.where(h_g, held[g], 0), axis=1) >= count)
+            holder |= h_g
+        # where every eligible candidate holds a matching instance the
+        # eviction may take them all: read it exactly there
+        every = ~dev_ok & met & cover & ~(eligible & ~holder).any(axis=1)
+        left = np.ones(n, dtype=bool)
+        fits = np.zeros(n, dtype=bool)
+        if every.any():
+            idx = np.nonzero(every)[0]
+            best = np.full(idx.shape[0], np.iinfo(np.int64).max)
+            taken = np.zeros((idx.shape[0], valid.shape[1]), dtype=bool)
+            for g in range(dev_free.shape[1]):
+                met_g, taken_g, net_g = _numpy_device_option(
+                    held[g][idx], eligible[idx], prio[idx],
+                    np.maximum(dev_free[0, g, idx], 0), count)
+                better = met_g & (dev_free[0, g, idx] >= 0) & (net_g < best)
+                best = np.where(better, net_g, best)
+                taken = np.where(better[:, None], taken_g, taken)
+            left[idx] = (eligible[idx] & ~taken).any(axis=1)
+            fits[idx] = np.all(
+                [u[idx] + a - total(taken, c_[idx]) <= c[idx]
+                 for u, a, c_, c in zip(used, ask, cand, caps)], axis=0)
+        option = np.where(dev_ok, option,
+                          met & cover & (left | fits))
+    return fit0, feas & ~fit0 & option
 
 
 def wavefront_preempt_compact_host(const, init, batch, ptab, pinit,
                                    dtype_name: str,
                                    p_pad: Optional[int] = None,
                                    B: int = WAVE_B):
-    """Host precompute for ONE preempt lane: the pristine option
-    predicate + refill-ordered compact node columns and candidate tables.
-    Returns (compactP (C, _WPC_NCOLS), cand dict of (C, A) arrays, scal_f (4,),
-    scal_i (4,), pen (P,), counts0 (G,))."""
+    """Host precompute for ONE preempt lane whose select without
+    eviction finds no node (wavefront_preempt_pristine): the evicting
+    options in scan order as compact node columns and candidate tables.
+    Returns (compact (C, _WPC_NCOLS + Gd), cand dict of (C, A) arrays and
+    held (C, Gd, A), scal_f (4,), scal_i (5,), pen (P,), counts0 (G,))."""
     dt = np.dtype(dtype_name)
     P = int(np.asarray(batch.ask_cpu).shape[0])
     P_out = max(P, p_pad or 0)
     N = int(np.asarray(const.cpu_cap).shape[0])
     A = int(np.asarray(ptab.cpu).shape[1])
-    ask_cpu = float(np.asarray(batch.ask_cpu, dtype=dt)[0])
-    ask_mem = float(np.asarray(batch.ask_mem, dtype=dt)[0])
-    ask_disk = float(np.asarray(batch.ask_disk, dtype=dt)[0])
-    count = float(np.asarray(batch.count, dtype=dt)[0])
-    L = int(np.asarray(batch.limit)[0])
-    n_active = int(np.asarray(batch.active).sum())
-    job_prio = int(np.asarray(ptab.job_prio))
-
-    cpu_cap = np.asarray(const.cpu_cap, dtype=dt)
-    mem_cap = np.asarray(const.mem_cap, dtype=dt)
-    disk_cap = np.asarray(const.disk_cap, dtype=dt)
-    used_c = np.asarray(init.used_cpu, dtype=dt)
-    used_m = np.asarray(init.used_mem, dtype=dt)
-    used_d = np.asarray(init.used_disk, dtype=dt)
-    feas = np.asarray(const.feasible, dtype=bool)
-    placed0 = np.asarray(init.placed)
-    placed_job0 = np.asarray(init.placed_job)
-    distinct = bool(np.asarray(const.distinct_hosts))
-    job_level = bool(np.asarray(const.distinct_job_level))
-    distinct_flag = (2 if distinct and job_level
-                     else (1 if distinct else 0))
-
-    dcount0 = placed_job0 if job_level else placed0
-    feas_nonres0 = feas if not distinct else (feas & (dcount0 == 0))
-    # device-dimension capacity (uniform ask, zero affinity weight --
-    # wavefront_ok gates): a node with no eligible group (or drained by
-    # earlier placements, tracked via j in the kernel) is NOT an option,
-    # not even via eviction -- eviction never frees matching devices
-    # (pack() rejects lanes whose evictable candidates hold them), so a
-    # failed device assign skips the node exactly like rank.go:443's
-    # PreemptForDevice returning nil
-    if np.asarray(const.dev_aff).shape[0]:
-        c_dev = _wave_device_capacity(const, init)
-        assert c_dev is not None, "unbounded device capacity replay"
-        dev_ok0 = c_dev >= 1
-    else:
-        c_dev = None
-        dev_ok0 = np.ones(N, dtype=bool)
-    fit0 = (feas_nonres0 & dev_ok0
-            & (used_c + ask_cpu <= cpu_cap)
-            & (used_m + ask_mem <= mem_cap)
-            & (used_d + ask_disk <= disk_cap))
-
-    cvalid = np.asarray(ptab.valid, dtype=bool)               # (N, A)
-    cprio = np.asarray(ptab.prio)
-    ccpu = np.asarray(ptab.cpu, dtype=dt)
-    cmem = np.asarray(ptab.mem, dtype=dt)
-    cdisk = np.asarray(ptab.disk, dtype=dt)
-    cmaxp = np.asarray(ptab.maxp)
-    cgrp = np.asarray(ptab.grp)
-    counts_np = np.asarray(pinit.counts, dtype=np.int64)
-    # pristine eviction outcome, computed EXACTLY (numpy transcription of
-    # _preempt_search_core's greedy + filterSuperset + the fit2 clamp): a
-    # conservative coverage bound here admits nodes the in-step search
-    # can never actually yield, and B such zombies starve the window
-    met0, freed0 = _numpy_preempt_pristine(
-        ccpu, cmem, cdisk, cprio, cmaxp, cgrp, cvalid, counts_np,
-        cpu_cap, mem_cap, disk_cap, job_prio,
-        ask_cpu, ask_mem, ask_disk)
-    fit2g0 = ((used_c + ask_cpu - freed0[0] <= cpu_cap)
-              & (used_m + ask_mem - freed0[1] <= mem_cap)
-              & (used_d + ask_disk - freed0[2] <= disk_cap))
-    option0 = fit0 | (feas_nonres0 & dev_ok0 & ~fit0 & met0 & fit2g0)
-
+    dev_free = np.asarray(init.dev_free)
+    Gd = dev_free.shape[1] if dev_free.shape[0] else 0
+    _fit0, option0 = wavefront_preempt_pristine(const, init, batch, ptab,
+                                                dtype_name)
     fit_pos = np.nonzero(option0)[0][:P_out + B]
     C = P_out + B
-    compact = np.zeros((C, _WPC_NCOLS), dtype=dt)
-    compact[:, _WPC_POS] = -1.0
     k = fit_pos.shape[0]
-    compact[:k, _WPC_FEAS] = feas[fit_pos].astype(dt)
-    compact[:k, _WPC_UC] = used_c[fit_pos]
-    compact[:k, _WPC_UM] = used_m[fit_pos]
-    compact[:k, _WPC_UD] = used_d[fit_pos]
-    compact[:k, _WPC_CC] = cpu_cap[fit_pos]
-    compact[:k, _WPC_CM] = mem_cap[fit_pos]
-    compact[:k, _WPC_CD] = disk_cap[fit_pos]
-    compact[:k, _WPC_PLACED] = placed0[fit_pos].astype(dt)
-    compact[:k, _WPC_PLACED_JOB] = placed_job0[fit_pos].astype(dt)
-    aff = (np.asarray(const.affinity, dtype=dt)
-           if bool(np.asarray(const.has_affinity))
-           else np.zeros(N, dtype=dt))
-    compact[:k, _WPC_AFF] = aff[fit_pos]
-    compact[:k, _WPC_POS] = fit_pos.astype(dt)
-    if c_dev is not None:
-        compact[:k, _WPC_CDEV] = np.minimum(
-            c_dev[fit_pos], P_out + 1).astype(dt)
-    else:
-        compact[:, _WPC_CDEV] = dt.type(_WPC_DEV_UNBOUNDED)
+    compact = np.zeros((C, _WPC_NCOLS + Gd), dtype=dt)
+    compact[:, _WPC_POS] = -1.0
+    cols = ((_WPC_FEAS, np.ones(N, dtype=dt)),
+            (_WPC_UC, init.used_cpu), (_WPC_UM, init.used_mem),
+            (_WPC_UD, init.used_disk), (_WPC_CC, const.cpu_cap),
+            (_WPC_CM, const.mem_cap), (_WPC_CD, const.disk_cap),
+            (_WPC_PLACED, init.placed), (_WPC_PLACED_JOB, init.placed_job),
+            (_WPC_AFF, const.affinity if bool(np.asarray(
+                const.has_affinity)) else np.zeros(N, dtype=dt)),
+            (_WPC_POS, np.arange(N)))
+    for col, arr in cols:
+        compact[:k, col] = np.asarray(arr, dtype=dt)[fit_pos]
+    for g in range(Gd):
+        compact[:k, _WPC_NCOLS + g] = dev_free[0, g, fit_pos]
 
     def take(arr, fill):
-        out = np.full((C, A), fill, dtype=arr.dtype)
+        out = np.full((C,) + arr.shape[1:], fill, dtype=arr.dtype)
         out[:k] = arr[fit_pos]
         return out
 
+    held = (np.transpose(np.asarray(ptab.dev_held, dtype=np.int32),
+                         (1, 0, 2)) if Gd
+            else np.zeros((N, 0, A), dtype=np.int32))
     cand = {
-        "cpu": take(ccpu, dt.type(0)),
-        "mem": take(cmem, dt.type(0)),
-        "disk": take(cdisk, dt.type(0)),
-        "prio": take(cprio.astype(np.int32), np.int32(0)),
+        "cpu": take(np.asarray(ptab.cpu, dtype=dt), dt.type(0)),
+        "mem": take(np.asarray(ptab.mem, dtype=dt), dt.type(0)),
+        "disk": take(np.asarray(ptab.disk, dtype=dt), dt.type(0)),
+        "prio": take(np.asarray(ptab.prio, dtype=np.int32), np.int32(0)),
         "maxp": take(np.asarray(ptab.maxp, dtype=np.int32), np.int32(0)),
         "grp": take(np.asarray(ptab.grp, dtype=np.int32), np.int32(-1)),
-        "valid": take(cvalid, False),
+        "valid": take(np.asarray(ptab.valid, dtype=bool), False),
+        "held": take(held, np.int32(0)),
     }
-    scal_f = np.array([ask_cpu, ask_mem, ask_disk, count], dtype=dt)
-    scal_i = np.array([L, n_active, job_prio, distinct_flag],
-                      dtype=np.int32)
+    distinct = bool(np.asarray(const.distinct_hosts))
+    job_level = bool(np.asarray(const.distinct_job_level))
+    scal_f = np.array([np.asarray(batch.ask_cpu, dtype=dt)[0],
+                       np.asarray(batch.ask_mem, dtype=dt)[0],
+                       np.asarray(batch.ask_disk, dtype=dt)[0],
+                       np.asarray(batch.count, dtype=dt)[0]], dtype=dt)
+    scal_i = np.array([
+        int(np.asarray(batch.limit)[0]), int(np.asarray(batch.active).sum()),
+        int(np.asarray(ptab.job_prio)),
+        2 if distinct and job_level else (1 if distinct else 0),
+        int(np.asarray(const.dev_count).reshape(-1)[0]) if Gd else 0],
+        dtype=np.int32)
     pen = np.full(P_out, -1, dtype=np.int32)
     pen[:P] = np.asarray(batch.penalty_idx, dtype=np.int32)
     counts0 = np.asarray(pinit.counts, dtype=np.int32)
@@ -2480,21 +2600,23 @@ def wavefront_preempt_compact_host(const, init, batch, ptab, pinit,
 def _solve_wave_preempt_impl(compact, cand, scal_f, scal_i, pen, counts0,
                              B: int = WAVE_B, spread_alg: bool = False,
                              dtype_name: str = "float32"):
-    """Device scan for the windowed preemption select. Returns
-    (chosen (P,), scores (P,), n_yielded (P,), evict_rows (P, A))."""
+    """Device scan of the windowed select for a lane whose select without
+    eviction finds no node at its start (wavefront_preempt_pristine):
+    each step is selectNextOption over the B slots -- the slots that fit
+    without eviction, which can only be nodes this lane evicted on, and
+    where none does, the slots' evicting options, each the dense
+    kernel's (_score_and_select_evict). Returns (chosen (P,), scores
+    (P,), n_yielded (P,), evict_rows (P, A))."""
     dtype = jnp.dtype(dtype_name)
     C = compact.shape[0]
     A = cand["cpu"].shape[1]
+    Gd = compact.shape[1] - _WPC_NCOLS
     P = C - B
     G = counts0.shape[0]
-    ask_cpu = scal_f[0]
-    ask_mem = scal_f[1]
-    ask_disk = scal_f[2]
-    count = scal_f[3]
-    L = scal_i[0]
-    n_active = scal_i[1]
-    job_prio = scal_i[2]
-    distinct_flag = scal_i[3]
+    ask_cpu, ask_mem, ask_disk, count = (scal_f[0], scal_f[1], scal_f[2],
+                                         scal_f[3])
+    L, n_active, job_prio, distinct_flag, dev_count = (
+        scal_i[0], scal_i[1], scal_i[2], scal_i[3], scal_i[4])
 
     slot0 = compact[:B]
     cand0 = {k: v[:B] for k, v in cand.items()}
@@ -2505,74 +2627,79 @@ def _solve_wave_preempt_impl(compact, cand, scal_f, scal_i, pen, counts0,
     arangeC = jnp.arange(C, dtype=jnp.int32)
     neg_inf = jnp.array(-jnp.inf, dtype=dtype)
     big = jnp.iinfo(jnp.int32).max
+    # static-length greedy on TPU (a dynamic-trip-count loop of tiny
+    # (B, A) ops inside a scan step is per-iteration sync latency);
+    # early-exit while_loop on CPU
+    static_iters = jax.default_backend() == "tpu"
 
     def option_state(slot, cd, j, evicted, counts):
-        """Per-slot fit/preempt status + scores against current state."""
+        """Per-slot fit / evicting option and their scores now."""
         jf = j.astype(dtype)
-        freed_prev_c = jnp.sum(jnp.where(evicted, cd["cpu"], 0.0), axis=1)
-        freed_prev_m = jnp.sum(jnp.where(evicted, cd["mem"], 0.0), axis=1)
-        freed_prev_d = jnp.sum(jnp.where(evicted, cd["disk"], 0.0), axis=1)
-        used_now_c = slot[:, _WPC_UC] + jf * ask_cpu - freed_prev_c
-        used_now_m = slot[:, _WPC_UM] + jf * ask_mem - freed_prev_m
-        used_now_d = slot[:, _WPC_UD] + jf * ask_disk - freed_prev_d
-        new_c = used_now_c + ask_cpu
-        new_m = used_now_m + ask_mem
-        new_d = used_now_d + ask_disk
 
+        def freed(rows):
+            return [jnp.sum(jnp.where(rows, cd[k], 0.0), axis=1)
+                    for k in ("cpu", "mem", "disk")]
+        gone = freed(evicted)
+        new_c = slot[:, _WPC_UC] + jf * ask_cpu - gone[0] + ask_cpu
+        new_m = slot[:, _WPC_UM] + jf * ask_mem - gone[1] + ask_mem
+        new_d = slot[:, _WPC_UD] + jf * ask_disk - gone[2] + ask_disk
+        caps = (slot[:, _WPC_CC], slot[:, _WPC_CM], slot[:, _WPC_CD])
         dcount = jnp.where(distinct_flag == 2,
                            slot[:, _WPC_PLACED_JOB] + jf,
                            slot[:, _WPC_PLACED] + jf)
-        # device capacity countdown: each landed placement (j) consumed
-        # one unit; a drained node stops being an option entirely (no
-        # eviction can free matching devices -- pack() gates on that)
-        dev_ok = slot[:, _WPC_CDEV] - jf >= 1.0
-        feas_nonres = ((slot[:, _WPC_FEAS] > 0.5) & dev_ok
+        feas_nonres = ((slot[:, _WPC_FEAS] > 0.5)
                        & ((distinct_flag == 0) | (dcount == 0.0)))
-        fit = (feas_nonres
-               & (new_c <= slot[:, _WPC_CC])
-               & (new_m <= slot[:, _WPC_CM])
-               & (new_d <= slot[:, _WPC_CD]))
+        free = slot[:, _WPC_NCOLS:].astype(jnp.int32)         # (B, Gd)
+        dev_ok = (jnp.any(free >= dev_count, axis=1) if Gd
+                  else jnp.ones(B, dtype=bool))
+        fit = (feas_nonres & dev_ok & (new_c <= caps[0])
+               & (new_m <= caps[1]) & (new_d <= caps[2]))
 
         valid_now = cd["valid"] & ~evicted
         eligible = valid_now & (job_prio - cd["prio"] >= 10)
-        # static-length greedy on TPU (a dynamic-trip-count loop of tiny
-        # (B, A) ops inside a scan step is per-iteration sync latency);
-        # early-exit while_loop on CPU (the search usually needs only a
-        # few picks, and full-A straight-line code costs more than the
-        # saved dispatches there)
-        import jax as _jax
-        met, evict, freed_c, freed_m, freed_d, net_prio = \
-            _preempt_search_core(
-                cd["cpu"], cd["mem"], cd["disk"], cd["prio"], cd["maxp"],
-                cd["grp"], valid_now, eligible, slot[:, _WPC_CC],
-                slot[:, _WPC_CM], slot[:, _WPC_CD], counts,
-                ask_cpu, ask_mem, ask_disk, dtype,
-                static_iters=_jax.default_backend() == "tpu")
-        fit2 = ((new_c - freed_c <= slot[:, _WPC_CC])
-                & (new_m - freed_m <= slot[:, _WPC_CM])
-                & (new_d - freed_d <= slot[:, _WPC_CD]))
-        fit_p = feas_nonres & ~fit & met & fit2
+        taken = jnp.zeros((B, A), dtype=bool)
+        dev_part = dev_ok
+        if Gd:
+            best = jnp.full(B, big, dtype=jnp.int32)
+            for g in range(Gd):
+                met_g, taken_g, net_g = _device_preempt_group(
+                    cd["held"][:, g], eligible, cd["prio"],
+                    jnp.maximum(free[:, g], 0), dev_count)
+                better = met_g & (free[:, g] >= 0) & (net_g < best)
+                best = jnp.where(better, net_g, best)
+                taken = jnp.where(better[:, None], taken_g, taken)
+            taken = taken & ~dev_ok[:, None]
+            dev_part = dev_ok | (best < big)
+        freed_dev = freed(taken)
+        use_c = new_c - freed_dev[0]
+        use_m = new_m - freed_dev[1]
+        use_d = new_d - freed_dev[2]
+        fits = (use_c <= caps[0]) & (use_m <= caps[1]) & (use_d <= caps[2])
+        met, tg_evict, _fc, _fm, _fd, _net = _preempt_search_core(
+            cd["cpu"], cd["mem"], cd["disk"], cd["prio"], cd["maxp"],
+            cd["grp"], valid_now & ~taken, eligible & ~taken, caps[0],
+            caps[1], caps[2], counts, ask_cpu, ask_mem, ask_disk, dtype,
+            static_iters=static_iters)
+        evict = taken | (tg_evict & ~fits[:, None])
+        fit_p = (feas_nonres & dev_part & ~fit & (fits | met)
+                 & jnp.any(evict, axis=1))
 
-        # scoring (mirrors _score_and_select_preempt on the slot axis)
-        free_cpu = 1.0 - new_c / jnp.maximum(slot[:, _WPC_CC], 1e-9)
-        free_mem = 1.0 - new_m / jnp.maximum(slot[:, _WPC_CM], 1e-9)
-        binpack = _binpack_score(free_cpu, free_mem, spread_alg)
-        free_cpu_p = 1.0 - (new_c - freed_c) / jnp.maximum(
-            slot[:, _WPC_CC], 1e-9)
-        free_mem_p = 1.0 - (new_m - freed_m) / jnp.maximum(
-            slot[:, _WPC_CM], 1e-9)
-        binpack_p = _binpack_score(free_cpu_p, free_mem_p, spread_alg)
-        pscore = 1.0 / (1.0 + jnp.exp(
-            PREEMPT_SCORE_RATE * (net_prio - PREEMPT_SCORE_ORIGIN)))
-        return (fit, fit_p, binpack, binpack_p, pscore, evict,
-                freed_c, freed_m, freed_d)
+        def binpack_of(uc, um):
+            return _binpack_score(1.0 - uc / jnp.maximum(caps[0], 1e-9),
+                                  1.0 - um / jnp.maximum(caps[1], 1e-9),
+                                  spread_alg)
+        prio_f = cd["prio"].astype(dtype)
+        mx = jnp.max(jnp.where(evict, prio_f, 0.0), axis=1)
+        sm = jnp.sum(jnp.where(evict, prio_f, 0.0), axis=1)
+        net_prio = jnp.where(mx > 0, mx + sm / jnp.maximum(mx, 1e-9), 0.0)
+        return (fit, fit_p, binpack_of(new_c, new_m), binpack_of(use_c, use_m),
+                net_prio, evict)
 
     def step(carry, xs):
         i, pen_i = xs
         j, slot, cd, evicted, cursor, counts, pending = carry
 
-        (fit, fit_p, binpack, binpack_p, pscore, evict,
-         freed_c, freed_m, freed_d) = option_state(
+        fit, fit_p, binpack, binpack_p, net_prio, evict = option_state(
             slot, cd, j, evicted, counts)
 
         coll = slot[:, _WPC_PLACED] + j.astype(dtype)
@@ -2584,10 +2711,12 @@ def _solve_wave_preempt_impl(compact, cand, scal_f, scal_i, pen, counts0,
         nscores = (1.0 + (coll > 0).astype(dtype)
                    + is_pen.astype(dtype) + (affs != 0.0).astype(dtype))
         other = anti + resched + affs
-        final_plain = (binpack + other) / nscores
-        final_pre = (binpack_p + other + pscore) / (nscores + 1.0)
-        fit_c = fit | fit_p
-        final = jnp.where(fit_p, final_pre, final_plain)
+        # selectNextOption: eviction only where no slot fits without it
+        plain = jnp.any(fit)
+        fit_c = jnp.where(plain, fit, fit_p)
+        evicting, tie = _evicting_mean(binpack_p + other, nscores, net_prio)
+        final = jnp.where(plain, (binpack + other) / nscores, evicting)
+        tie = jnp.where(plain, 0.0, tie)
 
         low = fit_c & (final <= SKIP_THRESHOLD)
         skip_rank = jnp.cumsum(low.astype(jnp.int32))
@@ -2603,7 +2732,7 @@ def _solve_wave_preempt_impl(compact, cand, scal_f, scal_i, pen, counts0,
         order = jnp.where(window, cpos, L + srank)
         eff = jnp.where(yielded, final, neg_inf)
         best = jnp.max(eff)
-        is_best = yielded & (eff == best)
+        is_best = _best_tie(yielded & (eff == best), tie, neg_inf)
         border = jnp.min(jnp.where(is_best, order, big))
         w = jnp.argmax(is_best & (order == border))
         any_yield = jnp.any(yielded)
@@ -2616,9 +2745,10 @@ def _solve_wave_preempt_impl(compact, cand, scal_f, scal_i, pen, counts0,
         score_out = jnp.where(any_yield, best, neg_inf)
         ny = jnp.sum(yielded.astype(jnp.int32))
 
-        # commit: the winner takes one copy; a preempting winner applies
-        # its eviction row and bumps the per-group counts
-        was_pre = jnp.any(oh_w & fit_p) & do
+        # commit: the winner takes one copy; an evicting winner applies
+        # its eviction row, gives back the instances it frees and bumps
+        # the per-group counts
+        was_pre = ~plain & do
         evict_w = evict & oh_w[:, None] & was_pre
         evict_row_out = jnp.any(evict_w, axis=0)                # (A,)
         do_i = do.astype(jnp.int32)
@@ -2629,26 +2759,38 @@ def _solve_wave_preempt_impl(compact, cand, scal_f, scal_i, pen, counts0,
                    & (cd["grp"] >= 0)[:, :, None]
                    & evict_w[:, :, None])
         counts2 = counts + jnp.sum(grp_hot, axis=(0, 1)).astype(jnp.int32)
+        if Gd:
+            free = slot[:, _WPC_NCOLS:]
+            back = jnp.sum(jnp.where(evict_w[:, None, :], cd["held"], 0),
+                           axis=2).astype(dtype)                # (B, Gd)
+            free = jnp.where(free >= 0, free + back, free)
+            # the placement takes its instances from the first group
+            # with enough free (zero affinity weight: device.go's best)
+            ok = free >= dev_count.astype(dtype)
+            g_star = jnp.argmax(ok, axis=1)
+            take_g = ((jnp.arange(Gd)[None, :] == g_star[:, None])
+                      & oh_w[:, None] & do)
+            free = free - take_g.astype(dtype) * dev_count.astype(dtype)
+            slot = slot.at[:, _WPC_NCOLS:].set(free)
 
         # shift-out, DEFERRED one step: this step's search already gives
         # every slot's exact option status, and a committed winner's state
         # only changes at its commit -- so the PREVIOUS winner ("pending")
-        # is a zombie iff it is not an option NOW. Deferring avoids a
-        # second in-step search; at most one zombie occupies the buffer
-        # for one step (never counted -- fit_c is False -- so the window
-        # semantics are unaffected while B >= L + MAX_SKIP + 1). Entries
-        # are exact options by the host's pristine predicate, so zombies
-        # only ever arise from winners.
+        # is a zombie iff it is no option NOW, with eviction or without.
+        # At most one zombie occupies the buffer for one step (never
+        # counted, so the window is unaffected while B >= L + MAX_SKIP +
+        # 1). Entries are exact options by the host's pristine predicate,
+        # so zombies only ever arise from winners.
         z = jnp.maximum(pending, 0)
         oh_z = arangeB == z
-        zomb = (pending >= 0) & ~jnp.any(oh_z & fit_c)
+        zomb = (pending >= 0) & ~jnp.any(oh_z & (fit | fit_p))
         oh_c = arangeC == jnp.clip(cursor, 0, C - 1)
         entry_row = jnp.sum(jnp.where(oh_c[:, None], compact, 0.0),
                             axis=0)
         entry_cd = {
-            kk: jnp.sum(jnp.where(oh_c[:, None], vv,
-                                  jnp.zeros((), dtype=vv.dtype)),
-                        axis=0).astype(vv.dtype)
+            kk: jnp.sum(jnp.where(
+                oh_c.reshape((C,) + (1,) * (vv.ndim - 1)), vv,
+                jnp.zeros((), dtype=vv.dtype)), axis=0).astype(vv.dtype)
             for kk, vv in cand.items()}
         take_next = arangeB >= z
         is_last = arangeB == B - 1
@@ -2712,12 +2854,11 @@ def _wave_preempt_program(cm_shape, cd_shape, c0_shape,
 
 def solve_lane_wave_preempt(const, init, batch, ptab, pinit, *,
                             spread_alg: bool, dtype_name: str,
-                            batched: bool = False, cache_version=None,
-                            delta_src=None):
+                            batched: bool = False, cache_version=None):
     """Windowed-preemption solve with host precompute + compact transfer;
     returns host numpy (chosen int64, scores, n_yielded int64,
     evict_rows (P, A) bool), shaped like solve_lane_fused's preempt
-    outputs. Callers gate on wavefront_preempt_ok."""
+    outputs. Callers gate on PackedLane.wavefront_ok."""
     S_dim = np.asarray(const.spread_vidx).shape[1 if batched else 0]
     if S_dim:
         raise ValueError(
@@ -2733,7 +2874,7 @@ def solve_lane_wave_preempt(const, init, batch, ptab, pinit, *,
     B = wavefront_buffer_size(L)
     if B is None:
         raise ValueError(f"lane limit {L} exceeds every wavefront buffer "
-                         "width (caller must gate on wavefront_preempt_ok)")
+                         "width (callers gate on PackedLane.wavefront_ok)")
     p_pad = _wave_p_bucket(P)
     if batched:
         active_rows = np.asarray(batch.active).any(axis=1)
@@ -2770,11 +2911,14 @@ def solve_lane_wave_preempt(const, init, batch, ptab, pinit, *,
                                counts0.shape, spread_alg, dtype_name,
                                batched, B)
     stages.mark("put")
+    # no version chain: the tables are each eval's evicting options in its
+    # own scan order and change wholesale between dispatches, and the
+    # chain's scatter programs, one an update-count bucket and table
+    # shape, would be compiles a paced window keeps meeting
     cm, cd, sf, si, pn, c0 = _put_eval_sharded(
         batched, compact.shape[0],
         (compact, cand, scal_f, scal_i, pen, counts0),
-        cache_version=cache_version, tag="compact_preempt",
-        delta_src=delta_src)
+        cache_version=cache_version, tag="compact_preempt")
     stages.mark("launch")
     out = fn(cm, cd, sf, si, pn, c0)
     stages.mark("fetch")

@@ -110,46 +110,46 @@ class PackedLane:
         """Can this lane route through the O(B)-per-step wavefront path
         (binpack.solve_lane_wave -- host precompute + compact scan)?
         Requires uniform asks over the active prefix, a window that fits
-        a buffer variant (limit+skips <= WAVE_B or WAVE_B_WIDE), and none
-        of distinct_property/devices/cores/preemption. Spreads,
-        affinities and reschedule penalties ARE modeled (spread counts
-        ride the carry; penalties ride the scan xs)."""
+        a buffer variant (limit+skips <= WAVE_B or WAVE_B_WIDE), and no
+        distinct_property or cores. Spreads, affinities, reschedule
+        penalties and uniform device asks ARE modeled (spread counts
+        ride the carry; penalties ride the scan xs); a preempting lane
+        takes the windowed preemption kernel where no node fits its ask
+        without eviction (_wavefront_check)."""
         if self._wave is not None:
             return self._wave
         self._wave = self._wavefront_check()
         return self._wave
 
     def _wavefront_check(self) -> bool:
-        from .binpack import wavefront_buffer_size
-        if self.ptab is not None:
-            # windowed preemption (solve_lane_wave_preempt): spreads stay
-            # dense (the preempt slot kernel carries no spread columns);
-            # networks/cores are excluded for preempt lanes by
-            # tg_solver_eligible(preempt=True); devices ride via the
-            # capacity-countdown column when _wave_devices_ok passes
-            # (checked in the shared section below)
-            if self.const.spread_vidx.shape[0]:
-                return False
-            # max_parallel penalties couple the greedy's pick ORDER to the
-            # evolving per-group eviction counts; the picked set feeds
-            # fit2, so a node's option status would no longer be static
-            # outside the window -- the invariant the windowed design
-            # rests on. Those lanes stay dense.
-            if bool(np.any(np.asarray(self.ptab.maxp)[
-                    np.asarray(self.ptab.valid)] > 0)):
-                return False
-            # the deferred zombie occupies one slot for a step: the
-            # window must still fit beside it
-            from .binpack import MAX_SKIP
-            lim = int(np.asarray(self.batch.limit)[0])
-            b = wavefront_buffer_size(lim)
-            if b is None or lim + MAX_SKIP + 1 > b:
-                return False
+        from .binpack import (MAX_SKIP, wavefront_buffer_size,
+                              wavefront_preempt_pristine)
         c = self.const
         if c.dp_vidx.shape[0] or c.mhz_per_core.shape[0]:
             return False
+        if self.ptab is not None:
+            # windowed preemption (solve_lane_wave_preempt): no spread
+            # columns, one device ask without affinities, a window that
+            # fits beside the deferred zombie's slot, and no node the
+            # ask fits without eviction at the lane's start
+            R = c.dev_aff.shape[0]
+            lim = int(np.asarray(self.batch.limit)[0])
+            buf = wavefront_buffer_size(lim)
+            if (c.spread_vidx.shape[0] or R > 1
+                    or (R and float(np.asarray(c.dev_sum_weight)))
+                    or buf is None or lim + MAX_SKIP + 1 > buf
+                    or not self._uniform_asks()):
+                return False
+            fit0, _ = wavefront_preempt_pristine(
+                c, self.init, self.batch, self.ptab, self.dtype_name)
+            return not fit0.any()
         if c.dev_aff.shape[0] and not self._wave_devices_ok():
             return False
+        return self._uniform_asks() and wavefront_buffer_size(
+            int(np.asarray(self.batch.limit)[0])) is not None
+
+    def _uniform_asks(self) -> bool:
+        """The active placements are a prefix and ask alike."""
         b = self.batch
         act = np.asarray(b.active)
         n_act = int(act.sum())
@@ -160,17 +160,14 @@ class PackedLane:
             v = np.asarray(arr)[:n_act]
             if not (v == v[0]).all():
                 return False
-        return wavefront_buffer_size(
-            int(np.asarray(b.limit)[0])) is not None
+        return True
 
     def _wave_devices_ok(self) -> bool:
         """Uniform device asks ride the wavefront as a pure capacity
         dimension (binpack._wave_device_capacity) when the dense device
         SCORE vanishes (zero affinity weight -> the dense kernel's
         device component is exactly 0) and the host capacity replay is
-        bounded. Candidate-held matching devices are rejected at pack
-        time (pack returns None -> host fallback), so eviction can
-        never change device availability."""
+        bounded."""
         c = self.const
         if float(np.asarray(c.dev_sum_weight)) != 0.0:
             return False
@@ -215,9 +212,9 @@ class PackedLane:
 def tg_solver_eligible(tg, job=None, preempt: bool = False) -> bool:
     """Does the dense path model everything this TG asks for? The
     remaining carve-outs (host iterator fallback):
-      - preemption combined with ports, devices or cores (network/device
-        preemption are subset searches, preemption.go:273,475; core
-        release needs id-level accounting)
+      - preemption combined with ports or cores (network preemption is
+        a subset search, preemption.go:273; core release needs id-level
+        accounting)
       - 0%-spread targets (the host's lowest-boost scoring depends on the
         scanned-prefix order, which couples window membership to scores)
     Devices, distinct_property AND reserved cores are modeled densely
@@ -237,12 +234,9 @@ def tg_solver_eligible(tg, job=None, preempt: bool = False) -> bool:
     if len(tg.networks) > 1:
         return False
     if preempt and (tg.networks or has_cores):
-        # devices + preemption ARE modeled (dense feas_nonres gates
-        # device-infeasible nodes out of the eviction path exactly like
-        # rank.go:443's nil PreemptForDevice; the windowed kernel
-        # carries a capacity countdown column) -- EXCEPT when evicting
-        # a candidate would free matching instances, which pack()
-        # detects and routes to the host iterator
+        # one device ask and preemption ARE modeled (PreemptForDevice in
+        # both kernels); several device asks whose evictions would free
+        # matching instances pack() routes to the host iterator
         return False
     spreads = list(tg.spreads) + (list(job.spreads) if job is not None else [])
     for s in spreads:
@@ -315,6 +309,124 @@ def dispatch_lane(lane: PackedLane):
         spread_alg=lane.spread_alg, dtype_name=lane.dtype_name,
         wave=wave, cache_version=lane.table_version,
         delta_src=lane.delta_src)
+
+
+class _PreemptBase:
+    """A snapshot's eviction candidates in node order (matrix.node_ids),
+    one row an alloc each node's proposed allocs hold in the store's
+    order (EvalContext.proposed_allocs with an empty plan), shared by
+    every preempting eval packed from that snapshot: an eval takes its
+    scan order's rows and masks its own job's. The base of one index is
+    caught up to the next from the store's alloc-delta journal by
+    refolding the nodes its pairs touch."""
+
+    __slots__ = ("allocs", "cpu", "mem", "disk", "prio", "maxp", "gid",
+                 "jid", "valid", "held", "groups", "jobs", "A")
+
+    @classmethod
+    def build(cls, snap, node_ids, nodes, n_pad):
+        base = cls()
+        base.allocs = [[a for a in snap.allocs_by_node(nid)
+                        if not a.released_from_node()] for nid in node_ids]
+        base.groups, base.jobs = {}, {}
+        base.A = int(2 ** np.ceil(np.log2(max(
+            max((len(r) for r in base.allocs), default=1), 8))))
+        n_dev = max((len(n.node_resources.devices) for n in nodes),
+                    default=0)
+        shape = (n_pad, base.A)
+        base.cpu, base.mem, base.disk = (np.zeros(shape) for _ in range(3))
+        base.prio, base.maxp = (np.zeros(shape, dtype=np.int32)
+                                for _ in range(2))
+        base.gid, base.jid = (np.full(shape, -1, dtype=np.int32)
+                              for _ in range(2))
+        base.valid = np.zeros(shape, dtype=bool)
+        base.held = np.zeros((n_dev,) + shape, dtype=np.int32)
+        for i, node in enumerate(nodes):
+            base._fold(i, node)
+        return base
+
+    def caught_up(self, snap, pairs, node_ids, nodes):
+        """A copy at the snapshot ``pairs`` lead to, or None where a node
+        outgrew the candidate axis."""
+        dirty = {a.node_id for pair in pairs for a in pair if a is not None}
+        pos = {nid: i for i, nid in enumerate(node_ids)}
+        idx = sorted(pos[n] for n in dirty if n in pos)
+        rows = {i: [a for a in snap.allocs_by_node(node_ids[i])
+                    if not a.released_from_node()] for i in idx}
+        if any(len(r) > self.A for r in rows.values()):
+            return None
+        out = _PreemptBase()
+        for k in self.__slots__:
+            v = getattr(self, k)
+            setattr(out, k, v.copy() if isinstance(v, np.ndarray) else v)
+        out.allocs = list(self.allocs)
+        out.groups, out.jobs = dict(self.groups), dict(self.jobs)
+        for i in idx:
+            out.allocs[i] = rows[i]
+            out._fold(i, nodes[i])
+        return out
+
+    def _fold(self, i, node) -> None:
+        for arr, fill in ((self.cpu, 0.0), (self.mem, 0.0), (self.disk, 0.0),
+                          (self.prio, 0), (self.maxp, 0), (self.gid, -1),
+                          (self.jid, -1), (self.valid, False)):
+            arr[i] = fill
+        self.held[:, i] = 0
+        dev_of = {g.id_string(): g_i for g_i, g in enumerate(
+            node.node_resources.devices)}
+        for a_i, alloc in enumerate(self.allocs[i]):
+            cr = alloc.allocated_resources.comparable()
+            self.cpu[i, a_i] = cr.cpu_shares
+            self.mem[i, a_i] = cr.memory_mb
+            self.disk[i, a_i] = cr.disk_mb
+            self.prio[i, a_i] = (alloc.job.priority if alloc.job is not None
+                                 else 50)
+            if alloc.job is not None:
+                atg = alloc.job.lookup_task_group(alloc.task_group)
+                if atg is not None and atg.migrate is not None:
+                    self.maxp[i, a_i] = atg.migrate.max_parallel
+            self.gid[i, a_i] = self.groups.setdefault(
+                (alloc.namespace, alloc.job_id, alloc.task_group),
+                len(self.groups))
+            self.jid[i, a_i] = self.jobs.setdefault(
+                (alloc.namespace, alloc.job_id), len(self.jobs))
+            self.valid[i, a_i] = (alloc.job is not None
+                                  and not alloc.terminal_status())
+            for tr in (alloc.allocated_resources.tasks.values()
+                       if dev_of else ()):
+                for d in tr.devices:
+                    g_i = dev_of.get(d.id_string())
+                    if g_i is not None:
+                        self.held[g_i, i, a_i] += len(d.device_ids)
+
+
+def _preempt_base(snap, matrix, nodes):
+    """The snapshot's _PreemptBase, memoized on the matrix for the few
+    newest state indexes (barrier workers pack from several at once)."""
+    token = snap.latest_index()
+    store = getattr(snap, "_store", snap)
+    memo = matrix.__dict__.get("_preempt_bases")
+    if memo is None or memo[0] is not store:
+        memo = (store, {})
+        matrix._preempt_bases = memo
+    bases = memo[1]
+    base = bases.get(token)
+    if base is not None:
+        return base
+    older = [t for t in list(bases) if t < token]
+    deltas_fn = getattr(store, "alloc_deltas_since", None)
+    if older and deltas_fn is not None:
+        t0 = max(older)
+        covered, pairs = deltas_fn(t0, upto=token)
+        if covered:
+            base = bases[t0].caught_up(snap, pairs, matrix.node_ids, nodes)
+    if base is None:
+        base = _PreemptBase.build(snap, matrix.node_ids, nodes,
+                                  matrix.n_pad)
+    bases[token] = base
+    for t in sorted(bases)[:-4]:
+        bases.pop(t, None)
+    return base
 
 
 class _DeviceShim:
@@ -487,14 +599,20 @@ class TpuPlacementService:
         # needed anyway -- do that walk ONCE and reuse it for usage
         # packing too (instead of the alloc-table fast path).
         ask_cores_total = sum(t.resources.cores for t in tg.tasks)
-        proposed_by_node = None
-        if self.preempt or ask_cores_total > 0:
+        proposed_by_node = base = None
+        plan = self.ctx.plan
+        if (self.preempt and not ask_cores_total and not plan.node_update
+                and not plan.node_preemptions and not plan.node_allocation):
+            # nothing of this eval's plan on any node yet: its candidates
+            # are the snapshot's, shared with every eval packed from it
+            base = _preempt_base(self.ctx.state, matrix, nodes)
+        elif self.preempt or ask_cores_total > 0:
             proposed_by_node = {
                 node.id: self.ctx.proposed_allocs(node.id) for node in nodes}
         table = getattr(self.ctx.state, "alloc_table", None)
         usage_index = state_index
         if (table is not None and not table.has_port_overflow
-                and proposed_by_node is None):
+                and proposed_by_node is None and base is None):
             usage, usage_index = self._pack_usage_from_table(
                 table, matrix, nodes, tg)
         else:
@@ -609,28 +727,35 @@ class TpuPlacementService:
 
         requests = [r for t in tg.tasks for r in t.resources.devices]
         if requests:
-            if proposed_by_node is None:
+            if proposed_by_node is None and base is None:
                 proposed_by_node = {
                     node.id: self.ctx.proposed_allocs(node.id)
                     for node in nodes}
             dev = self._pack_devices(tg, requests, nodes, order, n_pad,
-                                     proposed_by_node, dtype)
+                                     proposed_by_node, dtype, base)
             const = const._replace(dev_aff=dev[0], dev_count=dev[1],
                                    dev_sum_weight=dev[2])
             init = init._replace(dev_free=dev[3])
 
         ptab = pinit = cand_allocs = None
         if self.preempt:
-            ptab, pinit, cand_allocs = self._pack_preemption(
-                tg, nodes, order, n_pad, dtype, proposed_by_node)
-            if requests and cand_allocs is not None and \
-                    self._cands_hold_matching_devices(requests,
-                                                      cand_allocs,
-                                                      ptab):
-                # evicting such a candidate frees matching device
-                # instances (rank.go:443 PreemptForDevice territory) --
-                # neither the dense nor the windowed preempt kernel
-                # models device release; the host iterator does
+            n_groups = const.dev_aff.shape[1] if requests else 0
+            if base is not None:
+                ptab, pinit, cand_allocs = self._preemption_from_base(
+                    base, order, n_pad, dtype, n_groups)
+            else:
+                ptab, pinit, cand_allocs = self._pack_preemption(
+                    tg, nodes, order, n_pad, dtype, proposed_by_node,
+                    n_groups)
+            if (requests and (len(requests) > 1
+                              or float(np.asarray(const.dev_sum_weight)))
+                    and self._cands_hold_matching_devices(
+                        requests, cand_allocs, ptab)):
+                # the kernels evict for one device ask without device
+                # affinities (PreemptForDevice); several asks, each
+                # assigned in turn against what the last one evicted, or
+                # an affinity score of the freed group, stay with the
+                # host iterator
                 from ..server.telemetry import metrics as _tm
                 _tm.incr("nomad.solver.device_preempt_host_fallback")
                 return None
@@ -748,10 +873,11 @@ class TpuPlacementService:
         return vidx, limits, tg_scope, counts
 
     def _pack_devices(self, tg, requests, nodes, order, n_pad,
-                      proposed_by_node, dtype):
+                      proposed_by_node, dtype, base=None):
         """Device tables (feasible.go:1270 DeviceChecker + device.go
         allocator): per request r and matching node group g, the affinity
-        score and free instance count (capacity minus proposed usage)."""
+        score and free instance count (capacity minus proposed usage,
+        from ``base``'s holdings where the eval packs from one)."""
         from ..scheduler.rank import DeviceAllocator
 
         R = len(requests)
@@ -777,11 +903,17 @@ class TpuPlacementService:
             if not groups:
                 continue
             node = nodes[order[pos]]
-            allocator = DeviceAllocator(self.ctx, node)
-            allocator.add_allocs(proposed_by_node[node.id])
+            if base is None:
+                allocator = DeviceAllocator(self.ctx, node)
+                allocator.add_allocs(proposed_by_node[node.id])
             for g_i, group in enumerate(groups):
-                used = allocator.used.get(group.id_string(), set())
-                n_free = sum(1 for i in group.instance_ids if i not in used)
+                if base is not None:
+                    n_free = len(group.instance_ids) - int(
+                        base.held[g_i, order[pos]].sum())
+                else:
+                    used = allocator.used.get(group.id_string(), set())
+                    n_free = sum(1 for i in group.instance_ids
+                                 if i not in used)
                 for r_i, req in enumerate(requests):
                     if not group.matches_request(req.name):
                         continue
@@ -813,14 +945,15 @@ class TpuPlacementService:
         return score
 
     def _pack_preemption(self, tg, nodes, order, n_pad, dtype,
-                         proposed_by_node):
+                         proposed_by_node, n_groups: int = 0):
         """Build PreemptTables in shuffled node order: every proposed alloc
         becomes a candidate row (rows keep proposed_allocs order so dense
         argmin ties break like the host's in-order scan); ineligible rows
         (own job, terminal) are masked invalid
-        (reference: preemption.go setCandidates/filterAndGroup :666)."""
+        (reference: preemption.go setCandidates/filterAndGroup :666).
+        With ``n_groups`` (the lane's device axis), the instances of each
+        of the node's device groups every candidate holds."""
         from .binpack import PreemptState, PreemptTables
-        import jax.numpy as jnp
 
         per_node = []          # shuffled order: list of candidate allocs
         max_a = 1
@@ -842,6 +975,7 @@ class TpuPlacementService:
         dyn_ports = np.zeros((n_pad, A), dtype=np.int32)
         static_rel = np.zeros((n_pad, A), dtype=bool)
         valid = np.zeros((n_pad, A), dtype=bool)
+        dev_held = np.zeros((n_groups, n_pad, A), dtype=np.int32)
 
         group_idx: Dict[Tuple[str, str, str], int] = {}
         # dyn_ports/static_rel stay zero: preempt-eligible TGs never ask
@@ -850,7 +984,16 @@ class TpuPlacementService:
         # network-preemption path (preemption.go:273).
 
         for pos, allocs in enumerate(per_node):
+            groups = ({g.id_string(): g_i for g_i, g in enumerate(
+                nodes[order[pos]].node_resources.devices)}
+                if n_groups and allocs else {})
             for a_i, alloc in enumerate(allocs[:A]):
+                for tr in (alloc.allocated_resources.tasks.values()
+                           if groups else ()):
+                    for d in tr.devices:
+                        g_i = groups.get(d.id_string())
+                        if g_i is not None:
+                            dev_held[g_i, pos, a_i] += len(d.device_ids)
                 cr = alloc.allocated_resources.comparable()
                 cpu[pos, a_i] = cr.cpu_shares
                 mem[pos, a_i] = cr.memory_mb
@@ -863,10 +1006,6 @@ class TpuPlacementService:
                     if atg is not None and atg.migrate is not None:
                         mp = atg.migrate.max_parallel
                 maxp[pos, a_i] = mp
-                key = (alloc.namespace, alloc.job_id, alloc.task_group)
-                if key not in group_idx:
-                    group_idx[key] = len(group_idx)
-                grp[pos, a_i] = group_idx[key]
                 # host set_candidates/filter skips own-job, terminal and
                 # job-less allocs (scheduler/preemption.py:58,91-94)
                 valid[pos, a_i] = (
@@ -874,6 +1013,11 @@ class TpuPlacementService:
                     and (alloc.namespace, alloc.job_id)
                     != (self.job.namespace, self.job.id)
                     and not alloc.terminal_status())
+                if valid[pos, a_i] and self.job.priority - p >= 10:
+                    # an eviction count only an evictable alloc can meet
+                    key = (alloc.namespace, alloc.job_id, alloc.task_group)
+                    grp[pos, a_i] = group_idx.setdefault(key,
+                                                         len(group_idx))
 
         G = int(2 ** np.ceil(np.log2(max(len(group_idx), 4))))
         counts = np.zeros(G, dtype=np.int32)
@@ -887,10 +1031,50 @@ class TpuPlacementService:
         ptab = PreemptTables(
             cpu=cpu, mem=mem, disk=disk, prio=prio, maxp=maxp, grp=grp,
             dyn_ports=dyn_ports, static_rel=static_rel, valid=valid,
-            job_prio=np.asarray(self.job.priority, dtype=np.int32))
+            job_prio=np.asarray(self.job.priority, dtype=np.int32),
+            dev_held=dev_held)
         pinit = PreemptState(
             evicted=np.zeros((n_pad, A), dtype=bool), counts=counts)
         return ptab, pinit, per_node
+
+    def _preemption_from_base(self, base: _PreemptBase, order, n_pad,
+                              dtype, n_groups: int):
+        """_pack_preemption's tables from a snapshot's shared base:
+        its rows in this eval's scan order, this job's masked, the
+        evictable ones' groups numbered in the order of their ids."""
+        from .binpack import PreemptState, PreemptTables
+        n, A = len(order), base.A
+        order = np.asarray(order, dtype=np.int64)
+
+        def rows(arr, fill):
+            out = np.full((n_pad, A), fill, dtype=arr.dtype)
+            out[:n] = arr[order]
+            return out
+        prio = rows(base.prio, 0)
+        own = base.jobs.get((self.job.namespace, self.job.id), -2)
+        valid = rows(base.valid, False) & (rows(base.jid, -1) != own)
+        evictable = valid & (self.job.priority - prio >= 10)
+        gid = rows(base.gid, -1)
+        ids, inv = np.unique(gid[evictable], return_inverse=True)
+        grp = np.full((n_pad, A), -1, dtype=np.int32)
+        grp[evictable] = inv
+        dev_held = np.zeros((n_groups, n_pad, A), dtype=np.int32)
+        k = min(n_groups, base.held.shape[0])
+        dev_held[:k, :n] = base.held[:k][:, order]
+        G = int(2 ** np.ceil(np.log2(max(len(ids), 4))))
+        ptab = PreemptTables(
+            cpu=rows(base.cpu, 0.0).astype(dtype),
+            mem=rows(base.mem, 0.0).astype(dtype),
+            disk=rows(base.disk, 0.0).astype(dtype),
+            prio=prio, maxp=rows(base.maxp, 0), grp=grp,
+            dyn_ports=np.zeros((n_pad, A), dtype=np.int32),
+            static_rel=np.zeros((n_pad, A), dtype=bool), valid=valid,
+            job_prio=np.asarray(self.job.priority, dtype=np.int32),
+            dev_held=dev_held)
+        pinit = PreemptState(evicted=np.zeros((n_pad, A), dtype=bool),
+                             counts=np.zeros(G, dtype=np.int32))
+        cand_allocs = [base.allocs[i] for i in order] + [[]] * (n_pad - n)
+        return ptab, pinit, cand_allocs
 
     def materialize(self, lane: PackedLane, chosen, scores, n_yielded,
                     evict_rows=None) -> List[TpuPlacement]:
@@ -982,6 +1166,9 @@ class TpuPlacementService:
                         allocator.add_allocs(
                             self.ctx.proposed_allocs(node.id))
                         dev_allocators[node.id] = allocator
+                    if preempted:
+                        # what this placement evicts is offered anew
+                        allocator.release_allocs(preempted)
                     for req in task.resources.devices:
                         offer, _sum_aff, derr = allocator.assign_device(req)
                         if offer is None:
@@ -1159,7 +1346,7 @@ class TpuPlacementService:
             base = fold_usage_base(
                 matrix, nodes,
                 lambda nid: [a for a in snap.allocs_by_node(nid)
-                             if not a.client_terminal_status()])
+                             if not a.released_from_node()])
             _stat_incr("usage_base_misses")
             if base["ports"] is None:
                 freeze_usage_base(base)
@@ -1173,7 +1360,7 @@ class TpuPlacementService:
             pos_of = {nid: i for i, nid in enumerate(matrix.node_ids)}
             matrix._pos_index = pos_of
         for a in snap.allocs_by_job(self.job.namespace, self.job.id):
-            if a.client_terminal_status():
+            if a.released_from_node():
                 continue
             i = pos_of.get(a.node_id)
             if i is None:
@@ -1217,7 +1404,7 @@ class TpuPlacementService:
         ud = old_base["used_disk"].copy()
         for old, new in pairs:
             for a, sign in ((old, -1), (new, +1)):
-                if a is None or a.client_terminal_status():
+                if a is None or a.released_from_node():
                     continue
                 i = pos_of.get(a.node_id)
                 if i is None:
@@ -1252,7 +1439,7 @@ class TpuPlacementService:
             pos = pos_of.get(a.node_id)
             if pos is None:
                 return
-            if sign < 0 and a.client_terminal_status():
+            if sign < 0 and a.released_from_node():
                 return  # never counted in the table
             cr = a.allocated_resources.comparable()
             usage.used_cpu[pos] += sign * cr.cpu_shares

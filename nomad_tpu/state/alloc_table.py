@@ -54,7 +54,7 @@ class AllocTable:
         self.live = np.zeros(cap, dtype=np.uint8)
         # live by the APPLIER's filter (terminal_status: desired
         # stop/evict OR client-terminal), vs `live` which is the
-        # scheduler's filter (client-terminal only, ProposedAllocs)
+        # scheduler's filter (client-terminal or evicted, ProposedAllocs)
         self.live_strict = np.zeros(cap, dtype=np.uint8)
         # any ports/networks/reserved-cores/devices on the alloc: nodes
         # carrying such rows need the full python fit walk in the plan
@@ -252,7 +252,7 @@ class AllocTable:
         self.cpu[row] = cr.cpu_shares
         self.mem[row] = cr.memory_mb
         self.disk[row] = cr.disk_mb
-        self.live[row] = 0 if alloc.client_terminal_status() else 1
+        self.live[row] = 0 if alloc.released_from_node() else 1
         self.live_strict[row] = 0 if alloc.terminal_status() else 1
         self.special[row] = \
             1 if alloc.allocated_resources.has_special_dimensions() else 0
@@ -317,7 +317,7 @@ class AllocTable:
             crs.append(got[0])
             all_ports.append(got[1])
             special.append(got[2])
-        live = [0 if a.client_terminal_status() else 1 for a in allocs]
+        live = [0 if a.released_from_node() else 1 for a in allocs]
         live_strict = [0 if a.terminal_status() else 1 for a in allocs]
         job_hash = [stable_hash(a.namespace, a.job_id) for a in allocs]
         jobtg_hash = [stable_hash(a.namespace, a.job_id, a.task_group)

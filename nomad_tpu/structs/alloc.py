@@ -311,6 +311,14 @@ class Allocation:
     def terminal_status(self) -> bool:
         return self.server_terminal_status() or self.client_terminal_status()
 
+    def released_from_node(self) -> bool:
+        """Out of the scheduler's view of its node: its client reported
+        it terminal, or it was evicted, which is server-terminal from the
+        eviction's commit (context.go ProposedAllocs). A stop still counts
+        until its client reports it."""
+        return (self.desired_status == ALLOC_DESIRED_EVICT
+                or self.client_terminal_status())
+
     def index(self) -> int:
         """The [N] suffix of the alloc name, or -1 if unparseable
         (reference: Allocation.Index never throws)."""

@@ -5,8 +5,8 @@ reservations").
 Covers the three layers of the device extension:
   1. kernel: uniform device-ask lanes ride the non-preempt WAVEFRONT as
      a capacity dimension, bit-identical to the dense oracle;
-  2. preempt kernel: the capacity-countdown column keeps the windowed
-     preemption select exact when eviction can never free devices;
+  2. preempt kernels: one device ask evicts its holders on the device
+     (PreemptForDevice); several asks stay with the host iterator;
   3. end-to-end: the tier-5 world WITH device reservations places via a
      windowed kernel at >= 600 nodes with placement AND eviction-set
      parity against the host oracle.
@@ -120,14 +120,18 @@ def test_device_affinity_lane_stays_dense():
 def test_tier5_with_devices_places_via_windowed_kernel():
     """The VERDICT done-criterion: tier-5 world WITH device reservations
     at >= 600 nodes, placement + eviction-set parity host vs tpu, and
-    the tpu run actually dispatching the WINDOWED preempt kernel."""
+    the tpu run actually dispatching the WINDOWED preempt kernel. The
+    fillers hold every GPU: at 95% of cpu a GPU ask that found a free
+    instance would place without eviction (selectNextOption)."""
     metrics.reset()
     host, host_ev = run_tier_placements(5, 600, 48, seed=11,
                                         alg="binpack",
-                                        with_evictions=True)
+                                        with_evictions=True,
+                                        gpu_holders=True)
     tpu, tpu_ev = run_tier_placements(5, 600, 48, seed=11,
                                       alg="tpu-binpack",
-                                      with_evictions=True)
+                                      with_evictions=True,
+                                      gpu_holders=True)
     assert host, "host placed nothing -- bad world"
     assert tpu == host
     assert tpu_ev == host_ev
@@ -145,9 +149,9 @@ def test_tier5_with_devices_places_via_windowed_kernel():
 
 def test_preempt_device_lane_with_candidate_gpus_falls_back_to_host():
     """Candidates holding matching devices would be freed by eviction
-    (PreemptForDevice territory): pack() must route the lane to the
-    host iterator, and the end result still matches the host oracle
-    (trivially -- it IS the host path)."""
+    (PreemptForDevice territory). The kernels evict for one device ask;
+    a task group asking for two, each assigned in turn against what the
+    last evicted, is routed by pack() to the host iterator."""
     from nomad_tpu.structs import (
         AllocatedDeviceResource, PreemptionConfig)
 
@@ -176,7 +180,8 @@ def test_preempt_device_lane_with_candidate_gpus_falls_back_to_host():
 
     job = mock.job(id="gpu-preempt-job", priority=70)
     job.task_groups[0].tasks[0].resources.devices = [
-        DeviceRequest(name="nvidia/gpu", count=1)]
+        DeviceRequest(name="nvidia/gpu", count=1),
+        DeviceRequest(name="nvidia/gpu/v100", count=1)]
     h.state.upsert_job(job)
     metrics.reset()
     lane = _pack_lane(h, job, nodes, 4, preempt=True)

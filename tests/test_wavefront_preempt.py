@@ -3,12 +3,17 @@ kernel (solve_lane_wave_preempt vs solve_placements_preempt).
 
 The dense kernel is itself parity-gated against the host oracle
 (tests/test_preemption_tpu.py places AND evicts identically), so dense
-equality here closes the chain: wave == dense == host. Worlds sweep the
-dimensions the window design must preserve: priority tiers (ascending
-group gating), max_parallel penalties (group counts in the carry),
-distinct_hosts, affinity columns, reschedule penalties, multi-copy
-saturation (the deferred zombie shift), and inert padding lanes in the
-batched form."""
+equality here closes the chain: wave == dense == host. The windowed
+kernel takes a lane where no node fits its ask without eviction (the
+service routes the rest to the dense kernel), so every world here starts
+so: each node holds at least 3,001 of its 4,000 MHz, beyond its
+candidates where they hold less (the job's own or other allocs no
+eviction takes). Worlds sweep the dimensions the window design must
+preserve: priority tiers (ascending group gating), max_parallel
+penalties (group counts in the carry), distinct_hosts, affinity columns,
+reschedule penalties, multi-copy saturation (the deferred zombie shift,
+and slots that fit without eviction once the lane evicted there), and
+inert padding lanes in the batched form."""
 import random
 
 import numpy as np
@@ -17,6 +22,7 @@ import pytest
 from nomad_tpu.solver.binpack import (
     NodeConst, NodeState, PlacementBatch, PreemptState, PreemptTables,
     solve_lane_wave_preempt, solve_placements_preempt,
+    wavefront_preempt_pristine,
 )
 
 
@@ -59,6 +65,7 @@ def _world(rng, n=40, p=16, a=6, limit=5, fill=0.9, distinct=False,
             used[i] += c
             used_m[i] += cmem[i, k]
             k += 1
+    used = np.maximum(used, 3001.0)
 
     aff = np.zeros(n, dtype=dt)
     if affinity:
@@ -113,6 +120,9 @@ def _world(rng, n=40, p=16, a=6, limit=5, fill=0.9, distinct=False,
 
 
 def _compare(const, init, batch, ptab, pinit):
+    fit0, _ = wavefront_preempt_pristine(const, init, batch, ptab,
+                                         "float64")
+    assert not fit0.any()
     cd, sd, yd, evd, _ = solve_placements_preempt(
         const, init, batch, ptab, pinit, spread_alg=False,
         dtype_name="float64")
