@@ -306,8 +306,12 @@ class DeviceChecker:
 
     def _check_device_constraints(self, group, constraints) -> bool:
         for c in constraints:
-            lval, l_ok = self._resolve_device_target(c.l_target, group)
-            rval, r_ok = self._resolve_device_target(c.r_target, group)
+            # through the class: the allocators call this with a shim
+            # that carries only ctx
+            lval, l_ok = DeviceChecker._resolve_device_target(c.l_target,
+                                                             group)
+            rval, r_ok = DeviceChecker._resolve_device_target(c.r_target,
+                                                             group)
             if not check_constraint(self.ctx, c.operand, lval, rval, l_ok, r_ok):
                 return False
         return True

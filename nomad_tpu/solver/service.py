@@ -732,7 +732,7 @@ class TpuPlacementService:
                     node.id: self.ctx.proposed_allocs(node.id)
                     for node in nodes}
             dev = self._pack_devices(tg, requests, nodes, order, n_pad,
-                                     proposed_by_node, dtype, base)
+                                     proposed_by_node, dtype, base, matrix)
             const = const._replace(dev_aff=dev[0], dev_count=dev[1],
                                    dev_sum_weight=dev[2])
             init = init._replace(dev_free=dev[3])
@@ -873,23 +873,20 @@ class TpuPlacementService:
         return vidx, limits, tg_scope, counts
 
     def _pack_devices(self, tg, requests, nodes, order, n_pad,
-                      proposed_by_node, dtype, base=None):
+                      proposed_by_node, dtype, base=None, matrix=None):
         """Device tables (feasible.go:1270 DeviceChecker + device.go
         allocator): per request r and matching node group g, the affinity
-        score and free instance count (capacity minus proposed usage,
-        from ``base``'s holdings where the eval packs from one)."""
+        score and free instance count (capacity minus proposed usage).
+        Packed from ``base``, that is one gather over its holdings and
+        one check per distinct group; else a walk of every node's
+        proposed allocs."""
         from ..scheduler.rank import DeviceAllocator
+        from ..tensor.pack import pack_device_groups_cached
 
         R = len(requests)
-        # per node: count matching groups to size the Gd axis
-        per_node_groups = []
-        max_g = 1
-        for pos in range(len(order)):
-            node = nodes[order[pos]]
-            groups = list(node.node_resources.devices)
-            per_node_groups.append(groups)
-            max_g = max(max_g, len(groups))
-        Gd = int(2 ** np.ceil(np.log2(max(max_g, 1))))
+        groups = pack_device_groups_cached(nodes, n_pad, matrix=matrix)
+        G = groups.inst.shape[0]
+        Gd = int(2 ** np.ceil(np.log2(max(G, 1))))
 
         aff = np.zeros((R, Gd, n_pad), dtype=dtype)
         free = np.full((R, Gd, n_pad), -1, dtype=np.int32)
@@ -898,32 +895,51 @@ class TpuPlacementService:
         for r in requests:
             if r.affinities:
                 sum_w += sum(abs(float(a.weight)) for a in r.affinities)
+        sum_w = np.asarray(sum_w, dtype=dtype)
 
-        for pos, groups in enumerate(per_node_groups):
-            if not groups:
-                continue
-            node = nodes[order[pos]]
-            if base is None:
-                allocator = DeviceAllocator(self.ctx, node)
-                allocator.add_allocs(proposed_by_node[node.id])
-            for g_i, group in enumerate(groups):
-                if base is not None:
-                    n_free = len(group.instance_ids) - int(
-                        base.held[g_i, order[pos]].sum())
-                else:
-                    used = allocator.used.get(group.id_string(), set())
-                    n_free = sum(1 for i in group.instance_ids
-                                 if i not in used)
+        def verdict(group, req):
+            """(matches, affinity score) of one group for one request."""
+            if not group.matches_request(req.name) or (
+                    req.constraints and not self._dev_constraints_ok(
+                        group, req.constraints)):
+                return False, 0.0
+            return True, self._dev_affinity_score(group, req)
+
+        if base is not None:
+            from ..server.telemetry import metrics as _tm
+            _tm.incr("nomad.solver.device_tables_from_base")
+            if not G:
+                return aff, counts, sum_w, free
+            # per distinct group id (last column: no group), then gathered
+            # to the scan order
+            ok = np.zeros((R, len(groups.reps) + 1), dtype=bool)
+            score = np.zeros(ok.shape)
+            for k, group in enumerate(groups.reps):
                 for r_i, req in enumerate(requests):
-                    if not group.matches_request(req.name):
-                        continue
-                    if req.constraints and not self._dev_constraints_ok(
-                            group, req.constraints):
-                        continue
-                    free[r_i, g_i, pos] = n_free
-                    aff[r_i, g_i, pos] = self._dev_affinity_score(
-                        group, req)
-        return aff, counts, np.asarray(sum_w, dtype=dtype), free
+                    ok[r_i, k], score[r_i, k] = verdict(group, req)
+            o = np.asarray(order, dtype=np.int64)
+            ident = groups.ident[:, o]
+            n_free = groups.inst[:, o] - base.held.sum(-1)[:, o]
+            n = len(o)
+            free[:, :G, :n] = np.where(ok[:, ident], n_free, -1)
+            aff[:, :G, :n] = score[:, ident]
+            return aff, counts, sum_w, free
+
+        for pos in range(len(order)):
+            node = nodes[order[pos]]
+            if not node.node_resources.devices:
+                continue
+            allocator = DeviceAllocator(self.ctx, node)
+            allocator.add_allocs(proposed_by_node[node.id])
+            for g_i, group in enumerate(node.node_resources.devices):
+                used = allocator.used.get(group.id_string(), set())
+                n_free = sum(1 for i in group.instance_ids if i not in used)
+                for r_i, req in enumerate(requests):
+                    matches, score = verdict(group, req)
+                    if matches:
+                        free[r_i, g_i, pos] = n_free
+                        aff[r_i, g_i, pos] = score
+        return aff, counts, sum_w, free
 
     def _dev_constraints_ok(self, group, constraints) -> bool:
         from ..scheduler.feasible import DeviceChecker

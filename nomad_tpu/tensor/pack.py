@@ -324,6 +324,10 @@ def _freeze(obj) -> None:
                     obj.weights, obj.initial_counts):
             arr.setflags(write=False)
             _note_frozen(arr)
+    elif isinstance(obj, DeviceGroups):
+        for arr in (obj.inst, obj.ident):
+            arr.setflags(write=False)
+            _note_frozen(arr)
 
 
 def _note_frozen(arr) -> None:
@@ -422,6 +426,46 @@ def pack_affinities_cached(affinities, ctx, nodes, n_pad: int,
     key = ("aff", repr(affinities))
     return _matrix_memo(matrix, key, lambda: pack_affinities(
         affinities, ctx, nodes, n_pad))
+
+
+@dataclass
+class DeviceGroups:
+    """Each node's device groups on the node axis, slot g the node's g-th
+    group: its instance count and a small id of its identity (vendor,
+    type, name and attributes: all a device request's name, constraints
+    and affinities can read), with one representative group per id."""
+
+    inst: np.ndarray    # (G, n_pad) int32; 0 where the node has no group g
+    ident: np.ndarray   # (G, n_pad) int32; -1 where the node has no group g
+    reps: list          # id -> a NodeDeviceResource of that identity
+
+
+def pack_device_groups(nodes, n_pad: int) -> DeviceGroups:
+    G = max((len(n.node_resources.devices) for n in nodes), default=0)
+    inst = np.zeros((G, n_pad), dtype=np.int32)
+    ident = np.full((G, n_pad), -1, dtype=np.int32)
+    ids: Dict[tuple, int] = {}
+    reps: list = []
+    for i, node in enumerate(nodes):
+        for g, group in enumerate(node.node_resources.devices):
+            inst[g, i] = len(group.instance_ids)
+            key = (group.vendor, group.type, group.name,
+                   tuple(sorted((k, repr(v))
+                                for k, v in group.attributes.items())))
+            gid = ids.get(key)
+            if gid is None:
+                gid = ids[key] = len(reps)
+                reps.append(group)
+            ident[g, i] = gid
+    return DeviceGroups(inst, ident, reps)
+
+
+def pack_device_groups_cached(nodes, n_pad: int, matrix=None
+                              ) -> DeviceGroups:
+    """pack_device_groups memoized per node-table version: it reads the
+    nodes alone."""
+    return _matrix_memo(matrix, ("devgroups",),
+                        lambda: pack_device_groups(nodes, n_pad))
 
 
 @dataclass

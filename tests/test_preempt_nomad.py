@@ -511,3 +511,179 @@ def test_a_windowed_dispatch_warms_its_whole_axis_twin():
         got = batch_mod.fuse_and_solve(lanes, use_mesh=False, e_pad_hint=8)
         assert all(int(np.sum(chosen >= 0)) == 8 for chosen, *_ in got)
     assert guard.compile_stats()["backend_compiles"] == compiled
+
+
+def _device_tables_two_ways(state, job, nodes, dtype,
+                            eval_id="dt-eval-000000000001"):
+    """A preempting eval's device tables on a fresh plan: packed from
+    the snapshot's shared base, and by the walk of every node's proposed
+    allocs."""
+    from nomad_tpu.scheduler.context import EvalContext
+    from nomad_tpu.scheduler.util import shuffled_order
+    from nomad_tpu.solver import service
+    from nomad_tpu.tensor.pack import pack_nodes_cached
+    snap = state.snapshot()
+    ctx = EvalContext(snap, Plan(eval_id=eval_id, priority=job.priority,
+                                 job=job))
+    svc = service.TpuPlacementService(ctx, job, False, False, preempt=True)
+    matrix = pack_nodes_cached(nodes, snap.node_table_index)
+    order = shuffled_order(eval_id, snap.latest_index(), len(nodes))
+    tg = job.task_groups[0]
+    requests = [r for t in tg.tasks for r in t.resources.devices]
+    args = (tg, requests, nodes, order, matrix.n_pad)
+    base = service._preempt_base(snap, matrix, nodes)
+    from_base = svc._pack_devices(*args, None, dtype, base, matrix)
+    walked = {n.id: ctx.proposed_allocs(n.id) for n in nodes}
+    walk = svc._pack_devices(*args, walked, dtype, None, matrix)
+    return from_base, walk
+
+
+def _tier5_device_world(world):
+    n, count, seed, _cpu_only, util, held = WORLDS[world]
+    built, _plan, state = WB.tier5_world(n, count, seed, False, alg=None,
+                                         util=util, gpu_holders=held)
+    job = state.job_by_id("default", built[2]["id"])
+    return state, job, [state.node_by_id(n) for n in built[1]]
+
+
+def _commit_gpu_moves(state, job, nodes):
+    """On a few GPU nodes, a holder evicted and, on every other one of
+    them, a placement of ``job`` holding one of the instances it gave
+    back, all in one commit."""
+    changed = []
+    gpu_nodes = [n for n in nodes if n.node_resources.devices][:6]
+    for k, node in enumerate(gpu_nodes):
+        holder = next(a for a in state.allocs_by_node(node.id)
+                      if any(t.devices for t in
+                             a.allocated_resources.tasks.values()))
+        ev = holder.copy_skip_job()
+        ev.desired_status = "evict"
+        changed.append(ev)
+        if k % 2:
+            dev = next(d for t in holder.allocated_resources.tasks.values()
+                       for d in t.devices)
+            placed = mock.alloc_for(job, node, index=k)
+            placed.client_status = "running"
+            next(iter(placed.allocated_resources.tasks.values())).devices = [
+                AllocatedDeviceResource(vendor=dev.vendor, type=dev.type,
+                                        name=dev.name,
+                                        device_ids=dev.device_ids[:1])]
+            changed.append(placed)
+    state.upsert_allocs(changed)
+
+
+def _two_group_world():
+    """24 nodes with none, one or two device groups of four kinds (two
+    of one model at different memory), their instances partly held by
+    a priority-10 job; a priority-70 job asks one GPU of 10 GB or more
+    with an affinity for the v100, and any one GPU."""
+    from nomad_tpu.structs import Affinity, Constraint, DeviceRequest
+    from nomad_tpu.structs.resources import NodeDeviceResource
+    kinds = [(), (("1080ti", 11264, 2),), (("v100", 16384, 4),),
+             (("t4", 8192, 2),), (("1080ti", 11264, 2), ("v100", 32768, 2)),
+             (("t4", 8192, 4), ("v100", 16384, 4))]
+    store = StateStore()
+    low = mock.job(priority=10)
+    low.id = "dg-low"
+    store.upsert_job(low)
+    nodes, allocs = [], []
+    for i in range(24):
+        node = mock.node()
+        node.id = f"dg-node-{i:04d}"
+        node.node_resources.devices = [NodeDeviceResource(
+            vendor="nvidia", type="gpu", name=name,
+            instance_ids=[f"{node.id}-{name}-{k}" for k in range(count)],
+            attributes={"memory": mem})
+            for name, mem, count in kinds[i % len(kinds)]]
+        node.compute_class()
+        store.upsert_node(node)
+        nodes.append(node)
+        for g_i, group in enumerate(node.node_resources.devices):
+            take = group.instance_ids[:(i + g_i) % 3]
+            if take:
+                a = mock.alloc_for(low, node, index=len(allocs))
+                a.client_status = "running"
+                next(iter(a.allocated_resources.tasks.values())).devices = [
+                    AllocatedDeviceResource(
+                        vendor=group.vendor, type=group.type,
+                        name=group.name, device_ids=take)]
+                allocs.append(a)
+    store.upsert_allocs(allocs)
+    job = mock.job(priority=70)
+    job.id = "dg-high"
+    job.task_groups[0].tasks[0].resources.devices = [
+        DeviceRequest(name="nvidia/gpu", count=1, constraints=[Constraint(
+            l_target="${device.attr.memory}", operand=">=",
+            r_target="10000")], affinities=[Affinity(
+                l_target="${device.model}", r_target="v100", operand="=",
+                weight=50)]),
+        DeviceRequest(name="gpu", count=1)]
+    store.upsert_job(job)
+    return store, job, nodes
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+@pytest.mark.parametrize("case", ["gpu_a", "gpu_b", "gpu_a_caught_up",
+                                  "two_groups"])
+def test_the_device_tables_from_the_base_are_the_walk_s(case, dtype,
+                                                        monkeypatch):
+    """A preempting eval packed from the snapshot's shared base takes its
+    device tables from the base's holdings and one check per distinct
+    device group: they are, bit for bit, the tables the walk of every
+    node's proposed allocs gives, on a base built at the snapshot and on
+    one caught up to it across a commit."""
+    from nomad_tpu.solver import service
+    from nomad_tpu.tensor.pack import pack_device_groups_cached
+    if case == "two_groups":
+        state, job, nodes = _two_group_world()
+    else:
+        state, job, nodes = _tier5_device_world(case.split("_caught")[0])
+    if case.endswith("caught_up"):
+        _device_tables_two_ways(state, job, nodes, dtype)
+        _commit_gpu_moves(state, job, nodes)
+
+        def rebuilt(*_a, **_k):
+            raise AssertionError("the base was built anew, not caught up")
+        monkeypatch.setattr(service._PreemptBase, "build", rebuilt)
+    from_base, walk = _device_tables_two_ways(state, job, nodes, dtype)
+    for name, got, want in zip(("aff", "counts", "sum_w", "free"),
+                               from_base, walk):
+        assert got.dtype == want.dtype and got.shape == want.shape, name
+        assert np.array_equal(got, want), name
+    free = walk[3]
+    assert (free == -1).any() and (free >= 0).any()
+    if case.endswith("caught_up"):
+        assert (free > 0).any()
+    if case == "two_groups":
+        groups = pack_device_groups_cached(nodes, free.shape[2])
+        assert len(groups.reps) == 4 and free.shape[1] == 2
+        # the constraint keeps the t4 from the first ask, not the second
+        assert (free[0] == -1).sum() > (free[1] == -1).sum()
+        assert set(np.unique(walk[0][0])) == {0.0, 50.0}
+
+
+def test_device_tables_from_base_counts_the_lanes_packed_from_a_base():
+    """One count per preempting lane whose device tables came from the
+    snapshot's base; none for a lane whose plan already holds an entry,
+    packed through the walk of every node's proposed allocs."""
+    from nomad_tpu.scheduler.context import EvalContext
+    from nomad_tpu.scheduler.reconcile import AllocPlaceResult
+    from nomad_tpu.solver.service import TpuPlacementService
+    metrics.reset()
+    built, _plan, state = WB.tier5_world(200, 8, 21, False, alg=None,
+                                         gpu_holders=True)
+    name = "nomad.solver.device_tables_from_base"
+    assert _pack((built, state)) is not None
+    assert metrics.snapshot()["counters"].get(name, 0) == 1
+    job = state.job_by_id("default", built[2]["id"])
+    tg = job.task_groups[0]
+    nodes = [state.node_by_id(n) for n in built[1]]
+    plan = Plan(eval_id=built[3], priority=job.priority, job=job)
+    plan.append_alloc(mock.alloc_for(job, nodes[0], index=7))
+    ctx = EvalContext(state.snapshot(), plan)
+    places = [AllocPlaceResult(name=f"{job.id}.{tg.name}[{k}]",
+                               task_group=tg) for k in range(7)]
+    lane = TpuPlacementService(ctx, job, False, False, preempt=True).pack(
+        tg, places, nodes)
+    assert lane is not None and lane.ptab is not None
+    assert metrics.snapshot()["counters"].get(name, 0) == 1
